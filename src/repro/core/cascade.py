@@ -112,7 +112,9 @@ def centroid_bound_batch(sel_b: jax.Array, r_b: jax.Array, mask_b: jax.Array,
     radius = jnp.sqrt(jnp.max(jnp.where(mask_b > 0, d2, 0.0), axis=1))
     g2 = jnp.sum(g * g, axis=-1)                        # (N,)
     z2 = jnp.sum(z * z, axis=-1)                        # (Q,)
-    n2 = (g2[None, :] - 2.0 * m[None, :] * (z @ g.T)
+    # HIGHEST: the same cancellation as `core.sinkhorn.m_rows`
+    zg = jnp.matmul(z, g.T, precision=jax.lax.Precision.HIGHEST)
+    n2 = (g2[None, :] - 2.0 * m[None, :] * zg
           + (m[None, :] ** 2) * z2[:, None])            # ||g - m z||^2, (Q,N)
     lb = jnp.sqrt(jnp.maximum(n2, 0.0)) - m[None, :] * radius[:, None]
     lb = jnp.maximum(lb, 0.0)

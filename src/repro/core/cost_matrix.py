@@ -24,7 +24,10 @@ def cdist_matmul(a: jax.Array, b: jax.Array, *, squared: bool = False) -> jax.Ar
     """MXU form: |a|^2 + |b|^2 - 2ab, clamped at 0 for fp round-off."""
     a2 = jnp.sum(a * a, axis=-1)[:, None]
     b2 = jnp.sum(b * b, axis=-1)[None, :]
-    d2 = jnp.maximum(a2 + b2 - 2.0 * (a @ b.T), 0.0)
+    # HIGHEST: a TPU's default single bf16 pass loses the cancellation in
+    # |a|^2 + |b|^2 - 2 a.b (f32 on the CPU either way)
+    ab = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = jnp.maximum(a2 + b2 - 2.0 * ab, 0.0)
     return d2 if squared else jnp.sqrt(d2)
 
 

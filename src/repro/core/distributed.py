@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.cost_matrix import cdist
 from repro.core.sparse_sinkhorn import pad_k, safe_recip
 from repro.core import sparse_sinkhorn as ss
@@ -163,8 +162,8 @@ def build_wmd_fn(mesh: Mesh, *, lamb: float, max_iter: int,
                             cols_loc, vals_loc, lamb=lamb, max_iter=max_iter,
                             model_axis=model_axis, use_kernel=use_kernel)
 
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -245,8 +244,8 @@ def build_wmd_batch_fn(mesh: Mesh, *, lamb: float, max_iter: int,
             return wmd, n_iter, delta
         return wmd
 
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -354,8 +353,8 @@ def build_wmd_batch_fn_stripes(mesh: Mesh, *, max_iter: int,
             return wmd, n_iter, delta
         return wmd
 
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -398,8 +397,8 @@ def build_wmd_fn_docsharded(mesh: Mesh, *, lamb: float, max_iter: int,
             return ops.sddmm_spmm_type2(k_pad, km_pad, u, cols_loc, vals_loc)
         return ss.sddmm_spmm_type2(k_pad, km_pad, u, cols_loc, vals_loc)
 
-    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(all_axes), check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(all_axes), check_vma=False)
     return jax.jit(fn)
 
 

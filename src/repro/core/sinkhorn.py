@@ -65,7 +65,10 @@ def m_rows(word_ids: jax.Array, vecs: jax.Array,
     a2 = jnp.sum(a * a, axis=-1)[:, None]
     if b2 is None:
         b2 = jnp.sum(vecs * vecs, axis=-1)
-    return jnp.sqrt(jnp.maximum(a2 + b2[None, :] - 2.0 * (a @ vecs.T), 0.0))
+    # HIGHEST: a TPU's default single bf16 pass loses the cancellation in
+    # |a|^2 + |b|^2 - 2 a.b (f32 on the CPU either way)
+    ab = jnp.matmul(a, vecs.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.sqrt(jnp.maximum(a2 + b2[None, :] - 2.0 * ab, 0.0))
 
 
 def precompute_rows(word_ids: jax.Array, vecs: jax.Array, lamb: float,

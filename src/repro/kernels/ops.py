@@ -1,8 +1,9 @@
 """Public jit'd entry points for the Pallas kernels.
 
 Responsibilities:
-  * backend dispatch -- ``interpret=True`` everywhere except real TPU, so the
-    same call sites validate on CPU (this container) and run Mosaic on TPU;
+  * backend dispatch -- ``interpret=True`` on the CPU backend only, so the
+    same call sites validate on CPU and run Mosaic on TPU (other backends
+    raise);
   * alignment padding -- v_r to the f32 sublane multiple (8), docs to the
     doc-tile, so callers never think about hardware shapes;
   * the vocab-chunked driver (`sddmm_spmm_chunked`) that replays the
@@ -22,7 +23,15 @@ from repro.kernels._pad import pad_axis
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Mosaic on a TPU, the Pallas interpreter on the CPU, and an error on
+    any other backend: an interpreted kernel there would run silently slow
+    and under a device label it never touched."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise NotImplementedError(
+            f"Pallas kernels run on tpu (Mosaic) or cpu (interpret mode), "
+            f"not on {backend!r}")
+    return backend == "cpu"
 
 
 _pad_to = pad_axis

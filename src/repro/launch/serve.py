@@ -11,14 +11,15 @@ Poisson arrivals at ``--rate-qps``, or back-to-back submits when 0), with
 ``--max-queue`` backpressure and optional per-request ``--deadline-ms``
 budgets. Ctrl-C is safe: the loop drains the queue and in-flight batch
 before exiting, the `ServingStats` report (batch-size histogram, dispatch
-triggers, latency percentiles) always prints on the way out, and with
-``--cache-dir`` the persisted compilation cache's state is reported too.
+triggers, latency percentiles) always prints on the way out, and so does
+the persisted compilation cache's state.
 
 Startup / batch extensions (ROADMAP item 3):
 
-* ``--cache-dir DIR`` points jax's persistent compilation cache at DIR, so
-  a restarted server (or a CI job restoring DIR) skips every XLA backend
-  compile it has seen before -- pair with ``--warmup``.
+* jax's persistent compilation cache is always on, at
+  ``$JAX_COMPILATION_CACHE_DIR`` or else the checkout's ``.jax_cache/``
+  (``--cache-dir DIR`` overrides), so a restarted server skips every XLA
+  backend compile it has seen before -- pair with ``--warmup``.
 * ``--warmup`` precompiles the full serving envelope before any traffic:
   every pow2 Q bucket x request kind the flags imply, via the
   `serving.warmup` shape registry (the serving loop always warms; the flag
@@ -101,8 +102,9 @@ def main():
                          "print the per-shape compile report")
     ap.add_argument("--cache-dir", default="",
                     help="sinkhorn-wmd: persist jax's compilation cache "
-                         "here -- a restart (or a CI job restoring the "
-                         "directory) skips every XLA compile it has seen")
+                         "here instead of $JAX_COMPILATION_CACHE_DIR or "
+                         "the checkout's .jax_cache/ -- a restart skips "
+                         "every XLA compile it has seen")
     ap.add_argument("--offline", default="", metavar="QUERIES",
                     help="sinkhorn-wmd: offline bulk-scoring mode -- "
                          "stream this query file (.npz/.npy, (n, V)) at "
@@ -170,10 +172,9 @@ def main():
         from repro.configs import sinkhorn_wmd as wmd_cfg
         from repro.data import make_corpus
         from repro.serving import WMDService, enable_compilation_cache
-        if args.cache_dir:
-            # before the service exists: every compile from here on is
-            # persisted / looked up in the cache directory
-            enable_compilation_cache(args.cache_dir)
+        # before the service exists: every compile from here on is
+        # persisted / looked up in the cache directory
+        enable_compilation_cache(args.cache_dir or None)
         if args.ingest_stream and args.coalesce_window_ms <= 0:
             ap.error("--ingest-stream requires --coalesce-window-ms > 0 "
                      "(writes go through the coalescer's writer lane)")

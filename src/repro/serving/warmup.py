@@ -25,8 +25,9 @@ session's dispatch log and asserts zero first-hit compiles after warmup).
 
 Persisted compilation cache
 ---------------------------
-`enable_compilation_cache(dir)` points jax's persistent compilation cache
-at ``dir`` (entry thresholds zeroed so CPU-sized programs persist too).
+`enable_compilation_cache()` turns on jax's persistent compilation cache
+at ``$JAX_COMPILATION_CACHE_DIR`` or else at the fixed in-checkout
+``.jax_cache/`` (entry thresholds zeroed so CPU-sized programs persist too).
 Compiled programs are keyed by (HLO, jaxlib, flags) and written at compile
 time; a later process -- the next serve run, a CI job restoring the
 directory from `actions/cache` -- *re-lowers* each shape but skips the
@@ -57,6 +58,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import os
+import pathlib
 import threading
 import time
 from typing import Iterable, Sequence
@@ -84,7 +86,7 @@ _listener_installed = False
 _active_counters: list["CompileCounter"] = []
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)     # identity: nested counters may tie
 class CompileCounter:
     """Compile-or-retrieve tallies for one measured span.
 
@@ -154,20 +156,37 @@ def measure_compiles():
 
 # -- persisted compilation cache ---------------------------------------------
 
-def enable_compilation_cache(cache_dir: str | os.PathLike) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+# in the checkout root (src/repro/serving/warmup.py -> parents[3])
+DEFAULT_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[3]
+                        / ".jax_cache")
+
+
+def enable_compilation_cache(cache_dir: str | os.PathLike | None = None
+                             ) -> str:
+    """Turn on jax's persistent compilation cache; the one place that sets
+    its directory.
+
+    The directory is ``cache_dir`` when given (an explicit override), else
+    ``$JAX_COMPILATION_CACHE_DIR`` when set (jax already reads it; nothing
+    is set over it), else `DEFAULT_CACHE_DIR`, a fixed path inside the
+    checkout -- the path is part of what a later process must find again,
+    so it is never built from a temp dir, a pid or the time.
 
     Zeroes the entry thresholds (min compile time / min entry size) so the
     CPU-sized programs of the test and CI shapes persist too -- the
-    defaults only persist second-scale compiles. Safe to call before any
+    defaults only persist second-scale compiles. Call before the first
     compile in the process; programs compiled afterwards are written
     eagerly, keyed by (HLO, jaxlib version, compile flags), so a crash or
     SIGINT after the first compile still leaves a warm cache behind.
     Returns the directory (created if missing)."""
     import jax
-    cache_dir = os.fspath(cache_dir)
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if cache_dir is None and env_dir:
+        cache_dir = env_dir
+    else:
+        cache_dir = os.fspath(cache_dir or DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir

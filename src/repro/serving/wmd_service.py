@@ -251,9 +251,12 @@ class WMDService:
             "pruned top-k dispatches that fell back to the exact full scan")
         # prefilter state: the bound runs replicated on the ORIGINAL
         # (un-rebucketed) ELL -- the min over a doc's words needs the doc's
-        # whole support, which vocab re-bucketing splits across shards
-        self._ell_cols_d = jnp.asarray(self.ell.cols)
-        self._ell_vals_d = jnp.asarray(self.ell.vals)
+        # whole support, which vocab re-bucketing splits across shards.
+        # Replicated over the mesh, not left on the default device (which
+        # need not belong to the mesh).
+        self._replicated = NamedSharding(self.mesh, P())
+        self._ell_cols_d, self._ell_vals_d = jax.device_put(
+            (self.ell.cols, self.ell.vals), self._replicated)
         self._b2 = jnp.sum(self._vecs_d * self._vecs_d, axis=-1)
         self._doc_shards = 1
         for a in self._doc_axes:
@@ -364,8 +367,8 @@ class WMDService:
             _, self._cols_d, self._vals_d = shard_wmd_inputs(
                 self.mesh, self.vecs, self._rb.cols, self._rb.vals,
                 doc_axes=self._doc_axes)
-            self._ell_cols_d = jnp.asarray(self.ell.cols)
-            self._ell_vals_d = jnp.asarray(self.ell.vals)
+            self._ell_cols_d, self._ell_vals_d = jax.device_put(
+                (self.ell.cols, self.ell.vals), self._replicated)
             self._empty_doc_mask = np.asarray(
                 self.ell.vals.sum(axis=-1) == 0)
             self._cent = None                # tier-0 moments follow the base
@@ -377,8 +380,8 @@ class WMDService:
                 d_ell, self.mesh.shape["model"])
             self._dcols_d = jax.device_put(drb.cols, self._rerank_spec)
             self._dvals_d = jax.device_put(drb.vals, self._rerank_spec)
-            self._dell_cols_d = jnp.asarray(d_ell.cols)
-            self._dell_vals_d = jnp.asarray(d_ell.vals)
+            self._dell_cols_d, self._dell_vals_d = jax.device_put(
+                (d_ell.cols, d_ell.vals), self._replicated)
             ids, seg, row = lc.locations()
             self._live_ids = ids
             self._live_seg = seg

@@ -209,8 +209,17 @@ def test_early_exit_fewer_iterations_same_result(batch_problem):
 
 def test_distributed_vote_matches_single_host_masking():
     """build_wmd_batch_fn(tol>0) on a (2, 2) mesh: per-query n_iter from the
-    all-shards vote == single-host sinkhorn_wmd_converged_batch, and the
-    distances agree (subprocess: needs a forced device count)."""
+    all-shards vote is within one iteration of single-host
+    sinkhorn_wmd_converged_batch, and the distances agree (subprocess: needs
+    a forced device count).
+
+    Why not equal: the vote compares a relative iterate delta |dx|/|x| with
+    tol, and dx is a difference of two nearly equal f32 iterates, so the
+    delta carries ~eps/tol (about 1%) of relative rounding. The sharded
+    solve sums x over vocab shards through a psum, a different f32 order
+    than the single-host sum, so when a query's delta lands within that
+    noise of tol the two runs stop one iteration apart (seen: delta
+    1.004e-5 single-host vs 9.83e-6 sharded at iteration 57, tol 1e-5)."""
     import os
     import subprocess
     import sys
@@ -254,7 +263,8 @@ fn = build_wmd_batch_fn(mesh, lamb=1.0, max_iter=400, tol=1e-5,
 vd, cd, vld = shard_wmd_inputs(mesh, vecs, rb.cols, rb.vals)
 wmd, n_iter, delta = fn(jnp.asarray(vecs[sel_b]), jnp.asarray(r_b),
                         jnp.asarray(mask_b), vd, cd, vld)
-np.testing.assert_array_equal(np.asarray(n_iter), np.asarray(ref.n_iter))
+gap = np.abs(np.asarray(n_iter) - np.asarray(ref.n_iter))
+assert gap.max() <= 1, (np.asarray(n_iter), np.asarray(ref.n_iter))
 err = (np.abs(np.asarray(wmd) - np.asarray(ref.wmd)).max()
        / np.abs(np.asarray(ref.wmd)).max())
 assert err < 1e-4, err

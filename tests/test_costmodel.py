@@ -78,7 +78,7 @@ def test_collective_parser_end_to_end():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, json
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.costmodel import collective_bytes
         mesh = make_mesh((2, 4), ("data", "model"))
         def step(x, ws):

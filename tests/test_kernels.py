@@ -176,3 +176,16 @@ def test_chunked_driver_matches_monolithic():
                                      jnp.asarray(rb.vals))
     np.testing.assert_allclose(np.asarray(x_chunk), np.asarray(x_full),
                                rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("backend, interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """Mosaic on a TPU, the interpreter on the CPU, and no silent
+    interpreter on any other backend."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(NotImplementedError, match="gpu"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret

@@ -1,8 +1,8 @@
 """Kernel-oracle fuzz for the RWMD min-SDDMM Pallas kernel (kernels.rwmd),
 mirroring test_kernels.py: three-way agreement pallas == core-jnp == naive
 dense oracle over random shapes, including non-tile-multiple v_r / N / V
-and the +inf pad-row convention. CPU runs interpret mode; the accel.yml
-runner exercises the compiled Mosaic path through the same selectors."""
+and the +inf pad-row convention. CPU runs interpret mode; whether Mosaic
+compiles the kernel for a TPU is `tests/test_tpu_compile.py`'s question."""
 import jax.numpy as jnp
 import numpy as np
 import pytest

@@ -55,8 +55,9 @@ def test_cache_shardings_divisibility_safe():
     from repro.distributed import partitioning
     from repro.models import build_model
     # abstract mesh: spec-only validation without needing 8 real devices
-    from repro.compat import abstract_mesh
-    mesh = abstract_mesh((2, 4), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh(
+        (2, 4), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     for arch in arch_ids():
         cfg = get_config(arch)
         model = build_model(cfg)

@@ -171,6 +171,18 @@ def test_warm_then_zero_compiles_on_every_shape(stack):
     assert cc.compiles == 0
 
 
+def test_measure_compiles_nests_with_equal_tallies():
+    """An inner span whose tallies equal the outer's (both still zero)
+    detaches its own counter on exit, so the outer one keeps counting."""
+    import jax
+    with measure_compiles() as outer:
+        with measure_compiles() as inner:
+            pass
+        jax.jit(lambda x: x * 3.25 + 0.5)(np.float32(1.0))
+    assert inner.events == 0
+    assert outer.events >= 1
+
+
 def test_warmup_report_accounting(stack):
     svc = _fresh_service(stack)
     reg = ShapeRegistry.from_service(svc, max_batch=2, ks=(3,))
@@ -379,3 +391,32 @@ print(f"RESULT compiles={rep.compiles} hits={rep.persistent_hits} "
     assert int(warm_run["compiles"]) == 0, \
         f"second process recompiled: {warm_run}"
     assert int(warm_run["hits"]) == int(cold["compiles"])
+
+
+@pytest.mark.parametrize("env_set", [True, False], ids=["env", "default"])
+def test_compilation_cache_dir_resolution(env_set, tmp_path):
+    """With no argument the cache lands at $JAX_COMPILATION_CACHE_DIR when
+    set (and nothing overrides jax's own reading of it), else at the fixed
+    in-checkout DEFAULT_CACHE_DIR, which .gitignore covers."""
+    import subprocess
+    import sys
+    from repro.serving.warmup import DEFAULT_CACHE_DIR
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert os.path.dirname(DEFAULT_CACHE_DIR) == root
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert os.path.basename(DEFAULT_CACHE_DIR) + "/" in f.read().split()
+    script = ("import jax\n"
+              "from repro.serving import enable_compilation_cache\n"
+              "got = enable_compilation_cache()\n"
+              "print('RESULT', got, jax.config.jax_compilation_cache_dir)\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = DEFAULT_CACHE_DIR
+    if env_set:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cc")
+    p = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                       text=True, timeout=120, env=env, cwd=tmp_path)
+    assert p.returncode == 0, p.stderr
+    line = [ln for ln in p.stdout.splitlines() if ln.startswith("RESULT")]
+    assert line[0].split()[1:] == [want, want]
+    assert os.path.isdir(want)
