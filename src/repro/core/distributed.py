@@ -97,8 +97,9 @@ def _local_solve(vecs_sel, r_sel, row_mask, vecs_loc, cols_loc, vals_loc, *,
                  use_kernel: bool):
     """Runs on every device under shard_map. Doc axis: local slice; vocab
     axis: local stripe. Returns the (N_local,) WMD slice."""
-    k, km = masked_k(vecs_sel, vecs_loc, lamb, row_mask)
-    k_pad, km_pad = pad_k(k), pad_k(km)
+    with jax.named_scope("wmd.precompute"):
+        k, km = masked_k(vecs_sel, vecs_loc, lamb, row_mask)
+        k_pad, km_pad = pad_k(k), pad_k(km)
     v_r = r_sel.shape[0]
     n_loc = cols_loc.shape[0]
     ones_r = jnp.ones_like(r_sel)
@@ -115,17 +116,21 @@ def _local_solve(vecs_sel, r_sel, row_mask, vecs_loc, cols_loc, vals_loc, *,
         x_full = jax.lax.psum(x_part, model_axis)      # THE collective
         return x_full / r_sel[:, None]
 
-    x0 = jnp.full((v_r, n_loc), 1.0 / v_r, dtype=k.dtype)
-    x = jax.lax.fori_loop(0, max_iter, body, x0)
-    u = safe_recip(x)
+    with jax.named_scope("wmd.iterate"):
+        x0 = jnp.full((v_r, n_loc), 1.0 / v_r, dtype=k.dtype)
+        x = jax.lax.fori_loop(0, max_iter, body, x0)
     # final distance: local xm then scalar-per-doc psum (v_r x cheaper than
     # reducing xm itself)
-    if use_kernel:
-        from repro.kernels import ops
-        wmd_part = ops.sddmm_spmm_type2(k_pad, km_pad, u, cols_loc, vals_loc)
-    else:
-        wmd_part = ss.sddmm_spmm_type2(k_pad, km_pad, u, cols_loc, vals_loc)
-    return jax.lax.psum(wmd_part, model_axis)
+    with jax.named_scope("wmd.final"):
+        u = safe_recip(x)
+        if use_kernel:
+            from repro.kernels import ops
+            wmd_part = ops.sddmm_spmm_type2(k_pad, km_pad, u, cols_loc,
+                                            vals_loc)
+        else:
+            wmd_part = ss.sddmm_spmm_type2(k_pad, km_pad, u, cols_loc,
+                                           vals_loc)
+        return jax.lax.psum(wmd_part, model_axis)
 
 
 # ---------------------------------------------------------------------------
@@ -234,9 +239,12 @@ def build_wmd_batch_fn(mesh: Mesh, *, lamb: float, max_iter: int,
     vote_axes = (model_axis, *doc_axes)
 
     def per_device(vecs_sel, r_sel, row_mask, vecs_loc, cols_b, vals_b):
-        k, km = masked_k_batch(vecs_sel, vecs_loc, lamb, row_mask)
+        with jax.named_scope("wmd.precompute"):
+            k, km = masked_k_batch(vecs_sel, vecs_loc, lamb, row_mask)
+            k_pad, km_pad = pad_k(k), pad_k(km)
+            cols_loc, vals_loc = cols_b[0], vals_b[0]
         wmd, n_iter, delta = _local_batched_solve(
-            pad_k(k), pad_k(km), r_sel, cols_b[0], vals_b[0],
+            k_pad, km_pad, r_sel, cols_loc, vals_loc,
             max_iter=max_iter, model_axis=model_axis, impl=impl,
             docs_chunk=docs_chunk, chunk_placement=chunk_placement, tol=tol,
             vote_axes=vote_axes)
@@ -274,22 +282,25 @@ def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
             x_full = jax.lax.psum(x_part, model_axis)  # THE collective
             return x_full / r_sel[:, :, None]
 
-        if tol:
-            x, delta, n_iter = ss.batched_sinkhorn_loop(
-                iteration, x0_c, max_iter=max_iter, tol=tol,
-                delta_all_reduce=lambda d: jax.lax.pmax(d, vote_axes))
-        else:
-            x = jax.lax.fori_loop(0, max_iter,
-                                  lambda _, xx: iteration(xx), x0_c)
-            delta = jnp.zeros((q,), x0_c.dtype)
-            n_iter = jnp.full((q,), max_iter, jnp.int32)
-        u = safe_recip(x)
-        wmd_part = type2(k_pad, km_pad, u, cols_c, vals_c,
-                         docs_chunk=iter_chunk)
-        return jax.lax.psum(wmd_part, model_axis), n_iter, delta
+        with jax.named_scope("wmd.iterate"):
+            if tol:
+                x, delta, n_iter = ss.batched_sinkhorn_loop(
+                    iteration, x0_c, max_iter=max_iter, tol=tol,
+                    delta_all_reduce=lambda d: jax.lax.pmax(d, vote_axes))
+            else:
+                x = jax.lax.fori_loop(0, max_iter,
+                                      lambda _, xx: iteration(xx), x0_c)
+                delta = jnp.zeros((q,), x0_c.dtype)
+                n_iter = jnp.full((q,), max_iter, jnp.int32)
+        with jax.named_scope("wmd.final"):
+            u = safe_recip(x)
+            wmd_part = type2(k_pad, km_pad, u, cols_c, vals_c,
+                             docs_chunk=iter_chunk)
+            return jax.lax.psum(wmd_part, model_axis), n_iter, delta
 
     n_loc = cols_loc.shape[0]
-    x0 = jnp.full((q, v_r, n_loc), 1.0 / v_r, dtype=k_pad.dtype)
+    with jax.named_scope("wmd.iterate"):
+        x0 = jnp.full((q, v_r, n_loc), 1.0 / v_r, dtype=k_pad.dtype)
     if chunk_placement == "solve" and docs_chunk and docs_chunk < n_loc:
         # unrolled chunk loop (trailing chunk may be smaller -- python
         # slicing keeps shapes static per chunk, no doc padding needed)
@@ -344,8 +355,12 @@ def build_wmd_batch_fn_stripes(mesh: Mesh, *, max_iter: int,
     vote_axes = (model_axis, *doc_axes)
 
     def per_device(k_b, km_b, r_sel, cols_b, vals_b):
+        # the stripes come precomputed: this phase only unpacks the shard
+        with jax.named_scope("wmd.precompute"):
+            k_pad, km_pad = k_b[0], km_b[0]
+            cols_loc, vals_loc = cols_b[0], vals_b[0]
         wmd, n_iter, delta = _local_batched_solve(
-            k_b[0], km_b[0], r_sel, cols_b[0], vals_b[0],
+            k_pad, km_pad, r_sel, cols_loc, vals_loc,
             max_iter=max_iter, model_axis=model_axis, impl=impl,
             docs_chunk=docs_chunk, chunk_placement=chunk_placement, tol=tol,
             vote_axes=vote_axes)

@@ -104,7 +104,8 @@ def pad_k(k: jax.Array) -> jax.Array:
 
 def gather_k(k_pad: jax.Array, cols: jax.Array) -> jax.Array:
     """Gather K columns per ELL slot: (v_r, V+1), (N, nnz) -> (N, nnz, v_r)."""
-    return k_pad.T[cols]
+    with jax.named_scope("wmd.gather"):
+        return k_pad.T[cols]
 
 
 def sddmm(k_pad: jax.Array, u: jax.Array, cols: jax.Array,
@@ -320,7 +321,8 @@ def gather_k_batch(k_pad: jax.Array, cols: jax.Array) -> jax.Array:
     forces XLA to re-lay it out before every dot -- measured ~2.3x slower
     on CPU).
     """
-    return jnp.transpose(k_pad, (0, 2, 1))[:, cols]
+    with jax.named_scope("wmd.gather"):
+        return jnp.transpose(k_pad, (0, 2, 1))[:, cols]
 
 
 # Above this many chunks the doc loop rolls up into a lax.scan: the HLO

@@ -29,17 +29,41 @@ Contract (the whole point of the design):
   failed and degraded requests all end as closed trees with a status --
   the chaos suite asserts submitted == closed with no leaks.
 
-stdlib-only; safe to import from any layer.
+Program spans: :func:`span` names one stage of a call on the profiler's
+clock (a ``jax.profiler.TraceAnnotation``, a host event in the same trace
+as the device's ops) and records ``(name, t0, t1)`` on the tracer's clock
+into the caller's per-call list; the service hands that list back in
+``last_batch_stats["spans"]``. Names carry the ``wmd.`` prefix. With the
+profiler off a span costs one inactive annotation and two clock reads.
+
+stdlib-only at import (jax is imported on the first span); safe to import
+from any layer.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
 from collections import deque
 from typing import Callable
 
-__all__ = ["Tracer", "NullTracer", "NULL_TRACER"]
+__all__ = ["Tracer", "NullTracer", "NULL_TRACER", "span"]
+
+
+@contextlib.contextmanager
+def span(name: str, into: list, **attrs):
+    """Run the block as one profiler annotation ``name`` (``attrs`` are its
+    arguments in the trace viewer) and append ``(name, t0, t1)``, on the
+    tracer's default clock, to ``into`` when it exits, raised or not.
+    Nested spans append before their parent."""
+    from jax.profiler import TraceAnnotation
+    t0 = time.monotonic()
+    try:
+        with TraceAnnotation(name, **attrs):
+            yield
+    finally:
+        into.append((name, t0, time.monotonic()))
 
 
 class NullTracer:

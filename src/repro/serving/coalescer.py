@@ -193,6 +193,21 @@ class _Request:
                                   # lazily expires stale deadline-heap entries
 
 
+def _phase_spans(spans) -> dict[str, tuple[float, float]]:
+    """The engine phases a service call recorded (``last_batch_stats
+    ["spans"]``, see `repro.obs.trace.span`), as the request tree's
+    children: ``precompute`` is the K-cache rows, ``solve`` runs from the
+    first dispatch to the end of the last fetch. A call that recorded no
+    such span has no such child."""
+    out = {}
+    for phase, names in (("precompute", ("wmd.cache_rows",)),
+                         ("solve", ("wmd.dispatch", "wmd.fetch"))):
+        ts = [(t0, t1) for n, t0, t1 in spans if n in names]
+        if ts:
+            out[phase] = (min(t for t, _ in ts), max(t for _, t in ts))
+    return out
+
+
 def _next_pow2(q: int) -> int:
     return 1 << (q - 1).bit_length()
 
@@ -934,10 +949,8 @@ class QueryCoalescer:
             rung = None
             if self._guard is not None and self._guard.dispatch_log:
                 rung = self._guard.dispatch_log[-1][1]
-            pre_s = float(info.get("precompute_s", 0.0)) \
-                if err is None and not is_write else 0.0
-            solve_s = float(info.get("solve_s", 0.0)) \
-                if err is None and not is_write else 0.0
+            ran = err is None and not is_write
+            phases = _phase_spans(info.get("spans") or ()) if ran else {}
             status = ("failed" if err is not None
                       else "degraded" if degraded is not None else "ok")
             for rq in batch:
@@ -945,19 +958,21 @@ class QueryCoalescer:
                     rq.seq, "dispatch", t0, t_done, op=op, cause=cause,
                     batch=len(batch), rung=rung,
                     hit_rate=info.get("hit_rate"),
-                    tier=(degraded.tier if degraded is not None else None))
-                if pre_s:
+                    tier=(degraded.tier if degraded is not None else None),
+                    precompute_s=info.get("precompute_s") if ran else None,
+                    solve_s=info.get("solve_s") if ran else None,
+                    bound_s=prune.get("bound_s"),
+                    rerank_s=prune.get("rerank_s"),
+                    solves_avoided=prune.get("solves_avoided"))
+                if "precompute" in phases:
                     self._tracer.add_span(
-                        rq.seq, "precompute", t0, t0 + pre_s,
+                        rq.seq, "precompute", *phases["precompute"],
                         hits=info.get("hits"), misses=info.get("misses"))
-                if solve_s:
+                if "solve" in phases:
                     self._tracer.add_span(
-                        rq.seq, "solve", t0 + pre_s, t0 + pre_s + solve_s,
+                        rq.seq, "solve", *phases["solve"],
                         n_iter=getattr(getattr(self.svc, "cfg", None),
-                                       "max_iter", None),
-                        bound_s=prune.get("bound_s"),
-                        rerank_s=prune.get("rerank_s"),
-                        solves_avoided=prune.get("solves_avoided"))
+                                       "max_iter", None))
                 self._tracer.end_request(
                     rq.seq, t1=t_done, status=status,
                     deadline_missed=missed_by_seq.get(rq.seq, False),

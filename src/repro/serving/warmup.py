@@ -469,25 +469,28 @@ def warm(svc, registry: ShapeRegistry, *,
     rows_bucket = getattr(svc, "cache_rows_bucket", 128)
     shapes: dict[str, ShapeWarmup] = {}
     t_start = time.perf_counter()
-    for shape in sorted(registry, key=lambda s: (s.q_bucket, s.kind)):
-        batch = [qs[i] for i in range(shape.q_bucket)]
-        t0 = time.perf_counter()
-        with measure_compiles() as counter:
-            if shape.kind == "plain":
-                svc.query_batch(batch, impl=shape.impl)
-            else:
-                rerank = "union" if shape.kind == "top_k_union" \
-                    else "per_query"
-                svc.top_k_batch(batch, shape.k, prune=True,
-                                impl=shape.impl, rerank=rerank)
-                for sweep in _bound_chunk_payloads(
-                        svc.cfg, shape.q_bucket, rows_bucket, seed=seed):
-                    svc.top_k_batch(sweep, shape.k, prune=True,
+    # warm-up is not traffic: a service that counts traffic skips it
+    warming = getattr(svc, "warming", contextlib.nullcontext)
+    with warming():
+        for shape in sorted(registry, key=lambda s: (s.q_bucket, s.kind)):
+            batch = [qs[i] for i in range(shape.q_bucket)]
+            t0 = time.perf_counter()
+            with measure_compiles() as counter:
+                if shape.kind == "plain":
+                    svc.query_batch(batch, impl=shape.impl)
+                else:
+                    rerank = "union" if shape.kind == "top_k_union" \
+                        else "per_query"
+                    svc.top_k_batch(batch, shape.k, prune=True,
                                     impl=shape.impl, rerank=rerank)
-        shapes[shape.label] = ShapeWarmup(
-            shape=shape, wall_s=time.perf_counter() - t0,
-            compiles=counter.compiles, compile_s=counter.compile_s,
-            persistent_hits=counter.persistent_hits,
-            retrieval_s=counter.retrieval_s)
+                    for sweep in _bound_chunk_payloads(
+                            svc.cfg, shape.q_bucket, rows_bucket, seed=seed):
+                        svc.top_k_batch(sweep, shape.k, prune=True,
+                                        impl=shape.impl, rerank=rerank)
+            shapes[shape.label] = ShapeWarmup(
+                shape=shape, wall_s=time.perf_counter() - t0,
+                compiles=counter.compiles, compile_s=counter.compile_s,
+                persistent_hits=counter.persistent_hits,
+                retrieval_s=counter.retrieval_s)
     return WarmupReport(registry=registry, shapes=shapes,
                         wall_s=time.perf_counter() - t_start)

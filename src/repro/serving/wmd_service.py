@@ -118,8 +118,8 @@ Perf knobs (constructor fields):
 
 Cache observability: ``cache_stats`` (cumulative hits / misses / evictions /
 hit_rate) and ``last_batch_stats`` (per-call ``precompute_s`` / ``solve_s``
-phase split + that batch's hit_rate -- the fields the bench artifact
-records). The cache re-keys itself if ``cfg.lamb`` changes between calls
+phase split, read off the call's ``spans`` (see `query_batch`), + that
+batch's hit_rate -- the fields the bench artifact records). The cache re-keys itself if ``cfg.lamb`` changes between calls
 (lambda-invalidation: K rows are keyed by (word_id, lambda)).
 
 `examples/wmd_query_service.py` runs it end-to-end (including a Zipf
@@ -128,6 +128,7 @@ query-stream demo of the cache); `launch/serve.py` exposes it via
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import threading
@@ -150,6 +151,7 @@ from repro.core.distributed import (build_wmd_batch_fn,
                                     build_wmd_batch_fn_stripes, build_wmd_fn,
                                     pad_query, pad_query_batch,
                                     shard_wmd_inputs)
+from repro.obs.trace import span
 # one copy of the pow2 bucket-rounding rule for the whole serving layer:
 # the coalescer's admission buckets must match the service's Q padding
 from repro.serving.coalescer import _next_pow2
@@ -172,6 +174,11 @@ def _serialized(fn):
 
 # sentinel: "use the service's docs_chunk" (None already means unchunked)
 _UNSET = object()
+
+
+def _nonzeros(vals: np.ndarray) -> tuple[int, int]:
+    """(real, all) slots of an ELL values array: a pad slot holds 0."""
+    return int(np.count_nonzero(vals)), int(vals.size)
 
 
 @dataclasses.dataclass
@@ -249,6 +256,17 @@ class WMDService:
         self._prune_fallbacks = self.metrics.counter(
             "wmd_prune_fallback_total",
             "pruned top-k dispatches that fell back to the exact full scan")
+        # the slots a full-distance solve dispatch sweeps: Q_pow2 x v_r
+        # query slots, and every slot of the ELL it gathers K at; "real"
+        # ones carry a query word or a document nonzero
+        self._slots = {
+            (what, kind): self.metrics.counter(
+                f"wmd_{what}_slots_total",
+                f"{what} slots swept by full-distance solve dispatches",
+                labels={"kind": kind})
+            for what in ("query", "ell") for kind in ("real", "pad")}
+        self._ell_nnz = _nonzeros(self._rb.vals)
+        self._warm_local = threading.local()    # see warming()
         # prefilter state: the bound runs replicated on the ORIGINAL
         # (un-rebucketed) ELL -- the min over a doc's words needs the doc's
         # whole support, which vocab re-bucketing splits across shards.
@@ -371,6 +389,7 @@ class WMDService:
                 (self.ell.cols, self.ell.vals), self._replicated)
             self._empty_doc_mask = np.asarray(
                 self.ell.vals.sum(axis=-1) == 0)
+            self._ell_nnz = _nonzeros(self._rb.vals)
             self._cent = None                # tier-0 moments follow the base
             self._live_base_version = lc.base_version
             self._live_version = -1          # gather map must follow
@@ -380,6 +399,7 @@ class WMDService:
                 d_ell, self.mesh.shape["model"])
             self._dcols_d = jax.device_put(drb.cols, self._rerank_spec)
             self._dvals_d = jax.device_put(drb.vals, self._rerank_spec)
+            self._dell_nnz = _nonzeros(drb.vals)
             self._dell_cols_d, self._dell_vals_d = jax.device_put(
                 (d_ell.cols, d_ell.vals), self._replicated)
             ids, seg, row = lc.locations()
@@ -389,53 +409,49 @@ class WMDService:
             self._live_empty = lc.live_empty_mask()
             self._live_version = lc.version
 
-    @_serialized
-    def _query_batch_live(self, rs: Sequence[np.ndarray],
+    def _query_batch_live(self, rs: Sequence[np.ndarray], spans: list,
                           impl: str | None = None,
-                          use_cache: bool | None = None) -> np.ndarray:
+                          use_cache: bool | None = None):
         """(Q, num_live) exact distances over the live corpus, columns in
-        ascending doc-id order. One K-cache stripes assembly feeds one
-        stripes dispatch per non-empty segment; a segment holding no live
-        doc is skipped outright. docs_chunk is forced to None -- segments
-        are capacity-bounded, and per-doc bits are chunking-independent
+        ascending doc-id order, and the route's stats (None for an empty
+        call). One K-cache stripes assembly feeds one stripes dispatch per
+        non-empty segment; a segment holding no live doc is skipped
+        outright. docs_chunk is forced to None -- segments are
+        capacity-bounded, and per-doc bits are chunking-independent
         anyway, so one unchunked program per segment is the simplest
         correct plan."""
-        self._refresh_live()
-        n_live = self._live_ids.size
-        q = len(rs)
+        with span("wmd.prepare", spans):
+            self._refresh_live()
+            n_live = self._live_ids.size
+            q = len(rs)
+            if q and n_live:
+                self._validate_queries(rs)
+                sel_b, r_b, mask_b = self._padded_query_batch(rs)
         if q == 0 or n_live == 0:
             self.last_batch_stats = {}
-            return np.zeros((q, n_live), np.float32)
-        self._validate_queries(rs)
-        sel_b, r_b, mask_b = self._padded_query_batch(rs)
-        self._kcache.ensure_lamb(self.cfg.lamb)
-        use = use_cache is not False
-        t0 = time.perf_counter()
-        k_s, km_s, info = self._kcache.stripes_for_batch(sel_b, mask_b,
-                                                         use_cache=use)
-        jax.block_until_ready((k_s, km_s))
-        t_pre = time.perf_counter() - t0
-        self._check_km(km_s, mask_b)
+            return np.zeros((q, n_live), np.float32), None
+        k_s, km_s, info = self._cache_rows(sel_b, mask_b, use_cache, spans)
         fn = self._stripe_fn(impl or self.impl, None)
         r_d = jnp.asarray(r_b)
         out = np.empty((q, n_live), np.float32)
         segments = 0
-        t0 = time.perf_counter()
-        for seg_id, (cols_d, vals_d) in enumerate(
-                ((self._cols_d, self._vals_d),
-                 (self._dcols_d, self._dvals_d))):
+        for seg_id, (cols_d, vals_d, nnz) in enumerate(
+                ((self._cols_d, self._vals_d, self._ell_nnz),
+                 (self._dcols_d, self._dvals_d, self._dell_nnz))):
             pick = self._live_seg == seg_id
             if not pick.any():
                 continue
-            d_seg = np.asarray(fn(k_s, km_s, r_d, cols_d, vals_d))[:q]
+            with span("wmd.dispatch", spans):
+                self._count_slots(mask_b, nnz)
+                d_seg = fn(k_s, km_s, r_d, cols_d, vals_d)
+            with span("wmd.fetch", spans):
+                d_seg = np.asarray(d_seg)[:q]
             out[:, pick] = d_seg[:, self._live_row[pick]]
             segments += 1
-        t_solve = time.perf_counter() - t0
-        self.last_batch_stats = {"precompute_s": t_pre, "solve_s": t_solve,
-                                 "segments": segments, **info}
-        self._check_result(out, what="live query_batch distances",
-                           empty_doc_mask=self._live_empty)
-        return out
+        with span("wmd.check", spans):
+            self._check_result(out, what="live query_batch distances",
+                               empty_doc_mask=self._live_empty)
+        return out, {"segments": segments, **info}
 
     def _bounds_live(self, rs: Sequence[np.ndarray]) -> np.ndarray:
         """(Q, num_live) RWMD lower bounds over the live corpus: one M-row
@@ -611,15 +627,26 @@ class WMDService:
         """r: (V,) sparse query histogram -> (N,) distances (num_live
         columns in ascending doc-id order on a live service)."""
         if self.live is not None:
-            return self._query_batch_live([r])[0]
-        self._validate_queries([r])
-        sel_idx, r_sel = select_query(r)
-        sel_p, r_p, mask = pad_query(sel_idx, r_sel, self.cfg.v_r)
-        wmd = self._single_fn()(jnp.asarray(self.vecs[sel_p]),
-                                jnp.asarray(r_p), jnp.asarray(mask),
-                                self._vecs_d, self._cols_d, self._vals_d)
-        wmd = np.asarray(wmd)
-        self._check_result(wmd, what="query distances")
+            return self.query_batch([r])[0]
+        return self._solve_one(r, [])
+
+    def _solve_one(self, r: np.ndarray, spans: list) -> np.ndarray:
+        """One query through the per-query program, its stages recorded
+        into ``spans``."""
+        with span("wmd.prepare", spans):
+            self._validate_queries([r])
+            sel_idx, r_sel = select_query(r)
+            sel_p, r_p, mask = pad_query(sel_idx, r_sel, self.cfg.v_r)
+            vecs_sel = self.vecs[sel_p]
+        with span("wmd.dispatch", spans):
+            self._count_slots(mask, self._ell_nnz)
+            wmd = self._single_fn()(jnp.asarray(vecs_sel), jnp.asarray(r_p),
+                                    jnp.asarray(mask), self._vecs_d,
+                                    self._cols_d, self._vals_d)
+        with span("wmd.fetch", spans):
+            wmd = np.asarray(wmd)
+        with span("wmd.check", spans):
+            self._check_result(wmd, what="query distances")
         return wmd
 
     @_serialized
@@ -646,13 +673,32 @@ class WMDService:
         (`_query_batch_live`; docs_chunk is forced unchunked there) --
         (Q, num_live) columns in ascending doc-id order, bitwise identical
         to a one-shot build of the same docs.
+
+        Every route runs as the span ``wmd.query_batch`` with children
+        ``wmd.prepare`` (validate, select and pad, gather the query
+        embeddings on the host), ``wmd.cache_rows`` (the K-cache stripes,
+        stripes and live routes), ``wmd.dispatch`` (the jitted call, which
+        returns once the work is queued), ``wmd.fetch`` (the wait on the
+        device and the copy back) and ``wmd.check`` (the numeric guards);
+        ``last_batch_stats`` carries them under ``spans`` (see `_finish`).
         """
+        spans: list = []
+        with span("wmd.query_batch", spans, q=len(rs)):
+            out, stats = self._query_batch_routed(rs, spans, impl,
+                                                  docs_chunk, use_cache)
+        if stats is not None:
+            self._finish(stats, spans)
+        return out
+
+    def _query_batch_routed(self, rs, spans: list, impl, docs_chunk,
+                            use_cache):
+        """`query_batch`'s routes: (distances, stats for `_finish`), the
+        stats None when the call dispatched nothing."""
         if self.live is not None:
-            return self._query_batch_live(rs, impl=impl,
+            return self._query_batch_live(rs, spans, impl=impl,
                                           use_cache=use_cache)
         if len(rs) == 0:
-            return np.zeros((0, self.ell.num_docs), np.float32)
-        self._validate_queries(rs)
+            return np.zeros((0, self.ell.num_docs), np.float32), None
         # under an armed underflow gate every dispatch routes through the
         # stripes engine so the K*M pre-check (`core.guards.check_km_rows`)
         # sees the assembled rows; off at every shipped lambda, so the
@@ -673,55 +719,108 @@ class WMDService:
             # chunking is result-identical and the sequential route is the
             # faster singleton plan either way.
             # no stripes phase split for this route, but the call must not
-            # vanish from attribution: report total solve wall time with an
+            # vanish from attribution: report the solve time with an
             # explicit phases_separable=False marker
-            t0 = time.perf_counter()
-            out = self.query_batch_sequential(rs)
-            self.last_batch_stats = {
-                "solve_s": time.perf_counter() - t0,
-                "phases_separable": False, "route": "sequential"}
-            return out
-        sel_b, r_b, mask_b = self._padded_query_batch(rs)
+            out = np.stack([self._solve_one(r, spans) for r in rs])
+            return out, {"phases_separable": False, "route": "sequential"}
         q = len(rs)
         dc = self.docs_chunk if docs_chunk is _UNSET else (docs_chunk or None)
-        if use_cache is None and self.cache_capacity == 0 and not risk:
-            # cache disabled and no explicit routing request: the legacy
-            # single-program engine (precompute fused into the solve) is the
-            # faster plan -- the split stripes path pays an extra dispatch
-            # that only the cache can win back. Pass use_cache=True/False to
-            # route a cache-less service through the stripes engine anyway
-            # (e.g. for the bench's phase split).
+        # cache disabled and no explicit routing request: the legacy
+        # single-program engine (precompute fused into the solve) is the
+        # faster plan -- the split stripes path pays an extra dispatch that
+        # only the cache can win back. Pass use_cache=True/False to route a
+        # cache-less service through the stripes engine anyway (e.g. for
+        # the bench's phase split).
+        legacy = use_cache is None and self.cache_capacity == 0 and not risk
+        with span("wmd.prepare", spans):
+            self._validate_queries(rs)
+            sel_b, r_b, mask_b = self._padded_query_batch(rs)
+            if legacy:
+                vecs_b = self.vecs[sel_b]
+        if legacy:
             fn = self._batch_fn(impl or self.impl, dc)
+            with span("wmd.dispatch", spans):
+                self._count_slots(mask_b, self._ell_nnz)
+                wmd = fn(jnp.asarray(vecs_b), jnp.asarray(r_b),
+                         jnp.asarray(mask_b), self._vecs_d, self._cols_d,
+                         self._vals_d)
             # precompute is fused into the solve program here, so the
-            # phases are not separable -- still report the total wall time
+            # phases are not separable -- still report the solve time
             # instead of silently dropping the call from attribution
-            t0 = time.perf_counter()
-            wmd = fn(jnp.asarray(self.vecs[sel_b]), jnp.asarray(r_b),
-                     jnp.asarray(mask_b), self._vecs_d, self._cols_d,
-                     self._vals_d)
+            stats = {"phases_separable": False, "route": "legacy_fused"}
+        else:
+            fn = self._stripe_fn(impl or self.impl, dc)
+            k_s, km_s, stats = self._cache_rows(sel_b, mask_b, use_cache,
+                                                spans)
+            with span("wmd.dispatch", spans):
+                self._count_slots(mask_b, self._ell_nnz)
+                wmd = fn(k_s, km_s, jnp.asarray(r_b), self._cols_d,
+                         self._vals_d)
+        with span("wmd.fetch", spans):
             wmd = np.asarray(wmd)[:q]
-            self.last_batch_stats = {
-                "solve_s": time.perf_counter() - t0,
-                "phases_separable": False, "route": "legacy_fused"}
+        with span("wmd.check", spans):
             self._check_result(wmd, what="query_batch distances")
-            return wmd
-        fn = self._stripe_fn(impl or self.impl, dc)
+        return wmd, stats
+
+    def _cache_rows(self, sel_b, mask_b, use_cache, spans: list):
+        """The K-cache stripes of a padded batch (``use_cache=False`` is
+        the transient baseline), waited for, then the K*M pre-check."""
         self._kcache.ensure_lamb(self.cfg.lamb)   # lambda-invalidation
-        use = use_cache is not False              # False = transient baseline
-        t0 = time.perf_counter()
-        k_s, km_s, info = self._kcache.stripes_for_batch(sel_b, mask_b,
-                                                         use_cache=use)
-        jax.block_until_ready((k_s, km_s))
-        t_pre = time.perf_counter() - t0
-        self._check_km(km_s, mask_b)
-        t0 = time.perf_counter()
-        wmd = np.asarray(fn(k_s, km_s, jnp.asarray(r_b),
-                            self._cols_d, self._vals_d))[:q]
-        t_solve = time.perf_counter() - t0
-        self.last_batch_stats = {"precompute_s": t_pre, "solve_s": t_solve,
-                                 **info}
-        self._check_result(wmd, what="query_batch distances")
-        return wmd
+        with span("wmd.cache_rows", spans):
+            k_s, km_s, info = self._kcache.stripes_for_batch(
+                sel_b, mask_b, use_cache=use_cache is not False)
+            jax.block_until_ready((k_s, km_s))
+        with span("wmd.check", spans):
+            self._check_km(km_s, mask_b)
+        return k_s, km_s, info
+
+    def _finish(self, stats: dict, spans: list) -> None:
+        """Set ``last_batch_stats`` from a call's route stats and spans.
+        The phase split is read off the same spans: ``precompute_s`` is
+        ``wmd.cache_rows`` (routes that have it), ``solve_s`` is
+        ``wmd.dispatch`` plus ``wmd.fetch``. Outside `warming`, every
+        span's seconds go to the ``wmd_span_seconds`` histogram."""
+        def seconds(*names):
+            return sum(t1 - t0 for n, t0, t1 in spans if n in names)
+        stats = dict(stats, solve_s=seconds("wmd.dispatch", "wmd.fetch"),
+                     spans=spans)
+        if any(n == "wmd.cache_rows" for n, _, _ in spans):
+            stats["precompute_s"] = seconds("wmd.cache_rows")
+        self.last_batch_stats = stats
+        if self._warming:
+            return
+        for n, t0, t1 in spans:
+            self.metrics.histogram(
+                "wmd_span_seconds", "seconds in each stage of a service call",
+                labels={"span": n}).observe(t1 - t0)
+
+    def _count_slots(self, mask: np.ndarray, ell_nnz: tuple) -> None:
+        """Count one solve dispatch's swept slots (skipped in warm-up):
+        ``mask`` is its padded query mask, ``ell_nnz`` the (real, all)
+        slots of the ELL segment it gathers over."""
+        if self._warming:
+            return
+        real = int(np.count_nonzero(mask))
+        for what, (n_real, n_all) in (("query", (real, mask.size)),
+                                      ("ell", ell_nnz)):
+            self._slots[what, "real"].inc(n_real)
+            self._slots[what, "pad"].inc(n_all - n_real)
+
+    @property
+    def _warming(self) -> bool:
+        return getattr(self._warm_local, "on", False)
+
+    @contextlib.contextmanager
+    def warming(self):
+        """Mark this thread's dispatches as warm-up (`serving.warmup.warm`):
+        they compile programs and are not traffic, so the slot counters and
+        the span histogram skip them."""
+        prev = self._warming
+        self._warm_local.on = True
+        try:
+            yield
+        finally:
+            self._warm_local.on = prev
 
     def query_batch_sequential(self, rs: Sequence[np.ndarray]) -> np.ndarray:
         """Per-query dispatch loop -- the oracle/baseline for query_batch."""
@@ -866,7 +965,7 @@ class WMDService:
         shared block schedule does not yet span segments) routes here."""
         self._prune_fallbacks.inc()
         t0 = time.perf_counter()
-        d = self._query_batch_live(rs, impl=impl, use_cache=use_cache)
+        d = self.query_batch(rs, impl=impl, use_cache=use_cache)
         q, n = d.shape
         k_eff = min(k, n)
         idx = self._top_k(d, k_eff)
