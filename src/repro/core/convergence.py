@@ -15,10 +15,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sinkhorn import precompute
-from repro.core.sparse_sinkhorn import (_final_batch, _iteration_batch,
-                                        batched_sinkhorn_loop, pad_k,
+from repro.core.sparse_sinkhorn import (batched_sinkhorn_loop, pad_k,
                                         precompute_batch, safe_recip,
-                                        sddmm_spmm_type1, sddmm_spmm_type2)
+                                        sddmm_spmm_type1, sddmm_spmm_type2,
+                                        solve_contractions)
 
 
 class ConvergedWMD(NamedTuple):
@@ -92,7 +92,9 @@ def sinkhorn_wmd_converged_batch(sel_idx: jax.Array, r_sel: jax.Array,
     iteration-major step, bitwise exact) -- unlike the per-solve chunk
     hoisting of `sinkhorn_wmd_sparse_batch` -- because the global per-query
     freeze masks and the reported n_iter/delta are defined over the full
-    doc axis.
+    doc axis. Unchunked, the fused impl gathers K once, before the loop;
+    with a per-op docs_chunk, as in `core.distributed`, it gathers K in
+    every iteration (`sparse_sinkhorn.hoists_k_gather`).
     """
     pre = precompute_batch(sel_idx, r_sel, vecs, lamb, row_mask)
     k_pad = pad_k(pre.K)
@@ -101,12 +103,9 @@ def sinkhorn_wmd_converged_batch(sel_idx: jax.Array, r_sel: jax.Array,
     n = cols.shape[0]
     x0 = jnp.full((q, v_r, n), 1.0 / v_r, dtype=pre.K.dtype)
 
-    def iteration(x):
-        return _iteration_batch(impl, k_pad, pre.r, x, cols, vals,
-                                docs_chunk)
-
-    x, delta, n_iter = batched_sinkhorn_loop(iteration, x0,
-                                             max_iter=max_iter, tol=tol)
-    wmd = _final_batch(impl, k_pad, km_pad, safe_recip(x), cols, vals,
-                       docs_chunk)
+    type1, final = solve_contractions(impl, k_pad, km_pad, pre.r, cols, vals,
+                                      docs_chunk=docs_chunk)
+    x, delta, n_iter = batched_sinkhorn_loop(
+        lambda x: type1(safe_recip(x)), x0, max_iter=max_iter, tol=tol)
+    wmd = final(safe_recip(x))
     return BatchConvergedWMD(wmd=wmd, n_iter=n_iter, delta=delta)

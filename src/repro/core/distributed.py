@@ -150,7 +150,8 @@ def build_wmd_fn(mesh: Mesh, *, lamb: float, max_iter: int,
       vecs     (V, w)                P(model)     -- vocab-striped embeddings
       cols_b   (S_model, N, nnz_loc) P(model, doc_axes) -- rebucketed ELL
       vals_b   (S_model, N, nnz_loc) P(model, doc_axes)
-    and returns wmd (N,) sharded over doc_axes.
+    and returns wmd (N,) sharded over doc_axes. It gathers K in every
+    iteration (its ``k_gathers``, see `_with_k_gathers`).
     """
     doc_spec = P(tuple(doc_axes))
     in_specs = (P(None, None), P(None), P(None),
@@ -169,7 +170,26 @@ def build_wmd_fn(mesh: Mesh, *, lamb: float, max_iter: int,
 
     fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    return jax.jit(fn)
+    return _with_k_gathers(jax.jit(fn), hoisted=False, max_iter=max_iter)
+
+
+def _per_op_chunk(docs_chunk: int | None,
+                  chunk_placement: str) -> int | None:
+    """The chunk of each contraction op inside the Sinkhorn loop: only
+    "iteration" placement chunks per op ("solve" chunks the whole solve)."""
+    return docs_chunk if chunk_placement == "iteration" else None
+
+
+def _with_k_gathers(fn, *, hoisted: bool, max_iter: int):
+    """Attach ``fn.k_gathers``, the (where, count) a dispatch of ``fn`` adds
+    to the service's ``wmd_k_gathers_total``: ("once", 2) where the program
+    gathers K before its Sinkhorn loop and K.*M once in the final pass,
+    else ("per_iteration", max_iter + 2) -- K in each iteration, then K and
+    K.*M in the final pass (under early exit that is the budget, an upper
+    bound on the iterations run)."""
+    fn.k_gathers = ("once", 2) if hoisted else ("per_iteration",
+                                                 max_iter + 2)
+    return fn
 
 
 def build_wmd_batch_fn(mesh: Mesh, *, lamb: float, max_iter: int,
@@ -180,12 +200,14 @@ def build_wmd_batch_fn(mesh: Mesh, *, lamb: float, max_iter: int,
                        with_info: bool = False):
     """Build the jit'd multi-query batched WMD solver for ``mesh``.
 
-    The (Q, v_r, N) analogue of `build_wmd_fn`: per iteration, every device
-    performs ONE shared ELL gather feeding all Q queries' SDDMM and SpMM
-    contractions (`sddmm_spmm_type1_batch`), and the Q solves share the same
-    single psum over ``model`` -- collective count per iteration is
-    independent of Q, so batching amortizes both the gather and the
-    communication latency.
+    The (Q, v_r, N) analogue of `build_wmd_fn`: every device gathers K at
+    its ELL slots ONCE for all Q queries, before the Sinkhorn loop, and
+    each iteration's SDDMM and SpMM contractions read that block
+    (`ss.solve_contractions`; per-op "iteration" chunking and the unfused
+    and kernel impls gather in every iteration instead, `fn.k_gathers`
+    says which). The Q solves share the same single psum over
+    ``model`` -- collective count per iteration is independent of Q, so
+    batching amortizes both the gather and the communication latency.
 
     impl selects the contraction path ("fused" | "unfused" | "kernel", the
     same table as the single-chip solvers). docs_chunk cache-blocks each
@@ -254,7 +276,9 @@ def build_wmd_batch_fn(mesh: Mesh, *, lamb: float, max_iter: int,
 
     fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    return jax.jit(fn)
+    return _with_k_gathers(
+        jax.jit(fn), max_iter=max_iter, hoisted=ss.hoists_k_gather(
+            impl, _per_op_chunk(docs_chunk, chunk_placement)))
 
 
 def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
@@ -270,15 +294,18 @@ def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
     """
     q, v_r = r_sel.shape
     ones_r = jnp.ones_like(r_sel)
-    type1 = ss._resolve_impl("type1", impl, True)
-    type2 = ss._resolve_impl("type2", impl, True)
-    iter_chunk = docs_chunk if chunk_placement == "iteration" else None
+    iter_chunk = _per_op_chunk(docs_chunk, chunk_placement)
 
     def solve_chunk(x0_c, cols_c, vals_c):
+        # the chunk's K block is gathered once, before the loop, where
+        # `ss.hoists_k_gather` allows; per-op ("iteration") chunking keeps
+        # its bounded per-op working set and gathers in the loop
+        type1, type2 = ss.solve_contractions(
+            impl, k_pad, km_pad, ones_r, cols_c, vals_c,
+            docs_chunk=iter_chunk)
+
         def iteration(x):
-            u = safe_recip(x)
-            x_part = type1(k_pad, ones_r, u, cols_c, vals_c,
-                           docs_chunk=iter_chunk)
+            x_part = type1(safe_recip(x))
             x_full = jax.lax.psum(x_part, model_axis)  # THE collective
             return x_full / r_sel[:, :, None]
 
@@ -293,9 +320,7 @@ def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
                 delta = jnp.zeros((q,), x0_c.dtype)
                 n_iter = jnp.full((q,), max_iter, jnp.int32)
         with jax.named_scope("wmd.final"):
-            u = safe_recip(x)
-            wmd_part = type2(k_pad, km_pad, u, cols_c, vals_c,
-                             docs_chunk=iter_chunk)
+            wmd_part = type2(safe_recip(x))
             return jax.lax.psum(wmd_part, model_axis), n_iter, delta
 
     n_loc = cols_loc.shape[0]
@@ -370,7 +395,9 @@ def build_wmd_batch_fn_stripes(mesh: Mesh, *, max_iter: int,
 
     fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    return jax.jit(fn)
+    return _with_k_gathers(
+        jax.jit(fn), max_iter=max_iter, hoisted=ss.hoists_k_gather(
+            impl, _per_op_chunk(docs_chunk, chunk_placement)))
 
 
 def build_wmd_fn_docsharded(mesh: Mesh, *, lamb: float, max_iter: int,

@@ -16,8 +16,9 @@ type2 (final distance) swaps the SpMM operand to K.*M and reduces in-kernel:
 
 Three execution paths, selected by ``impl`` (one table, shared by the
 single-query and the batched solver -- see `_resolve_impl`):
-  * "fused"    -- single gather per iteration (jnp). Production jnp path and
-                  oracle for the Pallas kernel.
+  * "fused"    -- one gather feeds both contractions (jnp); the batched
+                  solvers gather it once per solve (see below). Production
+                  jnp path and oracle for the Pallas kernel.
   * "unfused"  -- separate SDDMM / SpMM with independent gathers, mirroring
                   the paper's pre-fusion baseline (Fig. 9 numerator).
   * "kernel"   -- `repro.kernels.ops` Pallas kernels (interpret=True on CPU).
@@ -28,13 +29,13 @@ All paths consume K padded with one trailing zero column so ELL pad slots
 Batched engine & cache blocking
 -------------------------------
 The batched iteration's nominal working set is the gathered tensor
-``(Q, N, nnz_max, v_r) * 4B`` -- at a bulk shape (Q=16, N=1024, nnz=64,
+``(Q, v_r, N, nnz_max) * 4B`` -- at a bulk shape (Q=16, N=1024, nnz=64,
 v_r=16) that is 64 MB, far past CPU LLC (and any VMEM budget), which is
 where `bench_query_batch.py` showed batched throughput collapsing to
 sequential parity. ``docs_chunk`` cache-blocks the engine at two levels:
 
   * per-op (``sddmm_spmm_type{1,2}_batch(docs_chunk=...)``): the SAME fused
-    math over static N-chunks, live gather ``(Q, docs_chunk, nnz, v_r)``.
+    math over static N-chunks, live gather ``(Q, v_r, docs_chunk, nnz)``.
     Bitwise exact -- every output element's FP op sequence is unchanged
     because both contractions reduce within a single doc (over v_r resp.
     nnz), never across docs. Used inside iteration-major loops that must
@@ -55,6 +56,16 @@ is unrolled in-trace (preserving XLA's gather-into-contraction fusion; a
 lax.scan fallback bounds HLO size past MAX_UNROLLED_CHUNKS). The Pallas
 analogue is the ``docs_blk`` / ``q_blk`` grid tiling in
 `kernels.sddmm_spmm` ("Batched kernel & cache blocking" there).
+
+Gathered once per solve: K[:, :, cols] depends on neither the iterate nor
+the iteration, so the fused batched solvers (`solve_contractions`) gather
+it once, before the Sinkhorn loop, into a (Q, v_r, N, nnz) block (nnz on
+the TPU's 128-wide lane axis) that every iteration and the final pass
+read; the final pass gathers K.*M once more. That holds per solve chunk
+(``docs_chunk`` outside the loop bounds the block); the unfused and kernel
+impls and per-op chunking gather K in every iteration instead
+(`hoists_k_gather`). On one v5e at paper_5k the in-loop gather was 95% of
+the solve.
 
 Early exit: `batched_sinkhorn_loop` is the shared while-loop core -- per
 query, iteration stops contributing writes once its relative iterate delta
@@ -277,9 +288,9 @@ def sinkhorn_wmd_sparse_pre(pre: SinkhornPrecompute, cols: jax.Array,
 # of the *corpus*, identical for every query, so the irregular part of the
 # iteration -- the gather of K columns at the nonzero word-ids -- becomes ONE
 # batched gather op serving all Q queries (same index set, Q stripes), laid
-# out (Q, N, nnz, v_r) so both downstream contractions consume it without
-# transposing (see gather_k_batch). Everything downstream is dense einsum
-# with a leading Q batch axis.
+# out (Q, v_r, N, nnz) for the fused contractions (see gather_k_block), and
+# made once per solve. Everything downstream is dense einsum with a leading
+# Q batch axis.
 #
 # Mixed-size queries ride the exact mask-based padding of core.distributed:
 # pad rows carry r = 1 and a zeroed K row, so they contribute exactly zero
@@ -316,13 +327,54 @@ def gather_k_batch(k_pad: jax.Array, cols: jax.Array) -> jax.Array:
     """One batched gather serving all Q queries.
 
     (Q, v_r, V+1), (N, nnz) -> (Q, N, nnz, v_r): one gather op whose batch
-    dims (q, n) lead, so both downstream contractions consume it with NO
-    transposition of the large tensor (the (N, nnz, Q, v_r) alternative
-    forces XLA to re-lay it out before every dot -- measured ~2.3x slower
-    on CPU).
+    dims (q, n) lead (the (N, nnz, Q, v_r) alternative forces XLA to re-lay
+    it out before every dot -- measured ~2.3x slower on CPU). The unfused
+    baseline and the RWMD bound contract it in this layout; the fused
+    solve relays it out as `gather_k_block`.
     """
     with jax.named_scope("wmd.gather"):
         return jnp.transpose(k_pad, (0, 2, 1))[:, cols]
+
+
+def gather_k_block(k_pad: jax.Array, cols: jax.Array) -> jax.Array:
+    """K at every ELL slot as a (Q, v_r, N, nnz) block: (Q, v_r, V+1),
+    (N, nnz) -> (Q, v_r, N, nnz).
+
+    nnz is the minor axis, so on a TPU the block fills the 128-wide lanes
+    with ELL slots; with v_r (often 32) there it would be padded 4x. This
+    is the layout of the block a solve gathers once and carries through
+    its Sinkhorn loop (`solve_contractions`).
+    """
+    kg = gather_k_batch(k_pad, cols)
+    with jax.named_scope("wmd.gather"):
+        return jnp.transpose(kg, (0, 3, 1, 2))
+
+
+def type1_from_block(kg: jax.Array, r_sel: jax.Array, u: jax.Array,
+                     vals: jax.Array) -> jax.Array:
+    """The fused iteration on a gathered (Q, v_r, N, nnz) K block: SDDMM
+    w[q,n,k] = sum_i kg[q,i,n,k] u[q,i,n], v = vals / w on the support,
+    then SpMM x[q,i,n] = sum_k kg[q,i,n,k] v[q,n,k], scaled by 1/r."""
+    w = jnp.einsum("qink,qin->qnk", kg, u)
+    v = jnp.where(vals[None] != 0.0, vals[None] * safe_recip(w), 0.0)
+    x = jnp.einsum("qink,qnk->qin", kg, v)
+    return x / r_sel[:, :, None]
+
+
+def type2_from_block(kg: jax.Array, kmg: jax.Array, u: jax.Array,
+                     vals: jax.Array) -> jax.Array:
+    """The fused final distance on gathered (Q, v_r, N, nnz) K and K.*M
+    blocks: (Q, N) WMD.
+
+    The per-doc reduction is spelled sum_k v * <(K.*M) col, u> -- the u
+    contraction happens inside the dot_general and the outer reduce runs
+    over the nnz (last) axis, whose extent is chunk-independent. That keeps
+    ``docs_chunk`` bitwise exact.
+    """
+    w = jnp.einsum("qink,qin->qnk", kg, u)
+    v = jnp.where(vals[None] != 0.0, vals[None] * safe_recip(w), 0.0)
+    wm = jnp.einsum("qink,qin->qnk", kmg, u)
+    return jnp.sum(wm * v, axis=-1)                  # (Q, docs)
 
 
 # Above this many chunks the doc loop rolls up into a lax.scan: the HLO
@@ -330,6 +382,14 @@ def gather_k_batch(k_pad: jax.Array, cols: jax.Array) -> jax.Array:
 # inside the loop body (measured up to ~4x slower on CPU) -- callers wanting
 # peak throughput should pick docs_chunk so S stays under this.
 MAX_UNROLLED_CHUNKS = 64
+
+
+def hoists_k_gather(impl: str, per_op_chunk: int | None) -> bool:
+    """Whether a solve gathers K once, before its Sinkhorn loop, instead of
+    once per iteration: the fused impl does, unless its ops are chunked
+    per call (``per_op_chunk``), which exists to bound each op's working
+    set and would be defeated by a whole-slice block."""
+    return impl == "fused" and per_op_chunk is None
 
 
 def _chunk_over_docs(f, u: jax.Array, cols: jax.Array, vals: jax.Array,
@@ -398,25 +458,17 @@ def sddmm_spmm_type1_batch(k_pad: jax.Array, r_sel: jax.Array, u: jax.Array,
                            docs_chunk: int | None = None) -> jax.Array:
     """Batched fused iteration body: (Q, v_r, N) <- one gather, two einsums.
 
-    Same math per query as `sddmm_spmm_type1`; the explicit q-leading einsum
-    spelling compiles to dot_generals whose batch dims (q, n) are already
-    the gathered tensor's leading dims (measured ~2x faster than the
-    vmap-of-single lowering on CPU, ~4x faster than a (N, nnz, Q, v_r)
-    gather layout).
-
-    ``docs_chunk`` scans the same math over N-chunks so the live gathered
-    working set is (Q, docs_chunk, nnz, v_r) -- bitwise identical, see
-    "Batched engine & cache blocking" in the module docstring.
+    Same math per query as `sddmm_spmm_type1`: `gather_k_block` then
+    `type1_from_block`. ``docs_chunk`` runs the same math over N-chunks so
+    the live gathered working set is (Q, v_r, docs_chunk, nnz) -- bitwise
+    identical, see "Batched engine & cache blocking" in the module
+    docstring.
 
     k_pad (Q, v_r, V+1), r_sel (Q, v_r), u (Q, v_r, N), cols/vals (N, nnz).
     """
     def chunk(u_c, cols_c, vals_c):
-        kg = gather_k_batch(k_pad, cols_c)           # the ONLY gather
-        w = jnp.einsum("qnki,qin->qnk", kg, u_c)
-        v = jnp.where(vals_c[None] != 0.0,
-                      vals_c[None] * safe_recip(w), 0.0)
-        x = jnp.einsum("qnki,qnk->qin", kg, v)
-        return x / r_sel[:, :, None]
+        return type1_from_block(gather_k_block(k_pad, cols_c), r_sel, u_c,
+                                vals_c)
 
     return _chunk_over_docs(chunk, u, cols, vals, docs_chunk,
                             pad_col=k_pad.shape[-1] - 1)
@@ -425,39 +477,45 @@ def sddmm_spmm_type1_batch(k_pad: jax.Array, r_sel: jax.Array, u: jax.Array,
 def sddmm_spmm_type2_batch(k_pad: jax.Array, km_pad: jax.Array, u: jax.Array,
                            cols: jax.Array, vals: jax.Array, *,
                            docs_chunk: int | None = None) -> jax.Array:
-    """Batched fused final distance: (Q, N) WMD for all queries at once.
-
-    The per-doc reduction is spelled sum_k v * <(K.*M) col, u> -- i.e. the
-    u contraction happens inside the dot_general and the outer reduce runs
-    over the nnz (last) axis, whose extent is chunk-independent. That keeps
-    ``docs_chunk`` bitwise exact: a reduce over the v_r (middle) axis would
-    let XLA's CPU emitter reassociate differently per doc-chunk shape.
-    """
+    """Batched fused final distance: (Q, N) WMD for all queries at once:
+    `gather_k_block` of K and of K.*M, then `type2_from_block`."""
     def chunk(u_c, cols_c, vals_c):
-        kg = gather_k_batch(k_pad, cols_c)
-        kmg = gather_k_batch(km_pad, cols_c)
-        w = jnp.einsum("qnki,qin->qnk", kg, u_c)
-        v = jnp.where(vals_c[None] != 0.0,
-                      vals_c[None] * safe_recip(w), 0.0)
-        wm = jnp.einsum("qnki,qin->qnk", kmg, u_c)
-        return jnp.sum(wm * v, axis=-1)              # (Q, docs)
+        return type2_from_block(gather_k_block(k_pad, cols_c),
+                                gather_k_block(km_pad, cols_c), u_c, vals_c)
 
     return _chunk_over_docs(chunk, u, cols, vals, docs_chunk,
                             pad_col=k_pad.shape[-1] - 1)
 
 
-def _iteration_batch(impl: str, k_pad: jax.Array, r_sel: jax.Array,
-                     x: jax.Array, cols: jax.Array, vals: jax.Array,
-                     docs_chunk: int | None = None) -> jax.Array:
-    return _resolve_impl("type1", impl, True)(
-        k_pad, r_sel, safe_recip(x), cols, vals, docs_chunk=docs_chunk)
+def solve_contractions(impl: str, k_pad: jax.Array, km_pad: jax.Array,
+                       r_sel: jax.Array, cols: jax.Array, vals: jax.Array, *,
+                       docs_chunk: int | None = None, hoist: bool = True):
+    """The (iteration, final) contractions of one solve over one doc slice:
+    ``iteration(u)`` -> (Q, v_r, N) x, ``final(u)`` -> (Q, N) distances.
 
+    Where `hoists_k_gather` allows (and ``hoist``), K is gathered here,
+    once, into a (Q, v_r, N, nnz) block under ``wmd.precompute`` that
+    every iteration and the final pass read; the final pass gathers K.*M
+    once more. Otherwise both run the impl table's per-call ops, which
+    gather K again on every call (``docs_chunk`` per-op chunking;
+    ``hoist=False`` forces this path for the fused impl too). Call it
+    outside the Sinkhorn loop: the block is then a loop invariant.
+    """
+    if hoist and hoists_k_gather(impl, docs_chunk):
+        with jax.named_scope("wmd.precompute"):
+            kg = gather_k_block(k_pad, cols)
 
-def _final_batch(impl: str, k_pad: jax.Array, km_pad: jax.Array,
-                 u: jax.Array, cols: jax.Array, vals: jax.Array,
-                 docs_chunk: int | None = None) -> jax.Array:
-    return _resolve_impl("type2", impl, True)(
-        k_pad, km_pad, u, cols, vals, docs_chunk=docs_chunk)
+        def final(u):
+            return type2_from_block(kg, gather_k_block(km_pad, cols), u,
+                                    vals)
+
+        return (lambda u: type1_from_block(kg, r_sel, u, vals)), final
+    type1 = _resolve_impl("type1", impl, True)
+    type2 = _resolve_impl("type2", impl, True)
+    return (lambda u: type1(k_pad, r_sel, u, cols, vals,
+                            docs_chunk=docs_chunk),
+            lambda u: type2(k_pad, km_pad, u, cols, vals,
+                            docs_chunk=docs_chunk))
 
 
 def batched_sinkhorn_loop(iteration, x0: jax.Array, *, max_iter: int,
@@ -560,9 +618,13 @@ def _solve_batch_stripes(k_pad: jax.Array, km_pad: jax.Array,
         # OUTSIDE the whole solve: each chunk runs all its iterations while
         # its (Q, v_r, docs_chunk) iterate stays cache-resident -- measured
         # 1.5-3.3x over the iteration-major unchunked loop at bulk shapes
-        # on CPU (see "Batched engine & cache blocking").
+        # on CPU (see "Batched engine & cache blocking"). The chunk's K
+        # block is gathered here, once, where `hoists_k_gather` allows.
+        type1, final = solve_contractions(impl, k_pad, km_pad, r_sel,
+                                          cols_c, vals_c)
+
         def iteration(x):
-            return _iteration_batch(impl, k_pad, r_sel, x, cols_c, vals_c)
+            return type1(safe_recip(x))
 
         if tol:
             x, _, _ = batched_sinkhorn_loop(iteration, x0_c,
@@ -572,8 +634,7 @@ def _solve_batch_stripes(k_pad: jax.Array, km_pad: jax.Array,
             # entirely (it could never fire -- delta >= 0.0 always holds)
             x = jax.lax.fori_loop(0, max_iter,
                                   lambda _, xx: iteration(xx), x0_c)
-        return _final_batch(impl, k_pad, km_pad, safe_recip(x),
-                            cols_c, vals_c)
+        return final(safe_recip(x))
 
     return _chunk_over_docs(solve_chunk, x0, cols, vals, docs_chunk,
                             pad_col=k_pad.shape[-1] - 1)

@@ -83,8 +83,8 @@ Service API
 Perf knobs (constructor fields):
   impl           -- default contraction path for query_batch.
   docs_chunk     -- cache-block the batched iteration over doc chunks of
-                    this size; at bulk shapes this keeps the (Q, docs_chunk,
-                    nnz, v_r) gathered working set cache-resident (see
+                    this size; at bulk shapes this keeps the (Q, v_r,
+                    docs_chunk, nnz) gathered working set cache-resident (see
                     core.sparse_sinkhorn "Batched engine & cache blocking").
   tol            -- early-exit tolerance: converged queries freeze, the
                     solve stops when all queries converge (0.0 = fixed
@@ -265,6 +265,16 @@ class WMDService:
                 f"{what} slots swept by full-distance solve dispatches",
                 labels={"kind": kind})
             for what in ("query", "ell") for kind in ("real", "pad")}
+        # the gathers of K at every ELL slot those dispatches make, as each
+        # solve program states them (its ``k_gathers``): "once" where it
+        # gathers K before its Sinkhorn loop, "per_iteration" where it
+        # gathers K again in every iteration
+        self._k_gathers = {
+            where: self.metrics.counter(
+                "wmd_k_gathers_total",
+                "gathers of K at every ELL slot by full-distance solve "
+                "dispatches", labels={"where": where})
+            for where in ("once", "per_iteration")}
         self._ell_nnz = _nonzeros(self._rb.vals)
         self._warm_local = threading.local()    # see warming()
         # prefilter state: the bound runs replicated on the ORIGINAL
@@ -442,7 +452,7 @@ class WMDService:
             if not pick.any():
                 continue
             with span("wmd.dispatch", spans):
-                self._count_slots(mask_b, nnz)
+                self._count_dispatch(mask_b, nnz, fn)
                 d_seg = fn(k_s, km_s, r_d, cols_d, vals_d)
             with span("wmd.fetch", spans):
                 d_seg = np.asarray(d_seg)[:q]
@@ -639,10 +649,11 @@ class WMDService:
             sel_p, r_p, mask = pad_query(sel_idx, r_sel, self.cfg.v_r)
             vecs_sel = self.vecs[sel_p]
         with span("wmd.dispatch", spans):
-            self._count_slots(mask, self._ell_nnz)
-            wmd = self._single_fn()(jnp.asarray(vecs_sel), jnp.asarray(r_p),
-                                    jnp.asarray(mask), self._vecs_d,
-                                    self._cols_d, self._vals_d)
+            fn = self._single_fn()
+            self._count_dispatch(mask, self._ell_nnz, fn)
+            wmd = fn(jnp.asarray(vecs_sel), jnp.asarray(r_p),
+                     jnp.asarray(mask), self._vecs_d, self._cols_d,
+                     self._vals_d)
         with span("wmd.fetch", spans):
             wmd = np.asarray(wmd)
         with span("wmd.check", spans):
@@ -659,8 +670,9 @@ class WMDService:
         With the cache enabled, the precompute phase dedups word-ids across
         the whole batch and computes only rows missing from the cross-query
         cache; cache-less services run the legacy fused-precompute program.
-        The solve runs one ELL gather and one psum per Sinkhorn iteration
-        for the whole batch either way. Q is rounded up to a power of two
+        The fused solve gathers K at the ELL slots once for the whole batch
+        (`core.sparse_sinkhorn.hoists_k_gather`) and runs one psum per
+        Sinkhorn iteration either way. Q is rounded up to a power of two
         (retrace bound), with the filler slots masked to contribute exactly
         zero. ``impl`` / ``docs_chunk`` override the service defaults for
         this call (pass docs_chunk=0 for explicitly unchunked);
@@ -740,7 +752,7 @@ class WMDService:
         if legacy:
             fn = self._batch_fn(impl or self.impl, dc)
             with span("wmd.dispatch", spans):
-                self._count_slots(mask_b, self._ell_nnz)
+                self._count_dispatch(mask_b, self._ell_nnz, fn)
                 wmd = fn(jnp.asarray(vecs_b), jnp.asarray(r_b),
                          jnp.asarray(mask_b), self._vecs_d, self._cols_d,
                          self._vals_d)
@@ -753,7 +765,7 @@ class WMDService:
             k_s, km_s, stats = self._cache_rows(sel_b, mask_b, use_cache,
                                                 spans)
             with span("wmd.dispatch", spans):
-                self._count_slots(mask_b, self._ell_nnz)
+                self._count_dispatch(mask_b, self._ell_nnz, fn)
                 wmd = fn(k_s, km_s, jnp.asarray(r_b), self._cols_d,
                          self._vals_d)
         with span("wmd.fetch", spans):
@@ -794,10 +806,15 @@ class WMDService:
                 "wmd_span_seconds", "seconds in each stage of a service call",
                 labels={"span": n}).observe(t1 - t0)
 
-    def _count_slots(self, mask: np.ndarray, ell_nnz: tuple) -> None:
-        """Count one solve dispatch's swept slots (skipped in warm-up):
-        ``mask`` is its padded query mask, ``ell_nnz`` the (real, all)
-        slots of the ELL segment it gathers over."""
+    def _count_dispatch(self, mask: np.ndarray, ell_nnz: tuple,
+                        fn) -> None:
+        """Count one solve dispatch's swept slots and gathers of K (skipped
+        in warm-up): ``mask`` is its padded query mask, ``ell_nnz`` the
+        (real, all) slots of the ELL segment it gathers over, ``fn`` the
+        solve program, whose ``k_gathers`` (`core.distributed`) states
+        where it gathers K and how often. (The unfused baseline's second
+        gather, of K/r, is the same gather in these programs -- r is folded
+        out of the iteration under shard_map -- and XLA merges the two.)"""
         if self._warming:
             return
         real = int(np.count_nonzero(mask))
@@ -805,6 +822,8 @@ class WMDService:
                                       ("ell", ell_nnz)):
             self._slots[what, "real"].inc(n_real)
             self._slots[what, "pad"].inc(n_all - n_real)
+        where, n = fn.k_gathers
+        self._k_gathers[where].inc(n)
 
     @property
     def _warming(self) -> bool:

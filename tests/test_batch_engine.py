@@ -166,6 +166,103 @@ def test_chunked_solver_matches_unchunked(batch_problem):
 
 
 # ---------------------------------------------------------------------------
+# K gathered once per solve (hoisted) vs once per iteration (in-loop)
+# ---------------------------------------------------------------------------
+
+def _with_filler(p):
+    """The bucket plus one all-pad filler query (the service's Q-bucket
+    filler): pad query rows, a pad query and ELL pad slots all present."""
+    q_pad = lambda a, fill: np.concatenate(
+        [a, np.full((1, a.shape[1]), fill, a.dtype)])
+    return q_pad(p["sel_b"], 0), q_pad(p["r_b"], 1.0), q_pad(p["mask_b"], 0.0)
+
+
+def _stripes_solve(p, sel_b, r_b, mask_b, *, tol, docs_chunk, placement):
+    """`sparse_sinkhorn._solve_batch_stripes`, traced afresh (a patched
+    `solve_contractions` must not hit an earlier trace)."""
+    from repro.core import sparse_sinkhorn as ss
+    del placement                    # always "solve" here
+    pre = precompute_batch(jnp.asarray(sel_b), jnp.asarray(r_b),
+                           jnp.asarray(p["vecs"]), LAMB,
+                           row_mask=jnp.asarray(mask_b))
+    fn = jax.jit(functools.partial(ss._solve_batch_stripes, max_iter=ITERS,
+                                   impl="fused", docs_chunk=docs_chunk,
+                                   tol=tol))
+    args = (pad_k(pre.K), pad_k(pre.KM), pre.r, p["cols"], p["vals"])
+    return np.asarray(fn(*args)), fn.lower(*args).as_text()
+
+
+def _mesh_solve(p, sel_b, r_b, mask_b, *, tol, docs_chunk, placement):
+    """`distributed.build_wmd_batch_fn` on a (1, 1) mesh."""
+    from repro.core import rebucket_for_vocab_shards
+    from repro.core.distributed import build_wmd_batch_fn, shard_wmd_inputs
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
+    rb = rebucket_for_vocab_shards(p["ell"], 1)
+    fn = build_wmd_batch_fn(mesh, lamb=LAMB, max_iter=ITERS, tol=tol,
+                            docs_chunk=docs_chunk,
+                            chunk_placement=placement)
+    args = (jnp.asarray(p["vecs"][sel_b]), jnp.asarray(r_b),
+            jnp.asarray(mask_b),
+            *shard_wmd_inputs(mesh, p["vecs"], rb.cols, rb.vals))
+    return np.asarray(fn(*args)), fn.lower(*args).as_text()
+
+
+def _converged_solve(p, sel_b, r_b, mask_b, *, tol, docs_chunk, placement):
+    """`convergence.sinkhorn_wmd_converged_batch`, traced afresh (past
+    its own jit cache); its docs_chunk is per-op only."""
+    del placement                    # always "iteration" here
+    fn = jax.jit(lambda s, r, m: sinkhorn_wmd_converged_batch.__wrapped__(
+        s, r, p["cols"], p["vals"], jnp.asarray(p["vecs"]), LAMB, ITERS,
+        tol=tol, docs_chunk=docs_chunk, row_mask=m).wmd)
+    args = (jnp.asarray(sel_b), jnp.asarray(r_b), jnp.asarray(mask_b))
+    return np.asarray(fn(*args)), fn.lower(*args).as_text()
+
+
+# (core, docs_chunk, chunk placement): the stripes core chunks the whole
+# solve only, the converged core each op only
+_HOIST_CASES = [
+    pytest.param(solve, dc, placement, id=f"{core}-{cid}")
+    for core, solve in (("stripes", _stripes_solve), ("mesh", _mesh_solve),
+                        ("converged", _converged_solve))
+    for cid, dc, placement in (("unchunked", None, "solve"),
+                               ("solve_chunks", 16, "solve"),
+                               ("iteration_unchunked", None, "iteration"),
+                               ("iteration_chunks", 16, "iteration"))
+    if (core, placement) != ("stripes", "iteration")
+    and (core, placement) != ("converged", "solve")]
+
+
+@pytest.mark.parametrize("tol", [0.0, 1e-3], ids=["fixed", "early_exit"])
+@pytest.mark.parametrize("solve,docs_chunk,placement", _HOIST_CASES)
+def test_hoisted_gather_matches_in_loop(batch_problem, monkeypatch, solve,
+                                        docs_chunk, placement, tol):
+    """The solve that gathers K once (`hoists_k_gather`) matches the one
+    that gathers it every iteration (`solve_contractions(hoist=False)`) to
+    1e-6 relative, with pad query rows, an all-pad query and ELL pad slots;
+    N = 45 so the 16-doc chunks do not divide it. Every solve without a
+    per-op chunk hoists; per-op chunking never does, so its two programs
+    are the same."""
+    from repro.core import convergence
+    from repro.core import sparse_sinkhorn as ss
+    p = batch_problem
+    args = _with_filler(p)
+    kw = dict(tol=tol, docs_chunk=docs_chunk, placement=placement)
+    hoisted, text_once = solve(p, *args, **kw)
+    in_loop_only = functools.partial(ss.solve_contractions, hoist=False)
+    monkeypatch.setattr(ss, "solve_contractions", in_loop_only)
+    monkeypatch.setattr(convergence, "solve_contractions", in_loop_only)
+    in_loop, text_each = solve(p, *args, **kw)
+    per_op_chunked = placement == "iteration" and docs_chunk is not None
+    assert (text_once != text_each) == (not per_op_chunked)
+    err = np.abs(hoisted - in_loop).max() / np.abs(in_loop).max()
+    assert err < 1e-6, err
+    assert hoisted.shape == (5, p["ell"].num_docs)
+    np.testing.assert_array_equal(hoisted[-1], 0.0)
+    np.testing.assert_array_equal(in_loop[-1], 0.0)
+
+
+# ---------------------------------------------------------------------------
 # Early-exit convergence
 # ---------------------------------------------------------------------------
 

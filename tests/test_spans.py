@@ -13,7 +13,8 @@ Contracts pinned here:
   * ``precompute_s`` / ``solve_s`` are read off the same spans, on every
     route;
   * ``wmd_query_slots_total`` / ``wmd_ell_slots_total`` count real and pad
-    slots per solve dispatch, and warm-up is not counted.
+    slots per solve dispatch, ``wmd_k_gathers_total`` the gathers of K it
+    makes (once, or in every iteration), and warm-up is not counted.
 """
 import glob
 import os
@@ -96,11 +97,13 @@ def test_solve_programs_carry_wmd_scopes(route):
     assert ops
     unscoped = [name for name, scopes in ops if not scopes]
     assert unscoped == []
-    # the stripes program's precompute only unpacks its shard: bitcasts
+    # K is gathered once, in the precompute, before the loop (in the
+    # stripes program too, whose stripes come precomputed); the final pass
+    # gathers K.*M
     outer = {scopes[0] for _, scopes in ops}
-    assert outer == set(PHASES[route == "stripes":])
+    assert outer == set(PHASES)
     gathers = {scopes[0] for _, scopes in ops if "wmd.gather" in scopes}
-    assert gathers == {"wmd.iterate", "wmd.final"}
+    assert gathers == {"wmd.precompute", "wmd.final"}
 
 
 def _host_spans(trace_dir):
@@ -190,6 +193,30 @@ def test_slot_counters_match_hand_counts():
     assert snap["wmd_ell_slots_total{kind=pad}"] == 2 * (slots - nnz)
     assert snap["wmd_span_seconds{span=wmd.query_batch}"]["count"] == 2
     assert snap["wmd_span_seconds{span=wmd.dispatch}"]["count"] == 2
+
+
+def test_k_gather_counter_counts_each_solve_dispatch():
+    """A fused full-distance dispatch gathers K once, before its loop, and
+    K.*M once; the unfused baseline and the sequential route gather K in
+    each of max_iter iterations plus the final pass's two. Warm-up adds
+    nothing."""
+    from repro.serving.warmup import ProgramShape, ShapeRegistry, warm
+    svc, rs = _service()
+    warm(svc, ShapeRegistry([ProgramShape("plain", 4)]))
+
+    def gathers():
+        snap = svc.metrics.snapshot()
+        return tuple(snap.get(f"wmd_k_gathers_total{{where={w}}}", 0)
+                     for w in ("once", "per_iteration"))
+    assert gathers() == (0, 0)
+    svc.query_batch(rs)
+    assert gathers() == (2, 0)
+    svc.query_batch(rs, impl="unfused")
+    assert gathers() == (2, tg.MAX_ITER + 2)
+    svc.query_batch(rs[:1])                 # sequential: per-query program
+    assert gathers() == (2, 2 * (tg.MAX_ITER + 2))
+    svc.query_batch(rs, use_cache=False)    # stripes program
+    assert gathers() == (4, 2 * (tg.MAX_ITER + 2))
 
 
 def test_coalescer_phase_children_are_the_service_spans():

@@ -17,6 +17,7 @@ import this file. The persistent compilation cache is off around these
 tests -- a described-chip executable cannot be read back without a chip.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -77,6 +78,43 @@ def mesh_args(topo):
     return mesh, on
 
 
+_CALLED = re.compile(r"\b(calls|to_apply|body|condition)=%?([\w.-]+)")
+_GATHER = re.compile(r"\sgather\(")
+
+
+def _gather_sites(hlo: str) -> tuple[int, int]:
+    """(gathers outside every ``while`` body, gathers inside one) of a
+    compiled HLO text, following fusions and other called computations."""
+    comps: dict[str, list[str]] = {}
+    entry = cur = None
+    for line in hlo.splitlines():
+        m = re.match(r"(ENTRY )?%?([\w.-]+) .*\{$", line)
+        if m and not line.startswith(" "):
+            cur = m.group(2)
+            comps[cur] = []
+            entry = cur if m.group(1) else entry
+        elif cur is not None:
+            comps[cur].append(line)
+
+    def reach(roots, skip_bodies):
+        seen, todo = set(), list(roots)
+        while todo:
+            c = todo.pop()
+            if c not in seen:
+                seen.add(c)
+                todo += [callee for line in comps[c]
+                         for kind, callee in _CALLED.findall(line)
+                         if not (skip_bodies and kind == "body")]
+        return seen
+
+    bodies = [callee for lines in comps.values() for line in lines
+              for kind, callee in _CALLED.findall(line) if kind == "body"]
+    inside = reach(bodies, False)
+    outside = reach([entry], True) - inside
+    return tuple(sum(len(_GATHER.findall(line)) for c in cs
+                     for line in comps[c]) for cs in (outside, inside))
+
+
 def _compile(fn, *args):
     compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()
@@ -85,17 +123,63 @@ def _compile(fn, *args):
     return compiled
 
 
-def test_service_plain_program_compiles(mesh_args):
-    """The plain-request program the service dispatches by default (no K
-    cache: precompute fused into the solve, unchunked, shard_map'd)."""
+def _plain_program(mesh_args, **kw):
     import jax.numpy as jnp
     from repro.core.distributed import build_wmd_batch_fn
     mesh, on = mesh_args
-    fn = build_wmd_batch_fn(mesh, lamb=1.0, max_iter=ITERS)
-    _compile(fn, on((Q, V_R, W)), on((Q, V_R)), on((Q, V_R)),
-             on((V, W), "model", None),
-             on((1, N, NNZ), "model", "data", None, dtype=jnp.int32),
-             on((1, N, NNZ), "model", "data", None))
+    fn = build_wmd_batch_fn(mesh, lamb=1.0, max_iter=ITERS, **kw)
+    return _compile(fn, on((Q, V_R, W)), on((Q, V_R)), on((Q, V_R)),
+                    on((V, W), "model", None),
+                    on((1, N, NNZ), "model", "data", None, dtype=jnp.int32),
+                    on((1, N, NNZ), "model", "data", None))
+
+
+def test_service_plain_program_compiles(mesh_args):
+    """The plain-request program the service dispatches by default (no K
+    cache: precompute fused into the solve, unchunked, shard_map'd). It
+    gathers K at the ELL slots once, before the Sinkhorn loop, and K.*M
+    once for the final pass; the loop gathers nothing. The block it
+    carries is laid out (Q, v_r, N, nnz): with v_r on the 128-wide lane
+    axis it would be padded 4x, and temp would pass 4 GiB."""
+    compiled = _plain_program(mesh_args)
+    assert _gather_sites(compiled.as_text()) == (2, 0)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def _stripes_program(mesh_args, **kw):
+    import jax.numpy as jnp
+    from repro.core.distributed import build_wmd_batch_fn_stripes
+    mesh, on = mesh_args
+    fn = build_wmd_batch_fn_stripes(mesh, max_iter=ITERS, **kw)
+    return _compile(fn, on((1, Q, V_R, V + 1), "model"),
+                    on((1, Q, V_R, V + 1), "model"), on((Q, V_R)),
+                    on((1, N, NNZ), "model", "data", None, dtype=jnp.int32),
+                    on((1, N, NNZ), "model", "data", None))
+
+
+@pytest.mark.parametrize("build,kw", [
+    pytest.param(_plain_program, {"tol": 1e-3}, id="plain-early_exit"),
+    pytest.param(_stripes_program, {}, id="stripes"),
+    pytest.param(_stripes_program, {"tol": 1e-3}, id="stripes-early_exit"),
+])
+def test_hoisted_programs_gather_outside_loop(mesh_args, build, kw):
+    """The early-exit ``while`` loop and the K-cache stripes program keep
+    the K block outside the loop too: two gathers before it (K, K.*M),
+    none inside, and the lane-dense block's temp."""
+    compiled = build(mesh_args, **kw)
+    assert _gather_sites(compiled.as_text()) == (2, 0)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+@pytest.mark.parametrize("kw", [
+    pytest.param({"impl": "unfused"}, id="unfused"),
+    pytest.param({"docs_chunk": N // 2, "chunk_placement": "iteration"},
+                 id="iteration_chunks"),
+])
+def test_plain_program_gathers_in_loop(mesh_args, kw):
+    """The paper's unfused baseline and per-op ("iteration") chunking keep
+    gathering K inside the Sinkhorn loop."""
+    assert _gather_sites(_plain_program(mesh_args, **kw).as_text())[1] > 0
 
 
 def test_service_rerank_program_compiles(mesh_args):
