@@ -148,6 +148,14 @@ class QueryStream:
     def dense(self, i: int) -> np.ndarray:
         return self._block(i).dense(i % QUERY_BLOCK, self.cfg["vocab_size"])
 
+    def clipped_share(self, n: int) -> float:
+        """Share of the first ``n`` queries whose length was cut at v_r."""
+        if not n:
+            return 0.0
+        self._block(n - 1)
+        return float(np.concatenate([b.clipped for b in self.blocks])[:n]
+                     .mean())
+
 
 def run_bulk(target, stream: QueryStream, traffic: dict, seconds: float):
     req, b = traffic["request"], int(traffic["service"]["max_batch"])
@@ -359,6 +367,7 @@ def run(bm: dict, cell_name: str, *, seed: int, seconds: float,
             "failed": failed, "setup_s": setup_s, "out": out, "ctx": ctx,
             "checks": checks, "checked": checked, "check_s": check_s,
             "memory_peak_bytes": int(peak),
+            "query_clip_share": stream.clipped_share(int(out["attempted"])),
             "setup_compiles": setup_compiles, "warmup": warm_rep,
             "window_compiles": window_compiles,
             "xplane_bytes": res.get("xplane_bytes")}

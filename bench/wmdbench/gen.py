@@ -15,7 +15,17 @@ thousands of documents costs seconds, not minutes:
   lengths (the same nnz), in another order;
 * word counts: integers 1..3, normalised per document.
 
-Queries draw ``words`` distinct Zipf words with counts 1..3, normalised.
+Queries come from one of two sources, each a stream of its own:
+
+* ``zipf``: ``words`` distinct Zipf(``s``) words with counts 1..3,
+  normalised (stream 3);
+* ``documents``: whole documents, drawn as the corpus draws one of the
+  same configuration (its word law, lengths from its ``doc_words`` law by
+  the same quantile-and-shuffle rule, per block of queries, so every block
+  and every seed gets the same multiset of lengths; counts 1..3,
+  normalised), each cut at the service's ``v_r`` words where the law runs
+  longer, since a query holds at most ``v_r`` (stream 6).
+
 Arrivals are a Poisson process made the same way: the exponential's
 quantiles shuffled by the seed, so every seed offers the same gaps in
 another order.
@@ -54,6 +64,7 @@ class Corpus:
 class Queries:
     ids: np.ndarray           # (n, m) int32 word ids, pad = -1
     weights: np.ndarray       # (n, m) float32 frequencies (sum 1), pad = 0
+    clipped: np.ndarray       # (n,) bool: the length was cut at v_r
 
     def __len__(self) -> int:
         return self.ids.shape[0]
@@ -135,20 +146,33 @@ def make_corpus(cfg: dict, num_docs: int, seed: int) -> Corpus:
     return Corpus(vecs=vecs, cols=cols, counts=counts, lengths=lengths)
 
 
+QUERY_SOURCES = ("zipf", "documents")
+
+
 def make_queries(cfg: dict, source: dict, n: int, seed: int, *,
                  block: int = 0) -> Queries:
-    """Block ``block`` of ``n`` queries of ``source``'s Zipf words (``s``,
-    ``words``), drawn by the seed."""
-    rng = rng_for(seed, 3, block)
-    if source["kind"] != "zipf":
+    """Block ``block`` of ``n`` queries of ``source`` (``zipf`` or
+    ``documents``, see the module's docstring), drawn by the seed."""
+    v = int(cfg["vocab_size"])
+    if source["kind"] == "zipf":
+        rng = rng_for(seed, 3, block)
+        cdf = zipf_cdf(v, source["s"])
+        lengths = np.full(n, int(source["words"]))
+        clipped = np.zeros(n, bool)
+    elif source["kind"] == "documents":
+        rng = rng_for(seed, 6, block)
+        cdf = zipf_cdf(v, cfg["word_law"]["s"])
+        lengths = rng.permutation(doc_lengths(n, cfg["doc_words"]))
+        clipped = lengths > int(cfg["v_r"])
+        lengths = np.minimum(lengths, int(cfg["v_r"]))
+    else:
         raise ValueError(f"unknown query source {source['kind']!r}")
-    m = int(source["words"])
-    ids = distinct_draws(rng, zipf_cdf(int(cfg["vocab_size"]), source["s"]),
-                         np.full(n, m))
+    ids = distinct_draws(rng, cdf, lengths)
     cnt = np.where(ids >= 0, rng.integers(1, 4, size=ids.shape), 0)
     cnt = cnt.astype(np.float32)
     weights = cnt / cnt.sum(axis=1, keepdims=True)
-    return Queries(ids=ids.astype(np.int32), weights=weights)
+    return Queries(ids=ids.astype(np.int32), weights=weights,
+                   clipped=clipped)
 
 
 def arrival_times(arrival: dict, seconds: float, seed: int) -> np.ndarray:

@@ -61,6 +61,7 @@ def log_lines(cell_name: str, seed: int, r: dict) -> list[str]:
         "window_compiles": r["window_compiles"].compiles,
         "window_wall_s": out["wall_s"], "attempted": r["attempted"],
         "answered": len(out["answers"]), "failed": r["failed"],
+        "query_clip_share": r["query_clip_share"],
         "generator_late_p95_ms": _pct(late, 95) if late is not None else None,
         "generator_late_max_ms": float(np.max(late))
         if late is not None and len(late) else None,

@@ -18,6 +18,8 @@ import importlib.util
 import json
 import os
 
+from wmdbench import gen
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -50,9 +52,10 @@ def config(bm: dict, cell_entry: dict, root: str = ROOT) -> dict:
 
 
 def traffic(cell_entry: dict, root: str = ROOT) -> dict:
-    """The cell's traffic mix. The schema carries bursts, whole-document
-    queries and writes, which no cell drives yet: a mix that asks for one
-    is refused until the cell that needs it brings its generator."""
+    """The cell's traffic mix. Queries come from one of
+    `gen.QUERY_SOURCES`. The schema also carries bursts and writes, which
+    the generator does not drive yet: a mix that asks for one, or for
+    another query source, is refused, not ignored."""
     path = os.path.join(root, "bench", "traffic",
                         f"{cell_entry['traffic']}.json")
     if not os.path.exists(path):
@@ -60,7 +63,7 @@ def traffic(cell_entry: dict, root: str = ROOT) -> dict:
     t = load_json(path)
     if t["arrival"].get("burst"):
         raise SpecError(f"{path}: bursts are not implemented yet")
-    if t["queries"]["kind"] != "zipf":
+    if t["queries"]["kind"] not in gen.QUERY_SOURCES:
         raise SpecError(f"{path}: query source {t['queries']['kind']!r} "
                         f"is not implemented yet")
     if float(t.get("writes", {}).get("share", 0.0)) > 0:

@@ -1,11 +1,12 @@
 """The harness's ``correct`` on the CPU at a tiny size: the reference is
-the paper's algorithm, sound runs pass, and the control and every fault a
-cell can have (an answer altered where it is produced, half of a batch left
-out, a true neighbour dropped) come out not correct."""
+the paper's algorithm, sound runs of every cell of the tiny checkout pass,
+and the control and every fault a cell can have (an answer altered where
+it is produced, half of a batch left out, a true neighbour dropped) come
+out not correct."""
 import numpy as np
 import pytest
 
-from wmdbench_testing import tiny_root
+from wmdbench_testing import tiny_cells, tiny_root
 
 from wmdbench import cell, reference, report, spec
 
@@ -69,8 +70,7 @@ def test_reference_is_the_papers_algorithm():
     np.testing.assert_allclose(got, want, rtol=2e-5)
 
 
-@pytest.mark.parametrize("name", ["tiny.full_bulk", "tiny.topk_bulk",
-                                  "tiny.topk_open"])
+@pytest.mark.parametrize("name", tiny_cells())
 def test_sound_runs_are_correct(root, name):
     line = _run(root, name, seconds=1.0 if "open" in name else 0.3)
     assert line["correct"], line["checks"]
@@ -119,10 +119,14 @@ def _drop_neighbour(cfg, corpus):
 
 
 @pytest.mark.parametrize("name,fault", [
-    ("tiny.full_bulk", _alter), ("tiny.full_bulk", _half_batch),
-    ("tiny.topk_bulk", _alter), ("tiny.topk_bulk", _half_batch),
-    ("tiny.topk_bulk", _drop_neighbour),
-    ("tiny.topk_open", _drop_neighbour)])
+    ("tiny_paper_5k.full_bulk", _alter),
+    ("tiny_paper_5k.full_bulk", _half_batch),
+    ("tiny_paper_5k.documents_bulk", _alter),
+    ("tiny_paper_5k.documents_bulk", _half_batch),
+    ("tiny_paper_5k.topk_bulk", _alter),
+    ("tiny_paper_5k.topk_bulk", _half_batch),
+    ("tiny_paper_5k.topk_bulk", _drop_neighbour),
+    ("tiny_paper_5k.topk_open", _drop_neighbour)])
 def test_a_broken_timed_path_is_not_correct(root, name, fault):
     line = _run(root, name, seconds=1.0 if "open" in name else 0.3,
                 answer=fault)
@@ -130,7 +134,9 @@ def test_a_broken_timed_path_is_not_correct(root, name, fault):
     assert any(c["value"] > c["limit"] for c in line["checks"].values())
 
 
-@pytest.mark.parametrize("name", ["tiny.full_bulk", "tiny.topk_bulk"])
+@pytest.mark.parametrize("name", ["tiny_paper_5k.full_bulk",
+                                  "tiny_paper_5k.documents_bulk",
+                                  "tiny_paper_5k.topk_bulk"])
 def test_the_bfloat16_control_is_not_correct(root, name):
     line = _run(root, name, answer=cell.control_answers)
     assert not line["correct"]

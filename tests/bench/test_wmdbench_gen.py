@@ -1,5 +1,6 @@
 """The benchmark's data and traffic generators: paper_5k's statistics,
 and the same inputs from the same seed."""
+import hashlib
 import json
 import os
 
@@ -12,6 +13,7 @@ from wmdbench import gen, spec
 
 PAPER = load(os.path.join(BENCH, "configs", "paper_5k.json"))
 ZIPF = {"kind": "zipf", "s": 1.07, "words": 19}
+DOCS = {"kind": "documents"}
 
 
 def test_corpus_matches_paper_5k_statistics():
@@ -70,19 +72,74 @@ def test_arrivals_same_gaps_in_another_order():
     assert not np.allclose(a, b)
 
 
-@pytest.mark.parametrize("field,value", [
-    (("arrival", "burst"), {"period_s": 2.0, "on_share": 0.25,
-                            "factor": 3.0}),
-    (("queries", "kind"), "documents"),
-    (("writes", "share"), 0.05)])
-def test_traffic_the_generator_does_not_drive_is_refused(tmp_path, field,
-                                                         value):
-    """The schema has bursts, whole-document queries and writes; until a
-    cell drives them, a mix that asks for one is refused, not ignored."""
+def test_zipf_queries_are_the_same_bytes_as_before_documents_came():
+    """A Zipf block of paper_5k's mix, as the generator drew it before the
+    documents source was added (its own stream, 3, is untouched)."""
+    q = gen.make_queries(PAPER, ZIPF, 512, seed=2**31 + 29, block=1)
+    digest = hashlib.sha256(q.ids.tobytes() + q.weights.tobytes())
+    assert digest.hexdigest() == (
+        "0a8e5ce4f02b1cb4e60b63bf7a8c932455a15ff94bf888af1b1f6c1f2799df7b")
+    assert not q.clipped.any()
+
+
+@pytest.mark.parametrize("v_r", [32, 160])
+def test_document_queries_follow_the_document_law(v_r):
+    """Whole-document queries: lengths are the corpus's law (quantiles of
+    the lognormal, clipped at doc_words.max) cut at v_r, the same multiset
+    in every block and seed, in another order; words are distinct and
+    weighted, the weights normalised. At paper_5k's v_r = 32 against a
+    mean of 35, 43% are cut."""
+    cfg = dict(PAPER, v_r=v_r)
+    law = cfg["doc_words"]
+    drawn = gen.doc_lengths(512, law)
+    blocks = [gen.make_queries(cfg, DOCS, 512, seed=s, block=b)
+              for s, b in ((2**31 + 5, 0), (2**31 + 5, 1), (3, 0))]
+    for q in blocks:
+        real = q.ids >= 0
+        lengths = real.sum(axis=1)
+        np.testing.assert_array_equal(np.sort(lengths),
+                                      np.sort(np.minimum(drawn, v_r)))
+        assert q.ids.shape[1] == lengths.max() <= min(law["max"], v_r)
+        assert all(np.unique(row[keep]).size == lengths[i]
+                   for i, (row, keep) in enumerate(zip(q.ids, real)))
+        assert (q.ids < cfg["vocab_size"]).all()
+        assert (q.weights[real] > 0).all() and (q.weights[~real] == 0).all()
+        np.testing.assert_allclose(q.weights.sum(axis=1), 1.0, rtol=1e-6)
+        assert q.clipped.sum() == (drawn > v_r).sum()
+        assert (lengths[q.clipped] == v_r).all()
+    assert q.clipped.mean() == (pytest.approx(0.43, abs=0.01)
+                                if v_r == 32 else 0.0)
+    firsts = [(q.ids >= 0).sum(axis=1)[:16] for q in blocks]
+    assert not np.array_equal(firsts[0], firsts[1])
+    again = gen.make_queries(cfg, DOCS, 512, seed=2**31 + 5, block=1)
+    np.testing.assert_array_equal(again.ids, blocks[1].ids)
+    np.testing.assert_array_equal(again.weights, blocks[1].weights)
+
+
+def _mix(tmp_path, field, value):
     t = load(os.path.join(BENCH, "traffic", "full_bulk.json"))
     t[field[0]][field[1]] = value
     os.makedirs(tmp_path / "bench" / "traffic")
     with open(tmp_path / "bench" / "traffic" / "mix.json", "w") as f:
         json.dump(t, f)
+    return t
+
+
+def test_a_documents_mix_is_accepted(tmp_path):
+    want = _mix(tmp_path, ("queries", "kind"), "documents")
+    assert spec.traffic({"traffic": "mix"}, str(tmp_path)) == want
+
+
+@pytest.mark.parametrize("field,value", [
+    (("arrival", "burst"), {"period_s": 2.0, "on_share": 0.25,
+                            "factor": 3.0}),
+    (("queries", "kind"), "sentences"),
+    (("writes", "share"), 0.05)])
+def test_traffic_the_generator_does_not_drive_is_refused(tmp_path, field,
+                                                         value):
+    """The schema has bursts and writes, which no generator drives yet,
+    and a mix may name a query source that does not exist: a mix that asks
+    for one is refused, not ignored."""
+    _mix(tmp_path, field, value)
     with pytest.raises(spec.SpecError, match="not implemented"):
         spec.traffic({"traffic": "mix"}, str(tmp_path))

@@ -135,14 +135,17 @@ def root(tmp_path_factory):
     return tiny_root(str(tmp_path_factory.mktemp("tiny")))
 
 
-def test_a_traced_tiny_run_reads_the_program(root):
-    """A traced full-distance run on the CPU: the slot shares equal the
+@pytest.mark.parametrize("name", ["tiny_paper_5k.full_bulk",
+                                  "tiny_paper_5k.documents_bulk"])
+def test_a_traced_tiny_run_reads_the_program(root, name):
+    """A traced full-distance run on the CPU, of Zipf queries and of
+    whole-document queries of mixed lengths: the slot shares equal the
     hand counts of the window's batches, warm-up left out, and the span
     histogram counts one ``wmd.query_batch`` per batch."""
     import jax
     from wmdbench import cell, gen
     bm = spec.load_benchmark(root)
-    name, seed = "tiny.full_bulk", 2**31 + 99
+    seed = 2**31 + 99
     r = cell.run(bm, name, seed=seed, seconds=0.3, trace=True,
                  devices=jax.devices(), t_start=time.perf_counter(),
                  root=root)
