@@ -1,10 +1,11 @@
 """End-to-end check that the Sinkhorn-WMD service runs on a TPU.
 
-Drives the paper_5k deployment (`configs/sinkhorn_wmd.py`: V=100 000 words,
-300-d embeddings, N=5000 docs, v_r=32, lambda=1, 15 iterations) through the
-service's normal path -- `WMDService` with `launch/serve.py`'s defaults,
-behind the async coalescer, warmed through the shape registry -- and checks
-what comes back:
+Drives a deployment of `configs/sinkhorn_wmd.py` (``--preset``: paper_5k,
+V=100 000 words, 300-d embeddings, N=5000 docs, v_r=32, lambda=1, 15
+iterations; or news20, V=29 671, N=11 293 docs of 72 words on average,
+v_r=288) through the service's normal path -- `WMDService` with
+`launch/serve.py`'s defaults, behind the async coalescer, warmed through
+the shape registry -- and checks what comes back:
 
   a. Zipf full-distance requests (``submit``);
   b. the same queries as pruned top-k (``submit_top_k``) and through the
@@ -12,6 +13,10 @@ what comes back:
   c. a live corpus (``WMDService.from_live``): writes through the writer
      lane, one of them a doc equal to a query, whose top-1 must then be
      that doc; a removed doc must leave the answers.
+
+news20 runs phase a alone, on one chip: its queries are whole documents
+of the corpus's own law, held out of it, and its solve is chunked over
+documents by the service's memory plan.
 
 Every distance that is checked is compared with
 `core.sinkhorn.sinkhorn_wmd_dense` run on the host CPU backend under
@@ -21,6 +26,7 @@ every doc that landed in a top-k. Every check is fatal.
     python chip_smoke.py            # one chip: phases a, b, c
     python chip_smoke.py --chips 4  # a and b on a (4, 1) doc-sharded mesh,
                                     # compared with the same on one chip
+    python chip_smoke.py --preset news20   # one chip: phase a
 
 The last line of stdout is ``{"ok": true, "device": {...}}``. Without a TPU
 the script exits non-zero before doing anything and prints no such line.
@@ -113,22 +119,30 @@ def compare(what: str, got, ref) -> dict:
 
 # -- the phases ----------------------------------------------------------------
 
-def serve_static(svc, qs):
-    """Phases a and b through one coalescer. Returns the coalesced rows, the
-    pruned and scanned top-k, the warmup report and per-phase wall seconds
-    (results arrive as host arrays, so each wall covers the device work)."""
+def serve_static(svc, qs, top_k=True):
+    """Phases a and b (a alone without ``top_k``) through one coalescer.
+    Returns the coalesced rows, the pruned and scanned top-k, the warmup
+    report and per-phase wall seconds (results arrive as host arrays, so
+    each wall covers the device work)."""
     import numpy as np
     from repro.serving import DegradedResult
     fallbacks = svc.metrics.counter("wmd_prune_fallback_total")
     co = svc.async_service(window_ms=WINDOW_MS, max_batch=MAX_BATCH,
                            metrics=svc.metrics)
     with co:
-        warm = co.warm_registry(ks=(TOP_K,), queries=qs)
+        warm = co.warm_registry(ks=(TOP_K,) if top_k else (), queries=qs)
         t0 = time.perf_counter()
         futs = [co.submit(r) for r in qs]
         co.drain()
         rows = [f.result() for f in futs]
         wall_a = time.perf_counter() - t0
+        if not top_k:
+            rows = np.stack(rows)
+            expect(co.stats().failed == 0 and rows.shape == (
+                len(qs), svc.ell.num_docs) and np.isfinite(rows).all(),
+                "full-distance rows failed or not finite")
+            return {"rows": rows, "warm": warm,
+                    "wall": {"a_full_distance": wall_a}}
         t0 = time.perf_counter()
         futs = [co.submit_top_k(r, TOP_K) for r in qs]
         co.drain()
@@ -162,19 +176,24 @@ def serve_static(svc, qs):
 
 def check_static(ref, data, qs, res, sample, label):
     """Served rows at the sample plus every top-k doc, and the top-k
-    distances, against the reference."""
+    distances (where phase b ran), against the reference."""
     import numpy as np
     got_rows, got_topk, want_rows, want_topk = [], [], [], []
+    top = res.get("idx")
     for i, r in enumerate(qs):
-        ids = list(sample) + [j for j in res["idx"][i] if j not in sample]
+        ids = list(sample) + ([] if top is None else
+                              [j for j in top[i] if j not in sample])
         d = ref(r, [ell_doc(data.ell, j) for j in ids])
         got_rows.append(res["rows"][i][ids])
         want_rows.append(d)
-        pos = {j: p for p, j in enumerate(ids)}
-        got_topk.append(res["dist"][i])
-        want_topk.append(d[[pos[j] for j in res["idx"][i]]])
+        if top is not None:
+            pos = {j: p for p, j in enumerate(ids)}
+            got_topk.append(res["dist"][i])
+            want_topk.append(d[[pos[j] for j in top[i]]])
     a = compare(f"{label} phase a", np.concatenate(got_rows),
                 np.concatenate(want_rows))
+    if top is None:
+        return a, None
     b = compare(f"{label} phase b", np.concatenate(got_topk),
                 np.concatenate(want_topk))
     return a, b
@@ -254,22 +273,51 @@ def check_live(ref, qs, res):
     return compare("phase c", res["dist"].ravel(), np.concatenate(want))
 
 
-def run(mesh, cfg, *, seed=SEED, live=True, cmp_mesh=None):
+def document_queries(cfg, seed):
+    """A corpus of ``cfg.num_docs`` documents and MAX_BATCH further ones of
+    the same law as whole-document queries."""
+    import dataclasses
+    import numpy as np
+    from repro.core.formats import EllDocs
+    from repro.data import make_corpus
+    n = cfg.num_docs
+    data = make_corpus(vocab_size=cfg.vocab_size, embed_dim=cfg.embed_dim,
+                       num_docs=n + MAX_BATCH, num_queries=0,
+                       mean_words=cfg.mean_words, seed=seed)
+    qs = []
+    for j in range(n, n + MAX_BATCH):
+        r = np.zeros(cfg.vocab_size, np.float32)
+        ids, w = ell_doc(data.ell, j)
+        r[ids] = w
+        qs.append(r)
+    ell = EllDocs(cols=data.ell.cols[:n], vals=data.ell.vals[:n],
+                  num_vocab=cfg.vocab_size)
+    return dataclasses.replace(data, ell=ell), qs
+
+
+def run(mesh, cfg, *, seed=SEED, live=True, cmp_mesh=None, documents=False):
     """Build paper-shaped data for ``cfg`` and run the phases on ``mesh``.
 
     With ``cmp_mesh`` phases a and b also run on that mesh and both must
     agree (top-k ids equal, distances within the reference tolerance).
-    Returns a dict of measurements; raises SmokeFailure on any check."""
+    With ``documents`` the queries are whole documents and phase a runs
+    alone. Returns a dict of measurements; raises SmokeFailure on any
+    check."""
     import numpy as np
     from repro.data import make_corpus, zipf_query_stream
     from repro.serving import WMDService, measure_compiles
     t0 = time.perf_counter()
-    data = make_corpus(vocab_size=cfg.vocab_size, embed_dim=cfg.embed_dim,
-                       num_docs=cfg.num_docs, num_queries=0,
-                       query_words=min(cfg.v_r - 1, 19), seed=seed)
-    stream = zipf_query_stream(vocab_size=cfg.vocab_size,
-                               query_words=min(cfg.v_r - 1, 13), seed=seed)
-    qs = [next(stream) for _ in range(MAX_BATCH)]
+    if documents:
+        data, qs = document_queries(cfg, seed)
+    else:
+        data = make_corpus(vocab_size=cfg.vocab_size,
+                           embed_dim=cfg.embed_dim, num_docs=cfg.num_docs,
+                           num_queries=0, query_words=min(cfg.v_r - 1, 19),
+                           seed=seed)
+        stream = zipf_query_stream(vocab_size=cfg.vocab_size,
+                                   query_words=min(cfg.v_r - 1, 13),
+                                   seed=seed)
+        qs = [next(stream) for _ in range(MAX_BATCH)]
     sample = np.sort(np.random.default_rng(seed).choice(
         data.ell.num_docs, min(SAMPLE_DOCS, data.ell.num_docs),
         replace=False)).tolist()
@@ -281,7 +329,10 @@ def run(mesh, cfg, *, seed=SEED, live=True, cmp_mesh=None):
     with measure_compiles() as counter:
         for label, m in meshes:
             svc = WMDService(mesh=m, cfg=cfg, vecs=data.vecs, ell=data.ell)
-            results[label] = serve_static(svc, qs)
+            results[label] = serve_static(svc, qs, top_k=not documents)
+            out["plan"] = {"bytes_limit": svc.device_bytes_limit,
+                           "budget_bytes": svc.plan_budget_bytes,
+                           "docs_chunk": svc._docs_chunk(MAX_BATCH)}
             del svc
         live_res = serve_live(mesh, cfg, data, qs) if live else None
     for label, m in meshes:
@@ -289,7 +340,9 @@ def run(mesh, cfg, *, seed=SEED, live=True, cmp_mesh=None):
         a, b = check_static(ref, data, qs, res, sample, label)
         out["phases"][label] = {
             "devices": int(m.devices.size), "wall_s": res["wall"],
-            "warmup": res["warm"].summary(), "ref_a": a, "ref_b": b}
+            "warmup": res["warm"].summary(), "ref_a": a}
+        if b is not None:
+            out["phases"][label]["ref_b"] = b
     if cmp_mesh is not None:
         main, other = results["main"], results["compare"]
         expect(np.array_equal(main["idx"], other["idx"]),
@@ -313,7 +366,13 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: phases a and b on a (4, 1) mesh over four "
                          "chips, compared with one chip (no live phase)")
+    ap.add_argument("--preset", default="paper_5k",
+                    choices=("paper_5k", "news20"),
+                    help="the deployment (configs/sinkhorn_wmd.py); news20 "
+                         "runs phase a alone, on one chip")
     args = ap.parse_args(argv)
+    if args.preset == "news20" and args.chips != 1:
+        ap.error("--preset news20 runs on one chip")
     src = os.path.join(ROOT, "src")
     if not os.path.isdir(os.path.join(src, "repro")):
         print("chip_smoke.py: src/repro not found next to this script; run "
@@ -343,12 +402,14 @@ def main(argv=None) -> int:
     log(f"device_kind={dev.device_kind} platform={dev.platform} "
         f"count={len(devices)} chips_used={args.chips}")
     log(f"compilation cache: {cache_dir}")
-    cfg = wmd_cfg.config("paper_5k")
+    cfg = wmd_cfg.config(args.preset)
     one = make_mesh((1, 1), ("data", "model"), devices=devices[:1])
     t0 = time.perf_counter()
     if args.chips == 4:
         four = make_mesh((4, 1), ("data", "model"), devices=devices[:4])
         out = run(four, cfg, live=False, cmp_mesh=one)
+    elif args.preset == "news20":
+        out = run(one, cfg, live=False, documents=True)
     else:
         out = run(one, cfg)
     wall = time.perf_counter() - t0
@@ -368,10 +429,11 @@ def main(argv=None) -> int:
     log(f"compiles={out['compiles']} compile_s={out['compile_s']:.2f} "
         f"persistent_hits={out['persistent_hits']} setup_s="
         f"{out['setup_s']:.2f} total_wall_s={wall:.2f}")
-    log(f"peak_bytes_in_use={peak}")
+    log(f"peak_bytes_in_use={peak} plan={json.dumps(out['plan'])}")
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out",
-                           f"chip_smoke_{args.chips}chip.json"), "w") as f:
+                           f"chip_smoke_{args.preset}_{args.chips}chip.json"),
+              "w") as f:
         json.dump({"device_kind": dev.device_kind, "chips": args.chips,
                    "peak_bytes_in_use": peak, "total_wall_s": wall, **out},
                   f, indent=1, default=str)

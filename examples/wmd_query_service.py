@@ -62,9 +62,10 @@ def main():
     ap.add_argument("--batch-queries", action="store_true",
                     help="solve all queries in one batched (Q, v_r, N) "
                          "program and report throughput vs the loop")
-    ap.add_argument("--docs-chunk", type=int, default=0,
-                    help="cache-block the batched solve over doc chunks "
-                         "of this size (0 = unchunked)")
+    ap.add_argument("--docs-chunk", type=int, default=None,
+                    help="sweep the batched solve over doc chunks of this "
+                         "size (0 = unchunked; default: the service's plan "
+                         "from the device's memory)")
     ap.add_argument("--zipf-stream", action="store_true",
                     help="serve batches from a Zipf query stream through "
                          "the cross-query K cache and print per-batch "
@@ -131,7 +132,7 @@ def main():
                        query_words=19, seed=0)
     t0 = time.perf_counter()
     svc = WMDService(mesh=mesh, cfg=cfg, vecs=data.vecs, ell=data.ell,
-                     docs_chunk=args.docs_chunk or None,
+                     docs_chunk=args.docs_chunk,
                      prune_chunk=args.prune_chunk,
                      cache_capacity=(args.cache_capacity
                                      if args.zipf_stream or args.coalesce

@@ -8,6 +8,11 @@ motivation):
   paper_5k  -- the paper's measured dataset: V=100k, w=300, N=5000,
                nnz ~ 173k (nnz_max 128), v_r bucket 32, 15 iterations.
   prod_5m   -- the paper's motivating scale: N = 5M docs, same vocab.
+  news20    -- Kusner et al. 2015's 20NEWS kNN classification (ICML 2015,
+               Table 1): V=29 671, w=300, N=11 293 training docs of 72
+               unique words on average (lognormal, clipped at 4x, so the
+               ELL is 288 wide), whole test documents as queries (v_r 288,
+               the longest document, so no query is cut).
 """
 from __future__ import annotations
 
@@ -25,6 +30,7 @@ class WMDConfig:
     lamb: float
     max_iter: int
     num_queries: int = 1  # simultaneous query batch (vmapped)
+    mean_words: float = 35.0  # unique words a generated document holds
 
 
 def config(shape: str = "paper_5k") -> WMDConfig:
@@ -32,6 +38,10 @@ def config(shape: str = "paper_5k") -> WMDConfig:
         return WMDConfig(name="sinkhorn-wmd/paper_5k", vocab_size=100_000,
                          embed_dim=300, num_docs=5_000, nnz_max=128, v_r=32,
                          lamb=1.0, max_iter=15)
+    if shape == "news20":
+        return WMDConfig(name="sinkhorn-wmd/news20", vocab_size=29_671,
+                         embed_dim=300, num_docs=11_293, nnz_max=288,
+                         v_r=288, lamb=1.0, max_iter=15, mean_words=72.0)
     if shape == "prod_5m":
         return WMDConfig(name="sinkhorn-wmd/prod_5m", vocab_size=100_000,
                          embed_dim=300, num_docs=5_242_880, nnz_max=128,

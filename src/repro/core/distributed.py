@@ -213,11 +213,14 @@ def build_wmd_batch_fn(mesh: Mesh, *, lamb: float, max_iter: int,
     same table as the single-chip solvers). docs_chunk cache-blocks each
     device's local doc slice, with ``chunk_placement`` choosing where the
     chunk loop sits (see sparse_sinkhorn "Batched engine & cache blocking"):
-      * "solve" (default) -- chunk loop OUTSIDE the Sinkhorn loop: each
-        chunk runs all its iterations cache-resident. Fastest on CPU /
-        small meshes, but the psum count becomes iterations x chunks, and
-        tol freezes each (query, chunk) block at its own convergence (the
-        reported n_iter/delta are per-query maxima over chunks).
+      * "solve" (default) -- a rolled chunk loop OUTSIDE the Sinkhorn
+        loop: each chunk gathers its own K block and runs all its
+        iterations, so one chunk's blocks are live at a time (how
+        `plan_docs_chunk` bounds memory) and the program holds one
+        Sinkhorn loop whatever the chunk count. The psum count becomes
+        iterations x chunks, and tol freezes each (query, chunk) block at
+        its own convergence (the reported n_iter/delta are per-query
+        maxima over chunks).
       * "iteration" -- per-op chunking inside the iteration-major loop:
         keeps ONE psum per iteration (the multi-chip contract) and global
         per-query freeze semantics exactly matching
@@ -281,6 +284,56 @@ def build_wmd_batch_fn(mesh: Mesh, *, lamb: float, max_iter: int,
             impl, _per_op_chunk(docs_chunk, chunk_placement)))
 
 
+LANES = 128                  # a TPU vreg's minor axis
+
+
+def _ceil_div(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def solve_bytes_per_doc(q: int, v_r: int, nnz_loc: int) -> int:
+    """Device bytes a document adds to the fused batched solve (float32).
+
+    Three (Q, v_r, ·, nnz) blocks, nnz padded to the 128-wide lane axis,
+    are live at once in the final pass: the K block that the Sinkhorn loop
+    carried, and K.*M gathered in the gather's own layout beside its
+    relayout copy (`ss.solve_contractions`); plus the (Q, v_r) iterate and
+    its update. An upper bound: the compiled temp of the news20 program
+    (Q 8, v_r 288, nnz 288: 10.64 MB a document) for one v5e grows by
+    3.005 blocks a document up to 512-document chunks, and holds 1.9 a
+    document at 1 136."""
+    block = q * v_r * _ceil_div(nnz_loc, LANES) * LANES * 4
+    return 3 * block + 2 * q * v_r * 4
+
+
+def solve_fixed_bytes(q: int, v_r: int, v_loc: int) -> int:
+    """Device bytes of the fused batched solve that do not grow with the
+    documents (float32): the (Q, v_r, V+1) K and K.*M stripes over the
+    local vocabulary, and the cost rows they are made from. 0.82 GB at
+    news20 (Q 8, v_r 288, V 29 671), over the 0.76 GB that the compiled
+    program's temp holds beside its document blocks on one v5e."""
+    return 3 * q * v_r * (v_loc + 1) * 4
+
+
+def plan_docs_chunk(q: int, v_r: int, nnz_loc: int, n_loc: int, v_loc: int,
+                    budget_bytes: int | None) -> int | None:
+    """The doc chunk of a fused batched solve over ``n_loc`` local
+    documents and ``v_loc`` local words whose working set must fit
+    ``budget_bytes``: None where the unchunked program fits or no budget
+    is known. Else as many chunks as the largest multiple of 8 documents
+    that fits beside the solve's fixed part needs, each evened out to the
+    next multiple of 8, so the padded last chunk stays nearly full. The
+    chunks are swept by a rolled loop (`_local_batched_solve`)."""
+    per_doc = solve_bytes_per_doc(q, v_r, nnz_loc)
+    if budget_bytes is None:
+        return None
+    budget_bytes -= solve_fixed_bytes(q, v_r, v_loc)
+    if n_loc * per_doc <= budget_bytes:
+        return None
+    fit = max(8, budget_bytes // per_doc // 8 * 8)
+    return _ceil_div(_ceil_div(n_loc, _ceil_div(n_loc, fit)), 8) * 8
+
+
 def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
                          max_iter: int, model_axis: str, impl: str,
                          docs_chunk: int | None, chunk_placement: str,
@@ -324,21 +377,20 @@ def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
             return jax.lax.psum(wmd_part, model_axis), n_iter, delta
 
     n_loc = cols_loc.shape[0]
+    if not (chunk_placement == "solve" and docs_chunk
+            and docs_chunk < n_loc):
+        with jax.named_scope("wmd.iterate"):
+            x0 = jnp.full((q, v_r, n_loc), 1.0 / v_r, dtype=k_pad.dtype)
+        return solve_chunk(x0, cols_loc, vals_loc)
+    # the rolled chunk loop: one chunk's blocks are live at a time, and the
+    # program holds one Sinkhorn loop whatever the chunk count
     with jax.named_scope("wmd.iterate"):
-        x0 = jnp.full((q, v_r, n_loc), 1.0 / v_r, dtype=k_pad.dtype)
-    if chunk_placement == "solve" and docs_chunk and docs_chunk < n_loc:
-        # unrolled chunk loop (trailing chunk may be smaller -- python
-        # slicing keeps shapes static per chunk, no doc padding needed)
-        parts = [solve_chunk(x0[:, :, s:s + docs_chunk],
-                             cols_loc[s:s + docs_chunk],
-                             vals_loc[s:s + docs_chunk])
-                 for s in range(0, n_loc, docs_chunk)]
-        wmd = jnp.concatenate([p[0] for p in parts], axis=-1)
-        n_iter = jnp.max(jnp.stack([p[1] for p in parts]), axis=0)
-        delta = jnp.max(jnp.stack([p[2] for p in parts]), axis=0)
-    else:
-        wmd, n_iter, delta = solve_chunk(x0, cols_loc, vals_loc)
-    return wmd, n_iter, delta
+        x0 = jnp.full((q, v_r, docs_chunk), 1.0 / v_r, dtype=k_pad.dtype)
+    wmd, n_iter, delta = ss.map_doc_chunks(
+        lambda c, v: solve_chunk(x0, c, v), cols_loc, vals_loc, docs_chunk,
+        pad_col=k_pad.shape[-1] - 1)
+    return (ss.join_doc_chunks(wmd, n_loc), jnp.max(n_iter, axis=0),
+            jnp.max(delta, axis=0))
 
 
 def build_wmd_batch_fn_stripes(mesh: Mesh, *, max_iter: int,

@@ -52,8 +52,9 @@ sequential parity. ``docs_chunk`` cache-blocks the engine at two levels:
 
 Non-dividing N is handled by padding docs with ELL pad slots (col = V ->
 the zero K column, val = 0), whose outputs are sliced off. The chunk loop
-is unrolled in-trace (preserving XLA's gather-into-contraction fusion; a
-lax.scan fallback bounds HLO size past MAX_UNROLLED_CHUNKS). The Pallas
+is unrolled in-trace (preserving XLA's gather-into-contraction fusion; the
+rolled `map_doc_chunks` bounds HLO size past MAX_UNROLLED_CHUNKS, and is
+the chunk loop of the served solve, `core.distributed`). The Pallas
 analogue is the ``docs_blk`` / ``q_blk`` grid tiling in
 `kernels.sddmm_spmm` ("Batched kernel & cache blocking" there).
 
@@ -87,6 +88,13 @@ from repro.core.cost_matrix import cdist
 from repro.core.sinkhorn import SinkhornPrecompute, precompute
 
 _IMPLS = ("fused", "unfused", "kernel")
+
+# Every contraction of the solve runs in float32. A TPU's default precision
+# rounds a float32 dot's operands to bfloat16 wherever XLA puts it on the
+# MXU, as it did for the SDDMM (K and u) at news20's v_r 288. Where XLA
+# keeps a contraction on the vector units (paper_5k's v_r 32) this changes
+# nothing.
+F32 = jax.lax.Precision.HIGHEST
 
 # Reciprocal guard: K = exp(-lamb*M) underflows f32 for far word pairs, and
 # the u = 1/x nonlinearity amplifies it to inf*0 = nan. Clamping the
@@ -123,23 +131,23 @@ def sddmm(k_pad: jax.Array, u: jax.Array, cols: jax.Array,
           vals: jax.Array) -> jax.Array:
     """Sampled dense-dense matmul: v[j,k] = vals[j,k] / (K^T u)[cols[j,k], j]."""
     kg = gather_k(k_pad, cols)                       # gather #1
-    w = jnp.einsum("nki,in->nk", kg, u)
+    w = jnp.einsum("nki,in->nk", kg, u, precision=F32)
     return jnp.where(vals != 0.0, vals * safe_recip(w), 0.0)
 
 
 def spmm(kor_pad: jax.Array, v: jax.Array, cols: jax.Array) -> jax.Array:
     """x[i,j] = sum_k K_over_r[i, cols[j,k]] * v[j,k] -- re-gathers K."""
     kg = gather_k(kor_pad, cols)                     # gather #2 (unfused cost)
-    return jnp.einsum("nki,nk->in", kg, v)
+    return jnp.einsum("nki,nk->in", kg, v, precision=F32)
 
 
 def sddmm_spmm_type1(k_pad: jax.Array, r_sel: jax.Array, u: jax.Array,
                      cols: jax.Array, vals: jax.Array) -> jax.Array:
     """Fused iteration body: one gather feeds both contractions."""
     kg = gather_k(k_pad, cols)                       # the ONLY gather
-    w = jnp.einsum("nki,in->nk", kg, u)
+    w = jnp.einsum("nki,in->nk", kg, u, precision=F32)
     v = jnp.where(vals != 0.0, vals * safe_recip(w), 0.0)
-    x = jnp.einsum("nki,nk->in", kg, v)
+    x = jnp.einsum("nki,nk->in", kg, v, precision=F32)
     return x / r_sel[:, None]
 
 
@@ -148,9 +156,9 @@ def sddmm_spmm_type2(k_pad: jax.Array, km_pad: jax.Array, u: jax.Array,
     """Fused final distance: 3 dense (K, K.*M, u) + 2 sparse (cols, vals)."""
     kg = gather_k(k_pad, cols)
     kmg = gather_k(km_pad, cols)
-    w = jnp.einsum("nki,in->nk", kg, u)
+    w = jnp.einsum("nki,in->nk", kg, u, precision=F32)
     v = jnp.where(vals != 0.0, vals * safe_recip(w), 0.0)
-    xm = jnp.einsum("nki,nk->in", kmg, v)
+    xm = jnp.einsum("nki,nk->in", kmg, v, precision=F32)
     return jnp.sum(u * xm, axis=0)                   # (N,)
 
 
@@ -355,9 +363,9 @@ def type1_from_block(kg: jax.Array, r_sel: jax.Array, u: jax.Array,
     """The fused iteration on a gathered (Q, v_r, N, nnz) K block: SDDMM
     w[q,n,k] = sum_i kg[q,i,n,k] u[q,i,n], v = vals / w on the support,
     then SpMM x[q,i,n] = sum_k kg[q,i,n,k] v[q,n,k], scaled by 1/r."""
-    w = jnp.einsum("qink,qin->qnk", kg, u)
+    w = jnp.einsum("qink,qin->qnk", kg, u, precision=F32)
     v = jnp.where(vals[None] != 0.0, vals[None] * safe_recip(w), 0.0)
-    x = jnp.einsum("qink,qnk->qin", kg, v)
+    x = jnp.einsum("qink,qnk->qin", kg, v, precision=F32)
     return x / r_sel[:, :, None]
 
 
@@ -371,13 +379,13 @@ def type2_from_block(kg: jax.Array, kmg: jax.Array, u: jax.Array,
     over the nnz (last) axis, whose extent is chunk-independent. That keeps
     ``docs_chunk`` bitwise exact.
     """
-    w = jnp.einsum("qink,qin->qnk", kg, u)
+    w = jnp.einsum("qink,qin->qnk", kg, u, precision=F32)
     v = jnp.where(vals[None] != 0.0, vals[None] * safe_recip(w), 0.0)
-    wm = jnp.einsum("qink,qin->qnk", kmg, u)
+    wm = jnp.einsum("qink,qin->qnk", kmg, u, precision=F32)
     return jnp.sum(wm * v, axis=-1)                  # (Q, docs)
 
 
-# Above this many chunks the doc loop rolls up into a lax.scan: the HLO
+# Above this many chunks the doc loop rolls up into a lax.map: the HLO
 # stays O(1) in S at the cost of defeating XLA's cross-op gather fusion
 # inside the loop body (measured up to ~4x slower on CPU) -- callers wanting
 # peak throughput should pick docs_chunk so S stays under this.
@@ -404,45 +412,66 @@ def _chunk_over_docs(f, u: jax.Array, cols: jax.Array, vals: jax.Array,
     The chunk loop is UNROLLED into the trace (independent per-chunk chains
     concatenated on the doc axis): each chain keeps XLA's gather-into-
     contraction fusion, so the gathered (Q, docs_chunk, nnz, v_r) block is
-    never materialized whole. A `lax.scan` spelling is kept as fallback for
-    very large chunk counts (> MAX_UNROLLED_CHUNKS) where HLO size matters
-    more than the fusion loss.
+    never materialized whole. Past MAX_UNROLLED_CHUNKS chunks it rolls up
+    into `map_doc_chunks`, where HLO size matters more than the fusion loss.
     """
     n = cols.shape[0]
     if not docs_chunk or docs_chunk >= n:   # None and 0 both mean unchunked
         return f(u, cols, vals)
-    pad = (-n) % docs_chunk
-    if pad:
-        cols = jnp.pad(cols, ((0, pad), (0, 0)), constant_values=pad_col)
-        vals = jnp.pad(vals, ((0, pad), (0, 0)))
-        u = jnp.pad(u, ((0, 0), (0, 0), (0, pad)))
-    s = (n + pad) // docs_chunk
-    if s <= MAX_UNROLLED_CHUNKS:
-        outs = [f(u[:, :, c * docs_chunk:(c + 1) * docs_chunk],
-                  cols[c * docs_chunk:(c + 1) * docs_chunk],
-                  vals[c * docs_chunk:(c + 1) * docs_chunk])
-                for c in range(s)]
-        return jnp.concatenate(outs, axis=-1)[..., :n]
-    q, v_r = u.shape[0], u.shape[1]
-    nnz = cols.shape[1]
-    operand = (jnp.moveaxis(u.reshape(q, v_r, s, docs_chunk), 2, 0),
-               cols.reshape(s, docs_chunk, nnz),
-               vals.reshape(s, docs_chunk, nnz))
+    s = -(-n // docs_chunk)
+    if s > MAX_UNROLLED_CHUNKS:
+        return join_doc_chunks(
+            map_doc_chunks(lambda c, v, u_c: f(u_c, c, v), cols, vals,
+                           docs_chunk, pad_col, u), n)
+    cols, vals, u = _pad_docs(cols, vals, s * docs_chunk - n, pad_col, u)
+    outs = [f(u[:, :, c * docs_chunk:(c + 1) * docs_chunk],
+              cols[c * docs_chunk:(c + 1) * docs_chunk],
+              vals[c * docs_chunk:(c + 1) * docs_chunk])
+            for c in range(s)]
+    return jnp.concatenate(outs, axis=-1)[..., :n]
 
-    def step(_, op):
-        u_c, cols_c, vals_c = op
-        return None, f(u_c, cols_c, vals_c)
 
-    _, out = jax.lax.scan(step, None, operand)       # (S, ..., docs_chunk)
+def _pad_docs(cols, vals, pad: int, pad_col: int, u=None):
+    """Append ``pad`` empty documents: ELL pad slots (col = pad_col, the
+    zero K column; val = 0), which solve to 0; ``u``'s doc axis is last."""
+    if not pad:
+        return cols, vals, u
+    cols = jnp.pad(cols, ((0, pad), (0, 0)), constant_values=pad_col)
+    vals = jnp.pad(vals, ((0, pad), (0, 0)))
+    if u is not None:
+        u = jnp.pad(u, ((0, 0),) * (u.ndim - 1) + ((0, pad),))
+    return cols, vals, u
+
+
+def map_doc_chunks(f, cols: jax.Array, vals: jax.Array, docs_chunk: int,
+                   pad_col: int, u: jax.Array | None = None):
+    """``f(cols_c, vals_c[, u_c])`` over equal doc chunks in one rolled loop
+    (`jax.lax.map`): the doc axis is padded with empty documents
+    (`_pad_docs`) to a whole number of chunks. Returns ``f``'s outputs (any
+    pytree) stacked on a leading chunk axis; `join_doc_chunks` lays a
+    doc-axis output back out. One chunk's working set is live at a time,
+    and the program holds one copy of ``f`` whatever the chunk count."""
+    n, nnz = cols.shape
+    s = -(-n // docs_chunk)
+    cols, vals, u = _pad_docs(cols, vals, s * docs_chunk - n, pad_col, u)
+    ops = (cols.reshape(s, docs_chunk, nnz), vals.reshape(s, docs_chunk, nnz))
+    if u is not None:
+        ops += (jnp.moveaxis(u.reshape(*u.shape[:-1], s, docs_chunk), -2, 0),)
+    return jax.lax.map(lambda op: f(*op), ops)
+
+
+def join_doc_chunks(out: jax.Array, n: int) -> jax.Array:
+    """(S, ..., docs_chunk) per-chunk outputs of `map_doc_chunks` ->
+    (..., n): the chunks laid end to end on the doc axis, pad docs cut."""
     out = jnp.moveaxis(out, 0, -2)
-    return out.reshape(*out.shape[:-2], s * docs_chunk)[..., :n]
+    return out.reshape(*out.shape[:-2], -1)[..., :n]
 
 
 def sddmm_batch(k_pad: jax.Array, u: jax.Array, cols: jax.Array,
                 vals: jax.Array) -> jax.Array:
     """Batched sampled dense-dense matmul with its own gather (unfused)."""
     kg = gather_k_batch(k_pad, cols)                 # gather #1
-    w = jnp.einsum("qnki,qin->qnk", kg, u)
+    w = jnp.einsum("qnki,qin->qnk", kg, u, precision=F32)
     return jnp.where(vals[None] != 0.0, vals[None] * safe_recip(w), 0.0)
 
 
@@ -450,7 +479,7 @@ def spmm_batch(kor_pad: jax.Array, v: jax.Array, cols: jax.Array
                ) -> jax.Array:
     """Batched SpMM -- re-gathers K (the unfused baseline's second gather)."""
     kg = gather_k_batch(kor_pad, cols)               # gather #2 (unfused cost)
-    return jnp.einsum("qnki,qnk->qin", kg, v)
+    return jnp.einsum("qnki,qnk->qin", kg, v, precision=F32)
 
 
 def sddmm_spmm_type1_batch(k_pad: jax.Array, r_sel: jax.Array, u: jax.Array,

@@ -50,9 +50,14 @@ def main():
                     choices=("fused", "unfused", "kernel"),
                     help="sinkhorn-wmd: contraction path for the batched "
                          "engine (kernel = Pallas, interpret on CPU)")
-    ap.add_argument("--docs-chunk", type=int, default=0,
-                    help="sinkhorn-wmd: cache-block the batched iteration "
-                         "over doc chunks of this size (0 = unchunked)")
+    ap.add_argument("--wmd-preset", default="paper_5k",
+                    choices=("paper_5k", "news20"),
+                    help="sinkhorn-wmd: the deployment to serve "
+                         "(configs/sinkhorn_wmd.py; --smoke overrides)")
+    ap.add_argument("--docs-chunk", type=int, default=None,
+                    help="sinkhorn-wmd: sweep the batched solve over doc "
+                         "chunks of this size (0 = unchunked; default: the "
+                         "service's plan from the device's memory)")
     ap.add_argument("--tol", type=float, default=0.0,
                     help="sinkhorn-wmd: early-exit tolerance for the "
                          "batched solve (0 = fixed max_iter)")
@@ -178,9 +183,11 @@ def main():
         if args.ingest_stream and args.coalesce_window_ms <= 0:
             ap.error("--ingest-stream requires --coalesce-window-ms > 0 "
                      "(writes go through the coalescer's writer lane)")
-        cfg = wmd_cfg.smoke_config() if args.smoke else wmd_cfg.config()
+        cfg = wmd_cfg.smoke_config() if args.smoke else \
+            wmd_cfg.config(args.wmd_preset)
         data = make_corpus(vocab_size=cfg.vocab_size,
                            embed_dim=cfg.embed_dim, num_docs=cfg.num_docs,
+                           mean_words=cfg.mean_words,
                            num_queries=args.num_queries,
                            query_words=min(cfg.v_r - 1, 19))
         if args.ingest_stream:
@@ -205,7 +212,7 @@ def main():
         else:
             svc = WMDService(mesh=mesh, cfg=cfg, vecs=data.vecs,
                              ell=data.ell, impl=args.impl,
-                             docs_chunk=args.docs_chunk or None,
+                             docs_chunk=args.docs_chunk,
                              tol=args.tol)
         if args.offline:
             _serve_wmd_offline(svc, args)

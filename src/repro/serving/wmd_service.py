@@ -82,10 +82,12 @@ Service API
 
 Perf knobs (constructor fields):
   impl           -- default contraction path for query_batch.
-  docs_chunk     -- cache-block the batched iteration over doc chunks of
-                    this size; at bulk shapes this keeps the (Q, v_r,
-                    docs_chunk, nnz) gathered working set cache-resident (see
-                    core.sparse_sinkhorn "Batched engine & cache blocking").
+  docs_chunk     -- sweep the batched solve over doc chunks of this size
+                    (0 = unchunked). None (the default) plans it per Q
+                    bucket from the device's memory
+                    (`core.distributed.plan_docs_chunk`, budget
+                    `plan_budget_bytes`): unchunked wherever the solve's
+                    (Q, v_r, N, nnz) blocks fit.
   tol            -- early-exit tolerance: converged queries freeze, the
                     solve stops when all queries converge (0.0 = fixed
                     max_iter).
@@ -150,7 +152,8 @@ from repro.core.kcache import KCache, MCache
 from repro.core.distributed import (build_wmd_batch_fn,
                                     build_wmd_batch_fn_stripes, build_wmd_fn,
                                     pad_query, pad_query_batch,
-                                    shard_wmd_inputs)
+                                    plan_docs_chunk, shard_wmd_inputs,
+                                    solve_bytes_per_doc)
 from repro.obs.trace import span
 # one copy of the pow2 bucket-rounding rule for the whole serving layer:
 # the coalescer's admission buckets must match the service's Q padding
@@ -172,8 +175,16 @@ def _serialized(fn):
     return wrapper
 
 
-# sentinel: "use the service's docs_chunk" (None already means unchunked)
+# sentinel: "use the service's docs_chunk" (None already means planned)
 _UNSET = object()
+
+# Share of the device's ``bytes_limit`` that the planned solve leaves free,
+# beside the fixed part and the document blocks that the plan counts
+# (`core.distributed.solve_fixed_bytes`, `solve_bytes_per_doc`): room for
+# the program's outputs and the allocator's fragmentation, which no reading
+# bounds yet. The plan's per-document count is itself an upper bound (3
+# blocks a document counted, 1.9 compiled at news20).
+PLAN_SLACK = 0.05
 
 
 def _nonzeros(vals: np.ndarray) -> tuple[int, int]:
@@ -275,6 +286,11 @@ class WMDService:
                 "gathers of K at every ELL slot by full-distance solve "
                 "dispatches", labels={"where": where})
             for where in ("once", "per_iteration")}
+        # the document chunks those dispatches sweep, and the plan of the
+        # last one (`_count_dispatch`)
+        self._solve_chunks = self.metrics.counter(
+            "wmd_solve_chunks_total",
+            "document chunks swept by full-distance solve dispatches")
         self._ell_nnz = _nonzeros(self._rb.vals)
         self._warm_local = threading.local()    # see warming()
         # prefilter state: the bound runs replicated on the ORIGINAL
@@ -295,6 +311,25 @@ class WMDService:
                                // self._doc_shards) * self._doc_shards
         self._rerank_spec = NamedSharding(
             self.mesh, P("model", tuple(self._doc_axes), None))
+        # the memory plan's inputs: each device's doc slice and ELL width,
+        # and a budget fixed here, from the device's bytes_limit less the
+        # arrays just placed (never a momentary free-memory reading, so
+        # every run plans the same programs); None where the backend
+        # reports no memory (the CPU), and the plan is then unchunked
+        self._n_loc = self._rb.cols.shape[1] // self._doc_shards
+        self._v_loc = -(-self.vecs.shape[0] // self.mesh.shape["model"])
+        limits = [(d.memory_stats() or {}).get("bytes_limit")
+                  for d in self.mesh.devices.flat]
+        self.device_bytes_limit = min(limits) if all(limits) else None
+        self.plan_budget_bytes = None
+        if self.device_bytes_limit:
+            self.plan_budget_bytes = min(
+                int(limit * (1.0 - PLAN_SLACK)) - self._resident_bytes(d)
+                for d, limit in zip(self.mesh.devices.flat, limits))
+            self.metrics.gauge(
+                "wmd_device_bytes_limit",
+                "bytes_limit of the service's devices (the least)").set(
+                    self.device_bytes_limit)
         # numeric-guard state: the a-priori underflow gate needs the
         # largest embedding norm (cost bound 2*max||v||); docs with zero
         # total mass legitimately solve to distance 0 and are exempt from
@@ -323,6 +358,26 @@ class WMDService:
             # arm the corpus's compaction lock-hold histogram on this
             # service's registry (late-bindable, like its tracer)
             self.live.metrics = self.metrics
+
+    def _resident_bytes(self, device) -> int:
+        """Bytes of the service's own arrays (embeddings, ELL copies,
+        caches) on ``device``."""
+        arrays = {id(a): a for o in (self, self._kcache, self._mcache)
+                  for a in vars(o).values() if isinstance(a, jax.Array)}
+        return sum(sh.data.nbytes for a in arrays.values()
+                   for sh in a.addressable_shards if sh.device == device)
+
+    def _docs_chunk(self, q: int, docs_chunk=_UNSET) -> int | None:
+        """The doc chunk of a batched solve of ``q`` (padded) queries over
+        the service ELL: the call's ``docs_chunk``, else the service's
+        (0 = unchunked either way), else the memory plan."""
+        if docs_chunk is _UNSET:
+            docs_chunk = self.docs_chunk
+        if docs_chunk is not None:
+            return docs_chunk or None
+        return plan_docs_chunk(q, self.cfg.v_r, self._rb.cols.shape[-1],
+                               self._n_loc, self._v_loc,
+                               self.plan_budget_bytes)
 
     def async_service(self, **kw):
         """Async admission front-end: a `serving.coalescer.QueryCoalescer`
@@ -736,7 +791,6 @@ class WMDService:
             out = np.stack([self._solve_one(r, spans) for r in rs])
             return out, {"phases_separable": False, "route": "sequential"}
         q = len(rs)
-        dc = self.docs_chunk if docs_chunk is _UNSET else (docs_chunk or None)
         # cache disabled and no explicit routing request: the legacy
         # single-program engine (precompute fused into the solve) is the
         # faster plan -- the split stripes path pays an extra dispatch that
@@ -747,12 +801,14 @@ class WMDService:
         with span("wmd.prepare", spans):
             self._validate_queries(rs)
             sel_b, r_b, mask_b = self._padded_query_batch(rs)
+            dc = self._docs_chunk(len(sel_b), docs_chunk)
             if legacy:
                 vecs_b = self.vecs[sel_b]
         if legacy:
             fn = self._batch_fn(impl or self.impl, dc)
             with span("wmd.dispatch", spans):
-                self._count_dispatch(mask_b, self._ell_nnz, fn)
+                self._count_dispatch(mask_b, self._ell_nnz, fn,
+                                     chunk_docs=dc or self._n_loc)
                 wmd = fn(jnp.asarray(vecs_b), jnp.asarray(r_b),
                          jnp.asarray(mask_b), self._vecs_d, self._cols_d,
                          self._vals_d)
@@ -765,7 +821,8 @@ class WMDService:
             k_s, km_s, stats = self._cache_rows(sel_b, mask_b, use_cache,
                                                 spans)
             with span("wmd.dispatch", spans):
-                self._count_dispatch(mask_b, self._ell_nnz, fn)
+                self._count_dispatch(mask_b, self._ell_nnz, fn,
+                                     chunk_docs=dc or self._n_loc)
                 wmd = fn(k_s, km_s, jnp.asarray(r_b), self._cols_d,
                          self._vals_d)
         with span("wmd.fetch", spans):
@@ -806,15 +863,19 @@ class WMDService:
                 "wmd_span_seconds", "seconds in each stage of a service call",
                 labels={"span": n}).observe(t1 - t0)
 
-    def _count_dispatch(self, mask: np.ndarray, ell_nnz: tuple,
-                        fn) -> None:
-        """Count one solve dispatch's swept slots and gathers of K (skipped
-        in warm-up): ``mask`` is its padded query mask, ``ell_nnz`` the
-        (real, all) slots of the ELL segment it gathers over, ``fn`` the
-        solve program, whose ``k_gathers`` (`core.distributed`) states
-        where it gathers K and how often. (The unfused baseline's second
-        gather, of K/r, is the same gather in these programs -- r is folded
-        out of the iteration under shard_map -- and XLA merges the two.)"""
+    def _count_dispatch(self, mask: np.ndarray, ell_nnz: tuple, fn, *,
+                        chunk_docs: int | None = None) -> None:
+        """Count one solve dispatch's swept slots, gathers of K and doc
+        chunks (skipped in warm-up): ``mask`` is its padded query mask,
+        ``ell_nnz`` the (real, all) slots of the ELL segment it gathers
+        over, ``fn`` the solve program, whose ``k_gathers``
+        (`core.distributed`) states where it gathers K and how often. (The
+        unfused baseline's second gather, of K/r, is the same gather in
+        these programs -- r is folded out of the iteration under shard_map
+        -- and XLA merges the two.) ``chunk_docs`` is the documents a chunk
+        of a batched solve over the service ELL (its local slice where
+        unchunked): such a dispatch also sets the plan gauges. Every other
+        dispatch sweeps its documents as one chunk."""
         if self._warming:
             return
         real = int(np.count_nonzero(mask))
@@ -824,6 +885,20 @@ class WMDService:
             self._slots[what, "pad"].inc(n_all - n_real)
         where, n = fn.k_gathers
         self._k_gathers[where].inc(n)
+        if chunk_docs is None:
+            self._solve_chunks.inc(1)
+            return
+        self._solve_chunks.inc(-(-self._n_loc // chunk_docs))
+        self.metrics.gauge(
+            "wmd_solve_chunk_docs",
+            "documents a chunk of the last batched solve dispatch").set(
+                chunk_docs)
+        self.metrics.gauge(
+            "wmd_solve_chunk_bytes",
+            "the blocks of a chunk of the last batched solve dispatch, as "
+            "core.distributed.solve_bytes_per_doc counts them").set(
+                chunk_docs * solve_bytes_per_doc(
+                    *mask.shape, self._rb.cols.shape[-1]))
 
     @property
     def _warming(self) -> bool:

@@ -80,11 +80,13 @@ def mesh_args(topo):
 
 _CALLED = re.compile(r"\b(calls|to_apply|body|condition)=%?([\w.-]+)")
 _GATHER = re.compile(r"\sgather\(")
+_WHILE = re.compile(r"\swhile\(")
 
 
-def _gather_sites(hlo: str) -> tuple[int, int]:
-    """(gathers outside every ``while`` body, gathers inside one) of a
-    compiled HLO text, following fusions and other called computations."""
+def _gather_sites(hlo: str, op: re.Pattern = _GATHER) -> tuple[int, int]:
+    """(``op`` sites outside every ``while`` body, sites inside one) of a
+    compiled HLO text, following fusions and other called computations;
+    gathers by default."""
     comps: dict[str, list[str]] = {}
     entry = cur = None
     for line in hlo.splitlines():
@@ -111,7 +113,7 @@ def _gather_sites(hlo: str) -> tuple[int, int]:
               for kind, callee in _CALLED.findall(line) if kind == "body"]
     inside = reach(bodies, False)
     outside = reach([entry], True) - inside
-    return tuple(sum(len(_GATHER.findall(line)) for c in cs
+    return tuple(sum(len(op.findall(line)) for c in cs
                      for line in comps[c]) for cs in (outside, inside))
 
 
@@ -123,15 +125,15 @@ def _compile(fn, *args):
     return compiled
 
 
-def _plain_program(mesh_args, **kw):
+def _plain_program(mesh_args, *, v_r=V_R, v=V, n=N, nnz=NNZ, **kw):
     import jax.numpy as jnp
     from repro.core.distributed import build_wmd_batch_fn
     mesh, on = mesh_args
     fn = build_wmd_batch_fn(mesh, lamb=1.0, max_iter=ITERS, **kw)
-    return _compile(fn, on((Q, V_R, W)), on((Q, V_R)), on((Q, V_R)),
-                    on((V, W), "model", None),
-                    on((1, N, NNZ), "model", "data", None, dtype=jnp.int32),
-                    on((1, N, NNZ), "model", "data", None))
+    return _compile(fn, on((Q, v_r, W)), on((Q, v_r)), on((Q, v_r)),
+                    on((v, W), "model", None),
+                    on((1, n, nnz), "model", "data", None, dtype=jnp.int32),
+                    on((1, n, nnz), "model", "data", None))
 
 
 def test_service_plain_program_compiles(mesh_args):
@@ -144,6 +146,36 @@ def test_service_plain_program_compiles(mesh_args):
     compiled = _plain_program(mesh_args)
     assert _gather_sites(compiled.as_text()) == (2, 0)
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def test_news20_planned_program_compiles(mesh_args):
+    """news20 at Q = 8 (`configs/sinkhorn_wmd.py`: v_r 288, V 29 671,
+    11 293 docs, ELL 288 wide; 80 GB of K and K.*M blocks unchunked): the
+    service's plan on one v5e's budget (16 GiB less its slack, less the
+    corpus and embeddings) chunks the solve over documents. At that chunk
+    the program fits the chip, takes more than half the budget, and holds
+    one Sinkhorn loop, in the body of the rolled chunk loop, not one per
+    chunk; both gathers sit in the chunk body. No op takes bfloat16: at
+    v_r 288 XLA puts the SDDMM on the MXU, which at default precision
+    rounds K and u to bfloat16."""
+    from repro.configs.sinkhorn_wmd import config
+    from repro.core.distributed import plan_docs_chunk
+    from repro.serving.wmd_service import PLAN_SLACK
+    cfg = config("news20")
+    shape = dict(v_r=cfg.v_r, v=cfg.vocab_size, n=cfg.num_docs,
+                 nnz=cfg.nnz_max)
+    resident = 4 * (cfg.vocab_size * W + 2 * cfg.num_docs * cfg.nnz_max)
+    budget = int(16 * 2**30 * (1.0 - PLAN_SLACK)) - resident
+    chunk = plan_docs_chunk(Q, cfg.v_r, cfg.nnz_max, cfg.num_docs,
+                            cfg.vocab_size, budget)
+    assert chunk and -(-cfg.num_docs // chunk) >= 8
+    compiled = _plain_program(mesh_args, docs_chunk=chunk, **shape)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes > budget / 2
+    hlo = compiled.as_text()
+    assert _gather_sites(hlo, _WHILE) == (1, 1)
+    assert _gather_sites(hlo) == (0, 2)
+    assert "bf16" not in hlo
 
 
 def _stripes_program(mesh_args, **kw):
