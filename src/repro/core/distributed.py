@@ -186,9 +186,14 @@ def _with_k_gathers(fn, *, hoisted: bool, max_iter: int):
     gathers K before its Sinkhorn loop and K.*M once in the final pass,
     else ("per_iteration", max_iter + 2) -- K in each iteration, then K and
     K.*M in the final pass (under early exit that is the budget, an upper
-    bound on the iterations run)."""
+    bound on the iterations run). And ``fn.k_gather_row_bytes(q, v_r)``,
+    the contiguous bytes one ELL slot's K gather fetches at Q queries of
+    v_r rows: one float32 row of all Q queries where K is gathered once
+    (`ss.gather_k_rows`), one query's v_r floats where it is gathered in
+    the loop (`ss.gather_k_batch`, `ss.gather_k`)."""
     fn.k_gathers = ("once", 2) if hoisted else ("per_iteration",
                                                  max_iter + 2)
+    fn.k_gather_row_bytes = lambda q, v_r: 4 * v_r * (q if hoisted else 1)
     return fn
 
 
@@ -300,8 +305,8 @@ def solve_bytes_per_doc(q: int, v_r: int, nnz_loc: int) -> int:
     relayout copy (`ss.solve_contractions`); plus the (Q, v_r) iterate and
     its update. An upper bound: the compiled temp of the news20 program
     (Q 8, v_r 288, nnz 288: 10.64 MB a document) for one v5e grows by
-    3.005 blocks a document up to 512-document chunks, and holds 1.9 a
-    document at 1 136."""
+    2.75 blocks a document from 256- to 512-document chunks, and holds
+    1.75 a document at 1 416."""
     block = q * v_r * _ceil_div(nnz_loc, LANES) * LANES * 4
     return 3 * block + 2 * q * v_r * 4
 
@@ -309,9 +314,11 @@ def solve_bytes_per_doc(q: int, v_r: int, nnz_loc: int) -> int:
 def solve_fixed_bytes(q: int, v_r: int, v_loc: int) -> int:
     """Device bytes of the fused batched solve that do not grow with the
     documents (float32): the (Q, v_r, V+1) K and K.*M stripes over the
-    local vocabulary, and the cost rows they are made from. 0.82 GB at
-    news20 (Q 8, v_r 288, V 29 671), over the 0.76 GB that the compiled
-    program's temp holds beside its document blocks on one v5e."""
+    local vocabulary, then the (V+1, Q*v_r) row tables that take their
+    place (`ss.k_row_tables`; the stripes die once the tables are built),
+    and the cost rows they are made from. 0.82 GB at news20 (Q 8, v_r
+    288, V 29 671), over the 0.58 GB that the compiled program's temp
+    holds beside its document blocks on one v5e."""
     return 3 * q * v_r * (v_loc + 1) * 4
 
 
@@ -348,6 +355,9 @@ def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
     q, v_r = r_sel.shape
     ones_r = jnp.ones_like(r_sel)
     iter_chunk = _per_op_chunk(docs_chunk, chunk_placement)
+    # the (V+1, Q*v_r) row tables the hoisted gathers read: built once,
+    # outside the chunk loop
+    rows = ss.k_row_tables(impl, k_pad, km_pad, iter_chunk)
 
     def solve_chunk(x0_c, cols_c, vals_c):
         # the chunk's K block is gathered once, before the loop, where
@@ -355,7 +365,7 @@ def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
         # its bounded per-op working set and gathers in the loop
         type1, type2 = ss.solve_contractions(
             impl, k_pad, km_pad, ones_r, cols_c, vals_c,
-            docs_chunk=iter_chunk)
+            docs_chunk=iter_chunk, rows=rows)
 
         def iteration(x):
             x_part = type1(safe_recip(x))

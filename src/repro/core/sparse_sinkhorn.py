@@ -66,7 +66,12 @@ read; the final pass gathers K.*M once more. That holds per solve chunk
 (``docs_chunk`` outside the loop bounds the block); the unfused and kernel
 impls and per-op chunking gather K in every iteration instead
 (`hoists_k_gather`). On one v5e at paper_5k the in-loop gather was 95% of
-the solve.
+the solve. Those two gathers read a row table (`k_row_table`): the
+stripes laid out (V+1, Q*v_r), built once per solve, outside any chunk
+loop, so each ELL slot fetches one contiguous row of Q*v_r floats for all
+Q queries (`gather_k_rows`), where the (Q, V+1, v_r) table of
+`gather_k_batch` has it fetch Q strided rows of v_r. The gathered
+(N, nnz, Q*v_r) rows are relaid out once into the block.
 
 Early exit: `batched_sinkhorn_loop` is the shared while-loop core -- per
 query, iteration stops contributing writes once its relative iterate delta
@@ -335,10 +340,13 @@ def gather_k_batch(k_pad: jax.Array, cols: jax.Array) -> jax.Array:
     """One batched gather serving all Q queries.
 
     (Q, v_r, V+1), (N, nnz) -> (Q, N, nnz, v_r): one gather op whose batch
-    dims (q, n) lead (the (N, nnz, Q, v_r) alternative forces XLA to re-lay
-    it out before every dot -- measured ~2.3x slower on CPU). The unfused
-    baseline and the RWMD bound contract it in this layout; the fused
-    solve relays it out as `gather_k_block`.
+    dims (q, n) lead, from the (Q, V+1, v_r) table, so each ELL slot
+    fetches Q rows of v_r floats, one per query, V+1 rows apart (the
+    (N, nnz, Q, v_r) alternative forces XLA to re-lay it out before every
+    dot inside the loop -- measured ~2.3x slower on CPU). The unfused
+    baseline, per-op chunking and the RWMD bound contract it in this
+    layout, inside their loops; the solve that gathers once reads the row
+    table instead (`gather_k_rows`).
     """
     with jax.named_scope("wmd.gather"):
         return jnp.transpose(k_pad, (0, 2, 1))[:, cols]
@@ -346,16 +354,40 @@ def gather_k_batch(k_pad: jax.Array, cols: jax.Array) -> jax.Array:
 
 def gather_k_block(k_pad: jax.Array, cols: jax.Array) -> jax.Array:
     """K at every ELL slot as a (Q, v_r, N, nnz) block: (Q, v_r, V+1),
-    (N, nnz) -> (Q, v_r, N, nnz).
+    (N, nnz) -> (Q, v_r, N, nnz), by `gather_k_batch` and a relayout.
 
     nnz is the minor axis, so on a TPU the block fills the 128-wide lanes
     with ELL slots; with v_r (often 32) there it would be padded 4x. This
     is the layout of the block a solve gathers once and carries through
-    its Sinkhorn loop (`solve_contractions`).
+    its Sinkhorn loop (`solve_contractions`), which gathers it from the row
+    table (`gather_k_rows`, bitwise the same block); per-op chunking
+    gathers it here, once per op.
     """
     kg = gather_k_batch(k_pad, cols)
     with jax.named_scope("wmd.gather"):
         return jnp.transpose(kg, (0, 3, 1, 2))
+
+
+def k_row_table(k_pad: jax.Array) -> jax.Array:
+    """The row table of a solve's stripes: (Q, v_r, V+1) -> (V+1, Q*v_r),
+    row c holding word c's K column of every query, the zero pad column
+    its last row. Built once per solve, outside any chunk loop."""
+    q, v_r, v1 = k_pad.shape
+    return jnp.transpose(k_pad, (2, 0, 1)).reshape(v1, q * v_r)
+
+
+def gather_k_rows(table: jax.Array, cols: jax.Array, q: int) -> jax.Array:
+    """The `gather_k_block` block from a `k_row_table`: (V+1, Q*v_r),
+    (N, nnz) -> (Q, v_r, N, nnz). Each ELL slot fetches one contiguous row
+    of Q*v_r floats; the (N, nnz, Q*v_r) rows are relaid out once.
+
+    The rows are transposed before Q*v_r is split: split first, the split
+    of the tiled minor axis is a relayout of its own (at news20's v_r 288,
+    one v5e spent 0.98 s of a 20 s window on each of the two)."""
+    n, nnz = cols.shape
+    with jax.named_scope("wmd.gather"):
+        rows = table[cols]                           # (N, nnz, Q*v_r)
+        return jnp.transpose(rows, (2, 0, 1)).reshape(q, -1, n, nnz)
 
 
 def type1_from_block(kg: jax.Array, r_sel: jax.Array, u: jax.Array,
@@ -516,26 +548,43 @@ def sddmm_spmm_type2_batch(k_pad: jax.Array, km_pad: jax.Array, u: jax.Array,
                             pad_col=k_pad.shape[-1] - 1)
 
 
+def k_row_tables(impl: str, k_pad: jax.Array, km_pad: jax.Array,
+                 docs_chunk: int | None = None):
+    """The (K, K.*M) `k_row_table` pair that `solve_contractions` gathers
+    from where `hoists_k_gather` allows, else None. A solve that sweeps
+    chunks builds them here, once, outside its chunk loop, and passes them
+    to each chunk's `solve_contractions`."""
+    if not hoists_k_gather(impl, docs_chunk):
+        return None
+    with jax.named_scope("wmd.precompute"):
+        return k_row_table(k_pad), k_row_table(km_pad)
+
+
 def solve_contractions(impl: str, k_pad: jax.Array, km_pad: jax.Array,
                        r_sel: jax.Array, cols: jax.Array, vals: jax.Array, *,
-                       docs_chunk: int | None = None, hoist: bool = True):
+                       docs_chunk: int | None = None, hoist: bool = True,
+                       rows=None):
     """The (iteration, final) contractions of one solve over one doc slice:
     ``iteration(u)`` -> (Q, v_r, N) x, ``final(u)`` -> (Q, N) distances.
 
     Where `hoists_k_gather` allows (and ``hoist``), K is gathered here,
-    once, into a (Q, v_r, N, nnz) block under ``wmd.precompute`` that
-    every iteration and the final pass read; the final pass gathers K.*M
-    once more. Otherwise both run the impl table's per-call ops, which
+    once, from its row table (``rows``, the `k_row_tables` pair, built
+    here when None) into a (Q, v_r, N, nnz) block under ``wmd.precompute``
+    that every iteration and the final pass read; the final pass gathers
+    K.*M once more. Otherwise both run the impl table's per-call ops, which
     gather K again on every call (``docs_chunk`` per-op chunking;
-    ``hoist=False`` forces this path for the fused impl too). Call it
-    outside the Sinkhorn loop: the block is then a loop invariant.
+    ``hoist=False`` forces this path for the fused impl too, and ``rows``
+    goes unread). Call it outside the Sinkhorn loop: the block is then a
+    loop invariant.
     """
     if hoist and hoists_k_gather(impl, docs_chunk):
+        k_rows, km_rows = rows or k_row_tables(impl, k_pad, km_pad)
+        q = r_sel.shape[0]
         with jax.named_scope("wmd.precompute"):
-            kg = gather_k_block(k_pad, cols)
+            kg = gather_k_rows(k_rows, cols, q)
 
         def final(u):
-            return type2_from_block(kg, gather_k_block(km_pad, cols), u,
+            return type2_from_block(kg, gather_k_rows(km_rows, cols, q), u,
                                     vals)
 
         return (lambda u: type1_from_block(kg, r_sel, u, vals)), final
@@ -640,6 +689,7 @@ def _solve_batch_stripes(k_pad: jax.Array, km_pad: jax.Array,
     q, v_r = r_sel.shape
     n = cols.shape[0]
     x0 = jnp.full((q, v_r, n), 1.0 / v_r, dtype=k_pad.dtype)
+    rows = k_row_tables(impl, k_pad, km_pad)
 
     def solve_chunk(x0_c, cols_c, vals_c):
         # docs never interact across the Sinkhorn iteration (each doc is an
@@ -648,9 +698,10 @@ def _solve_batch_stripes(k_pad: jax.Array, km_pad: jax.Array,
         # its (Q, v_r, docs_chunk) iterate stays cache-resident -- measured
         # 1.5-3.3x over the iteration-major unchunked loop at bulk shapes
         # on CPU (see "Batched engine & cache blocking"). The chunk's K
-        # block is gathered here, once, where `hoists_k_gather` allows.
+        # block is gathered here, once, from the row tables built before
+        # the chunk loop, where `hoists_k_gather` allows.
         type1, final = solve_contractions(impl, k_pad, km_pad, r_sel,
-                                          cols_c, vals_c)
+                                          cols_c, vals_c, rows=rows)
 
         def iteration(x):
             return type1(safe_recip(x))

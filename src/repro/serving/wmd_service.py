@@ -868,8 +868,9 @@ class WMDService:
         """Count one solve dispatch's swept slots, gathers of K and doc
         chunks (skipped in warm-up): ``mask`` is its padded query mask,
         ``ell_nnz`` the (real, all) slots of the ELL segment it gathers
-        over, ``fn`` the solve program, whose ``k_gathers``
-        (`core.distributed`) states where it gathers K and how often. (The
+        over, ``fn`` the solve program, whose ``k_gathers`` and
+        ``k_gather_row_bytes`` (`core.distributed`) state where it gathers
+        K, how often, and how long a row each ELL slot fetches. (The
         unfused baseline's second gather, of K/r, is the same gather in
         these programs -- r is folded out of the iteration under shard_map
         -- and XLA merges the two.) ``chunk_docs`` is the documents a chunk
@@ -885,6 +886,12 @@ class WMDService:
             self._slots[what, "pad"].inc(n_all - n_real)
         where, n = fn.k_gathers
         self._k_gathers[where].inc(n)
+        self.metrics.gauge(
+            "wmd_k_gather_row_bytes",
+            "contiguous bytes one ELL slot's K gather fetches in the last "
+            "full-distance solve dispatch").set(
+                fn.k_gather_row_bytes(mask.size // mask.shape[-1],
+                                      mask.shape[-1]))
         if chunk_docs is None:
             self._solve_chunks.inc(1)
             return

@@ -262,6 +262,32 @@ def test_hoisted_gather_matches_in_loop(batch_problem, monkeypatch, solve,
     np.testing.assert_array_equal(in_loop[-1], 0.0)
 
 
+@pytest.mark.parametrize("q,v_r,v,n,nnz", [
+    (1, 4, 16, 3, 8), (4, 16, 256, 45, 24), (5, 7, 33, 10, 5),
+    (8, 32, 300, 17, 144)])
+def test_row_table_gather_matches_block(q, v_r, v, n, nnz):
+    """The hoisted gather from the (V+1, Q*v_r) row table
+    (`gather_k_rows` of `k_row_table`) is bitwise `gather_k_block`'s
+    (Q, v_r, N, nnz) block, with pad query rows, an all-pad last query and
+    ELL pad slots (col = V, the zero column)."""
+    from repro.core import sparse_sinkhorn as ss
+    rng = np.random.default_rng(q * 1000 + v_r)
+    mask = (rng.random((q, v_r)) < 0.7).astype(np.float32)
+    mask[:, 0], mask[-1] = 1.0, 0.0
+    k = rng.random((q, v_r, v)).astype(np.float32) * mask[..., None]
+    cols = rng.integers(0, v, (n, nnz)).astype(np.int32)
+    cols[rng.random((n, nnz)) < 0.3] = v
+    cols[0] = v                                   # an empty document
+    k_pad, cols = pad_k(jnp.asarray(k)), jnp.asarray(cols)
+    rows = jax.jit(lambda kp, c: ss.gather_k_rows(ss.k_row_table(kp), c, q))
+    block = np.asarray(jax.jit(ss.gather_k_block)(k_pad, cols))
+    got = np.asarray(rows(k_pad, cols))
+    assert got.shape == block.shape == (q, v_r, n, nnz)
+    np.testing.assert_array_equal(got, block)
+    np.testing.assert_array_equal(got[-1], 0.0)
+    np.testing.assert_array_equal(got[:, :, 0], 0.0)
+
+
 # ---------------------------------------------------------------------------
 # Early-exit convergence
 # ---------------------------------------------------------------------------

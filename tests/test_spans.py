@@ -219,6 +219,28 @@ def test_k_gather_counter_counts_each_solve_dispatch():
     assert gathers() == (4, 2 * (tg.MAX_ITER + 2))
 
 
+def test_k_gather_row_bytes_reads_the_last_dispatch():
+    """``wmd_k_gather_row_bytes`` holds the contiguous bytes one ELL slot's
+    K gather fetches in the last solve dispatch: a row of all Q_pow2 x v_r
+    floats where K is gathered once (the legacy fused route), one query's
+    v_r where the unfused baseline gathers in its loop. Warm-up sets
+    nothing."""
+    from repro.serving.warmup import ProgramShape, ShapeRegistry, warm
+    svc, rs = _service()
+    warm(svc, ShapeRegistry([ProgramShape("plain", 4)]))
+
+    def row_bytes():
+        return svc.metrics.snapshot().get("wmd_k_gather_row_bytes")
+    assert row_bytes() is None
+    svc.query_batch(rs)                     # Q = 3 -> one Q_pow2 = 4 bucket
+    assert svc.last_batch_stats["route"] == "legacy_fused"
+    assert row_bytes() == 4 * tg.V_R_BUCKET * 4
+    svc.query_batch(rs, impl="unfused")
+    assert row_bytes() == tg.V_R_BUCKET * 4
+    svc.query_batch(rs)
+    assert row_bytes() == 4 * tg.V_R_BUCKET * 4
+
+
 def test_coalescer_phase_children_are_the_service_spans():
     """The request tree's ``precompute`` and ``solve`` children are the
     service's own ``wmd.cache_rows`` and dispatch-to-fetch intervals."""

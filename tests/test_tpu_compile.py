@@ -136,28 +136,31 @@ def _plain_program(mesh_args, *, v_r=V_R, v=V, n=N, nnz=NNZ, **kw):
                     on((1, n, nnz), "model", "data", None))
 
 
-def test_service_plain_program_compiles(mesh_args):
-    """The plain-request program the service dispatches by default (no K
-    cache: precompute fused into the solve, unchunked, shard_map'd). It
-    gathers K at the ELL slots once, before the Sinkhorn loop, and K.*M
-    once for the final pass; the loop gathers nothing. The block it
-    carries is laid out (Q, v_r, N, nnz): with v_r on the 128-wide lane
-    axis it would be padded 4x, and temp would pass 4 GiB."""
-    compiled = _plain_program(mesh_args)
-    assert _gather_sites(compiled.as_text()) == (2, 0)
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+def _gather_operands(hlo: str) -> list[tuple[tuple[int, ...], ...]]:
+    """(operand dims, slice sizes) of every gather of a compiled HLO text."""
+    dims = lambda text: tuple(int(d) for d in text.split(",") if d)
+    shapes = dict(re.findall(r"%([\w.-]+) = \w+\[([\d,]*)\]", hlo))
+    return [(dims(shapes[op]), dims(sizes)) for op, sizes in re.findall(
+        r"\sgather\(%([\w.-]+),.*?slice_sizes=\{([\d,]*)\}", hlo)]
 
 
-def test_news20_planned_program_compiles(mesh_args):
-    """news20 at Q = 8 (`configs/sinkhorn_wmd.py`: v_r 288, V 29 671,
-    11 293 docs, ELL 288 wide; 80 GB of K and K.*M blocks unchunked): the
-    service's plan on one v5e's budget (16 GiB less its slack, less the
-    corpus and embeddings) chunks the solve over documents. At that chunk
-    the program fits the chip, takes more than half the budget, and holds
-    one Sinkhorn loop, in the body of the rolled chunk loop, not one per
-    chunk; both gathers sit in the chunk body. No op takes bfloat16: at
-    v_r 288 XLA puts the SDDMM on the MXU, which at default precision
-    rounds K and u to bfloat16."""
+def _row_table(v: int, v_r: int) -> re.Pattern:
+    """Ops that make a (V+1, Q*v_r) float32 row table (not the parameters
+    and tuple reads that pass one on), for `_gather_sites`."""
+    return re.compile(rf"= f32\[{v + 1},{Q * v_r}\]\S* "
+                      r"(?!parameter\(|get-tuple-element\()")
+
+
+@pytest.fixture(scope="module")
+def plain_5k(mesh_args):
+    """The paper_5k plain-request program, compiled once for its tests."""
+    return _plain_program(mesh_args)
+
+
+@pytest.fixture(scope="module")
+def news20_planned(mesh_args):
+    """news20's program at the plan's chunk on one v5e's budget, compiled
+    once for its tests: (config, budget, chunk, compiled)."""
     from repro.configs.sinkhorn_wmd import config
     from repro.core.distributed import plan_docs_chunk
     from repro.serving.wmd_service import PLAN_SLACK
@@ -168,14 +171,70 @@ def test_news20_planned_program_compiles(mesh_args):
     budget = int(16 * 2**30 * (1.0 - PLAN_SLACK)) - resident
     chunk = plan_docs_chunk(Q, cfg.v_r, cfg.nnz_max, cfg.num_docs,
                             cfg.vocab_size, budget)
-    assert chunk and -(-cfg.num_docs // chunk) >= 8
-    compiled = _plain_program(mesh_args, docs_chunk=chunk, **shape)
+    assert chunk
+    return cfg, budget, chunk, _plain_program(mesh_args, docs_chunk=chunk,
+                                              **shape)
+
+
+def test_service_plain_program_compiles(plain_5k):
+    """The plain-request program the service dispatches by default (no K
+    cache: precompute fused into the solve, unchunked, shard_map'd). It
+    gathers K at the ELL slots once, before the Sinkhorn loop, and K.*M
+    once for the final pass; the loop gathers nothing. The block it
+    carries is laid out (Q, v_r, N, nnz): with v_r on the 128-wide lane
+    axis it would be padded 4x, and temp would pass 4 GiB."""
+    assert _gather_sites(plain_5k.as_text()) == (2, 0)
+    assert plain_5k.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def test_service_plain_program_gathers_row_table(plain_5k):
+    """Both gathers of that program read a (V+1, Q*v_r) row table, one
+    contiguous row of Q*v_r floats an ELL slot, not Q strided rows."""
+    assert _gather_operands(plain_5k.as_text()) == \
+        [((V + 1, Q * V_R), (1, Q * V_R))] * 2
+
+
+def test_news20_planned_program_compiles(news20_planned):
+    """news20 at Q = 8 (`configs/sinkhorn_wmd.py`: v_r 288, V 29 671,
+    11 293 docs, ELL 288 wide; 80 GB of K and K.*M blocks unchunked): the
+    service's plan on one v5e's budget (16 GiB less its slack, less the
+    corpus and embeddings) chunks the solve over documents. At that chunk
+    the program fits the chip, takes more than half the budget, and holds
+    one Sinkhorn loop, in the body of the rolled chunk loop, not one per
+    chunk; both gathers sit in the chunk body. No op takes bfloat16: at
+    v_r 288 XLA puts the SDDMM on the MXU, which at default precision
+    rounds K and u to bfloat16."""
+    cfg, budget, chunk, compiled = news20_planned
+    assert -(-cfg.num_docs // chunk) >= 8
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes > budget / 2
     hlo = compiled.as_text()
     assert _gather_sites(hlo, _WHILE) == (1, 1)
     assert _gather_sites(hlo) == (0, 2)
     assert "bf16" not in hlo
+
+
+def test_news20_planned_program_gathers_row_table(news20_planned):
+    """At news20's planned chunk both gathers, still in the chunk body,
+    read a (V+1, Q*v_r) row table, which is built outside the rolled chunk
+    loop, once a dispatch; and the program stays within the budget that
+    the plan counted its chunk against, its temp within the plan's own
+    count (`solve_fixed_bytes` beside the chunk's document blocks)."""
+    from repro.core.distributed import solve_bytes_per_doc, solve_fixed_bytes
+    cfg, budget, chunk, compiled = news20_planned
+    hlo = compiled.as_text()
+    row = Q * cfg.v_r
+    assert _gather_operands(hlo) == [((cfg.vocab_size + 1, row),
+                                      (1, row))] * 2
+    assert _gather_sites(hlo) == (0, 2)
+    built_outside, built_inside = _gather_sites(
+        hlo, _row_table(cfg.vocab_size, cfg.v_r))
+    assert built_outside > 0 and built_inside == 0
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= budget
+    assert mem.temp_size_in_bytes <= (
+        solve_fixed_bytes(Q, cfg.v_r, cfg.vocab_size)
+        + chunk * solve_bytes_per_doc(Q, cfg.v_r, cfg.nnz_max))
 
 
 def _stripes_program(mesh_args, **kw):
