@@ -1,10 +1,9 @@
 """Multi-query batching throughput: batched (Q, v_r, N) engine vs the
 sequential per-query dispatch loop, with a ``--docs-chunk`` cache-blocking
-sweep, an ``--impl`` (fused | kernel) mode, and a ``--zipf`` query-stream
-mode exercising the cross-query K cache.
+sweep and a ``--zipf`` query-stream mode exercising the cross-query K cache.
 
     PYTHONPATH=src python benchmarks/bench_query_batch.py [--tiny] \
-        [--docs-chunk 0 64 128 256] [--impl fused] [--out BENCH_query_batch.json]
+        [--docs-chunk 0 64 128 256] [--out BENCH_query_batch.json]
     PYTHONPATH=src python benchmarks/bench_query_batch.py --zipf \
         [--cache-capacity 2048] [--zipf-s 1.3] [--out BENCH_zipf_cache.json]
 
@@ -21,12 +20,13 @@ O(Q*v_r*V*w) to O(misses*V*w), so at hit rate h it approaches 1/(1-h) minus
 assembly overhead).
 
 For each Q the sequential baseline replays `WMDService.query` Q times
-(re-gathering K, re-running precompute, and paying one program dispatch per
-query); the batched path runs ONE device program. ``--docs-chunk`` sweeps
+(the same engine at Q = 1: re-gathering K, re-running precompute, and
+paying one program dispatch per query); the batched path runs ONE device
+program. ``--docs-chunk`` sweeps
 `WMDService(docs_chunk=...)` (0 = unchunked): the chunk loop sits OUTSIDE
 the Sinkhorn loop (docs are independent OT problems), so each chunk's
 (Q, v_r, docs_chunk) iterate stays cache-resident across all iterations --
-see core.sparse_sinkhorn "Batched engine & cache blocking". All variants are
+see core.sparse_sinkhorn "Batched engine". All variants are
 timed INTERLEAVED (round-robin, median of rounds) so slow-box drift hits
 every variant equally. Emits ``name,us_per_call,derived`` CSV rows (the
 harness idiom) and writes a JSON artifact for the perf trajectory
@@ -45,9 +45,7 @@ Measured regimes on the 2-core CPU CI box (vocab 2k, nnz ~96, v_r 16):
     win of the batched engine is collective amortization on real meshes
     (one psum per iteration regardless of Q), which a single-host bench
     cannot show.
-  * Q = 1 is routed to the sequential path by the service admission policy
-    (speedup 0.96x batched in the PR-1 artifact; the `admission` field
-    records the route).
+  * at Q = 1 the batched and sequential paths dispatch the same program.
 
 Self-contained on purpose (no benchmarks.common import): CI invokes it as a
 script with only the installed `repro` package on the path.
@@ -75,7 +73,7 @@ def bench_interleaved(calls: dict, *, warmup: int = 1, rounds: int = 5):
 
 def run(*, vocab: int = 1024, docs: int = 128, qs=(1, 4, 16, 64),
         mean_words: float = 8.0, query_words: int = 13, v_r: int = 16,
-        docs_chunks=(0,), impl: str = "fused", rounds: int = 5,
+        docs_chunks=(0,), rounds: int = 5,
         cache_capacity: int = 0, out: str | None = None) -> dict:
     import numpy as np
     from repro.configs.sinkhorn_wmd import WMDConfig
@@ -94,13 +92,13 @@ def run(*, vocab: int = 1024, docs: int = 128, qs=(1, 4, 16, 64),
     if 0 not in docs_chunks:
         docs_chunks = (0,) + docs_chunks
     # ONE service (one device-sharded corpus); the chunk sweep rides the
-    # per-(impl, docs_chunk) batch-fn cache via query_batch(docs_chunk=...)
+    # per-docs_chunk batch-fn cache via query_batch(docs_chunk=...)
     svc = WMDService(mesh=mesh, cfg=cfg, vecs=data.vecs, ell=data.ell,
-                     impl=impl, cache_capacity=cache_capacity)
+                     cache_capacity=cache_capacity)
 
     results = {"vocab": vocab, "docs": docs, "v_r": cfg.v_r,
                "nnz_max": data.ell.nnz_max, "max_iter": cfg.max_iter,
-               "impl": impl, "docs_chunks": list(docs_chunks),
+               "docs_chunks": list(docs_chunks),
                "cache_capacity": cache_capacity, "points": [],
                "note": ("chunk_times_s sweeps WMDService(docs_chunk=...); "
                         "chosen_chunk minimizes batched time. At bulk N the "
@@ -117,26 +115,6 @@ def run(*, vocab: int = 1024, docs: int = 128, qs=(1, 4, 16, 64),
                         "--zipf artifact for the cache's steady state.")}
     for q in qs:
         queries = data.queries[:q]
-        if q == 1 and impl == "fused":
-            # the service admission policy routes fused singletons to the
-            # sequential path (0.96x batched in the PR-1 artifact) -- every
-            # "batched" variant IS the sequential program, so a chunk sweep
-            # would chart pure timing noise. Record the policy instead.
-            # (A non-fused impl bypasses the shortcut, so Q=1 falls through
-            # to the real batched measurement below.)
-            med = bench_interleaved(
-                {"seq": lambda: svc.query_batch_sequential(queries)},
-                rounds=rounds)
-            t_seq = med["seq"]
-            # only genuinely measured fields: no t_batched_s / speedup /
-            # max_abs_err, so trajectory consumers can't mistake the policy
-            # route for a batched measurement
-            point = {"Q": 1, "t_seq_s": t_seq, "qps_seq": 1 / t_seq,
-                     "admission": "sequential"}
-            results["points"].append(point)
-            print(f"qbatch/Q1,{t_seq * 1e6:.1f},"
-                  f"qps={1 / t_seq:.1f}:admission=sequential")
-            continue
         # correctness gate before timing: batched must match the oracle
         # (for every swept chunk size)
         seq_ref = svc.query_batch_sequential(queries)
@@ -173,7 +151,6 @@ def run(*, vocab: int = 1024, docs: int = 128, qs=(1, 4, 16, 64),
             "speedup": t_seq / t_bat,
             "speedup_chunked_vs_unchunked": t_un / t_bat,
             "max_abs_err": err,
-            "admission": "batched",
             "precompute_s": phases["precompute_s"],
             "solve_s": phases["solve_s"],
             "hit_rate": phases["hit_rate"],
@@ -195,7 +172,7 @@ def run_zipf(*, vocab: int = 8192, docs: int = 128, q: int = 16,
              batches: int = 24, warm: int = 8, query_words: int = 13,
              v_r: int = 16, s: float = 1.3, cache_capacity: int = 2048,
              embed_dim: int = 256, rows_bucket: int = 16,
-             impl: str = "fused", out: str | None = None) -> dict:
+             out: str | None = None) -> dict:
     """Zipf query-stream mode: steady-state cache hit rate + phase split.
 
     Replays ``batches`` batches of ``q`` queries drawn from one seeded
@@ -228,13 +205,13 @@ def run_zipf(*, vocab: int = 8192, docs: int = 128, q: int = 16,
                        query_words=query_words, seed=0)
     mesh = make_mesh((1, 1), ("data", "model"))
     svc = WMDService(mesh=mesh, cfg=cfg, vecs=data.vecs, ell=data.ell,
-                     impl=impl, cache_capacity=cache_capacity,
+                     cache_capacity=cache_capacity,
                      cache_rows_bucket=rows_bucket)
     stream = zipf_query_stream(vocab_size=vocab, query_words=query_words,
                                s=s, seed=1)
     results = {"mode": "zipf", "vocab": vocab, "docs": docs, "Q": q,
                "v_r": v_r, "query_words": query_words, "zipf_s": s,
-               "cache_capacity": cache_capacity, "impl": impl,
+               "cache_capacity": cache_capacity,
                "warm_batches": warm, "points": [],
                "note": ("per batch: cache-ON call then transient cache-OFF "
                         "baseline on the same queries, asserted bitwise "
@@ -304,9 +281,6 @@ def main():
     ap.add_argument("--qs", type=int, nargs="+", default=[1, 4, 16, 64])
     ap.add_argument("--docs-chunk", type=int, nargs="+", default=[0],
                     help="docs_chunk sweep; 0 = unchunked (always included)")
-    ap.add_argument("--impl", default="fused", choices=("fused", "kernel"),
-                    help="batched contraction path (kernel = Pallas, "
-                         "interpret mode on CPU: slow, correctness timing)")
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--tiny", action="store_true",
                     help="CI smoke shape (small corpus, Q <= 8)")
@@ -338,7 +312,7 @@ def main():
                  v_r=args.v_r, s=args.zipf_s,
                  cache_capacity=(2048 if args.cache_capacity is None
                                  else args.cache_capacity),
-                 impl=args.impl, out=out)
+                 out=out)
     elif args.tiny:
         run(vocab=512, docs=64, qs=(1, 4, 8), docs_chunks=(0, 16, 32),
             rounds=3, cache_capacity=args.cache_capacity or 0, out=out)
@@ -346,7 +320,7 @@ def main():
         run(vocab=args.vocab or 1024, docs=args.docs, qs=tuple(args.qs),
             mean_words=args.mean_words, query_words=args.query_words,
             v_r=args.v_r, docs_chunks=tuple(args.docs_chunk),
-            impl=args.impl, rounds=args.rounds,
+            rounds=args.rounds,
             cache_capacity=args.cache_capacity or 0, out=out)
 
 

@@ -33,9 +33,7 @@ from repro.core.cascade import (centroid_bound_batch, doc_centroids,
 from repro.core.sparse_sinkhorn import (BatchedSinkhornPrecompute,
                                         batched_sinkhorn_loop, pad_k,
                                         precompute_batch, sddmm, spmm,
-                                        sddmm_batch, spmm_batch,
                                         sddmm_spmm_type1, sddmm_spmm_type2,
-                                        sddmm_spmm_type1_batch,
                                         sddmm_spmm_type2_batch,
                                         sinkhorn_wmd_sparse,
                                         sinkhorn_wmd_sparse_batch,
@@ -63,8 +61,8 @@ __all__ = [
     "pad_k", "sddmm", "spmm", "sddmm_spmm_type1", "sddmm_spmm_type2",
     "sinkhorn_wmd_sparse",
     "BatchedSinkhornPrecompute", "precompute_batch",
-    "batched_sinkhorn_loop", "sddmm_batch", "spmm_batch",
-    "sddmm_spmm_type1_batch", "sddmm_spmm_type2_batch",
+    "batched_sinkhorn_loop",
+    "sddmm_spmm_type2_batch",
     "sinkhorn_wmd_sparse_batch", "sinkhorn_wmd_sparse_batch_stripes",
     "SinkhornResult", "sinkhorn_divergence", "sinkhorn_plan",
     "ConvergedWMD", "sinkhorn_wmd_converged",

@@ -64,15 +64,12 @@ class BatchConvergedWMD(NamedTuple):
     delta: jax.Array   # (Q,) final per-query relative |dx|_inf
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("max_iter", "impl", "docs_chunk"))
+@functools.partial(jax.jit, static_argnames=("max_iter",))
 def sinkhorn_wmd_converged_batch(sel_idx: jax.Array, r_sel: jax.Array,
                                  cols: jax.Array, vals: jax.Array,
                                  vecs: jax.Array, lamb: float, max_iter: int,
                                  tol: float = 1e-6,
-                                 row_mask: jax.Array | None = None,
-                                 impl: str = "fused",
-                                 docs_chunk: int | None = None
+                                 row_mask: jax.Array | None = None
                                  ) -> BatchConvergedWMD:
     """Batched early-exit solve with **per-query convergence masking**.
 
@@ -87,14 +84,10 @@ def sinkhorn_wmd_converged_batch(sel_idx: jax.Array, r_sel: jax.Array,
     solver and the distributed shard_map engine.)
 
     sel_idx/r_sel/row_mask are (Q, v_r) bucketed queries (see pad_query).
-    impl selects the contraction path (same table as
-    `sinkhorn_wmd_sparse_batch`). docs_chunk here is PER-OP (inside each
-    iteration-major step, bitwise exact) -- unlike the per-solve chunk
-    hoisting of `sinkhorn_wmd_sparse_batch` -- because the global per-query
-    freeze masks and the reported n_iter/delta are defined over the full
-    doc axis. Unchunked, the fused impl gathers K once, before the loop;
-    with a per-op docs_chunk, as in `core.distributed`, it gathers K in
-    every iteration (`sparse_sinkhorn.hoists_k_gather`).
+    The solve is unchunked, so the freeze masks and the reported
+    n_iter/delta are global over the doc axis -- the single-host oracle of
+    `core.distributed`'s all-shards convergence vote. K is gathered once,
+    before the loop (`sparse_sinkhorn.solve_contractions`).
     """
     pre = precompute_batch(sel_idx, r_sel, vecs, lamb, row_mask)
     k_pad = pad_k(pre.K)
@@ -103,8 +96,7 @@ def sinkhorn_wmd_converged_batch(sel_idx: jax.Array, r_sel: jax.Array,
     n = cols.shape[0]
     x0 = jnp.full((q, v_r, n), 1.0 / v_r, dtype=pre.K.dtype)
 
-    type1, final = solve_contractions(impl, k_pad, km_pad, pre.r, cols, vals,
-                                      docs_chunk=docs_chunk)
+    type1, final = solve_contractions(k_pad, km_pad, pre.r, cols, vals)
     x, delta, n_iter = batched_sinkhorn_loop(
         lambda x: type1(safe_recip(x)), x0, max_iter=max_iter, tol=tol)
     wmd = final(safe_recip(x))

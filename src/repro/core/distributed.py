@@ -12,19 +12,23 @@ PIUMA DGAS scale-out:
     w[j,k] = <K[:, col], u[:, j]> is therefore **fully local** -- a word's K
     column lives with its nonzero, the DGAS locality argument made explicit.
   * the only collective is one ``psum`` over ``model`` per Sinkhorn iteration
-    (the partial SpMM contributions, v_r x N_local floats per chip), plus one
-    scalar-per-doc psum for the final distances. Per-chip psum bytes are
+    (the partial SpMM contributions, Q x v_r x N_local floats per chip), plus
+    one scalar-per-doc psum for the final distances. Per-chip psum bytes are
     independent of pod count at fixed per-chip work -- the TPU version of the
     paper's "no performance hit from 1 die to 8 dies".
 
-The per-device compute reuses the *same* fused SDDMM-SpMM code (jnp or
-Pallas) as the single-chip path; `ops.sddmm_spmm_chunked` is the one-chip
-replay of this exact decomposition.
+One solve serves every query count, Q = 1 included (`build_wmd_batch_fn`,
+and `build_wmd_batch_fn_stripes` for the K cache's stripes): the fused
+SDDMM-SpMM of `core.sparse_sinkhorn`, with K gathered at each device's ELL
+slots once per batch, before the Sinkhorn loop (`ss.solve_contractions`).
+Where that block would not fit, the solve is chunked over documents
+outside the Sinkhorn loop, by a memory plan (`plan_docs_chunk`).
+`ops.sddmm_spmm_chunked` is the one-chip replay of the vocab decomposition.
 
 Query padding: multiple queries are bucketed to a common v_r; pad rows carry
-r = 1 and an all-zero K row (`pad_query` + the row mask in `masked_k`), which
-makes padded rows contribute *exactly* zero to every w, x and WMD -- no
-epsilon approximations.
+r = 1 and an all-zero K row (`pad_query` + the row mask in `masked_k_batch`),
+which makes padded rows contribute *exactly* zero to every w, x and WMD --
+no epsilon approximations.
 """
 from __future__ import annotations
 
@@ -89,154 +93,38 @@ def masked_k_batch(vecs_sel: jax.Array, vecs_loc: jax.Array, lamb: float,
 
 
 # ---------------------------------------------------------------------------
-# The per-device program
+# The served solve
 # ---------------------------------------------------------------------------
-
-def _local_solve(vecs_sel, r_sel, row_mask, vecs_loc, cols_loc, vals_loc, *,
-                 lamb: float, max_iter: int, model_axis: str,
-                 use_kernel: bool):
-    """Runs on every device under shard_map. Doc axis: local slice; vocab
-    axis: local stripe. Returns the (N_local,) WMD slice."""
-    with jax.named_scope("wmd.precompute"):
-        k, km = masked_k(vecs_sel, vecs_loc, lamb, row_mask)
-        k_pad, km_pad = pad_k(k), pad_k(km)
-    v_r = r_sel.shape[0]
-    n_loc = cols_loc.shape[0]
-    ones_r = jnp.ones_like(r_sel)
-
-    def type1_partial(u):
-        if use_kernel:
-            from repro.kernels import ops
-            return ops.sddmm_spmm_type1(k_pad, ones_r, u, cols_loc, vals_loc)
-        return ss.sddmm_spmm_type1(k_pad, ones_r, u, cols_loc, vals_loc)
-
-    def body(_, x):
-        u = safe_recip(x)
-        x_part = type1_partial(u)                      # local vocab stripe
-        x_full = jax.lax.psum(x_part, model_axis)      # THE collective
-        return x_full / r_sel[:, None]
-
-    with jax.named_scope("wmd.iterate"):
-        x0 = jnp.full((v_r, n_loc), 1.0 / v_r, dtype=k.dtype)
-        x = jax.lax.fori_loop(0, max_iter, body, x0)
-    # final distance: local xm then scalar-per-doc psum (v_r x cheaper than
-    # reducing xm itself)
-    with jax.named_scope("wmd.final"):
-        u = safe_recip(x)
-        if use_kernel:
-            from repro.kernels import ops
-            wmd_part = ops.sddmm_spmm_type2(k_pad, km_pad, u, cols_loc,
-                                            vals_loc)
-        else:
-            wmd_part = ss.sddmm_spmm_type2(k_pad, km_pad, u, cols_loc,
-                                           vals_loc)
-        return jax.lax.psum(wmd_part, model_axis)
-
-
-# ---------------------------------------------------------------------------
-# Public driver
-# ---------------------------------------------------------------------------
-
-def build_wmd_fn(mesh: Mesh, *, lamb: float, max_iter: int,
-                 doc_axes: Sequence[str] = ("data",),
-                 model_axis: str = "model",
-                 use_kernel: bool = False):
-    """Build the jit'd multi-chip WMD solver for ``mesh``.
-
-    The returned fn takes (vecs_sel, r_sel, row_mask, vecs, cols_b, vals_b):
-      vecs_sel (v_r, w)              replicated   -- query word embeddings
-      r_sel    (v_r,)                replicated
-      row_mask (v_r,)                replicated
-      vecs     (V, w)                P(model)     -- vocab-striped embeddings
-      cols_b   (S_model, N, nnz_loc) P(model, doc_axes) -- rebucketed ELL
-      vals_b   (S_model, N, nnz_loc) P(model, doc_axes)
-    and returns wmd (N,) sharded over doc_axes. It gathers K in every
-    iteration (its ``k_gathers``, see `_with_k_gathers`).
-    """
-    doc_spec = P(tuple(doc_axes))
-    in_specs = (P(None, None), P(None), P(None),
-                P(model_axis, None),
-                P(model_axis, *[tuple(doc_axes)], None),
-                P(model_axis, *[tuple(doc_axes)], None))
-    out_specs = doc_spec
-
-    def per_device(vecs_sel, r_sel, row_mask, vecs_loc, cols_b, vals_b):
-        # leading (shard-local) model axis is size 1 after sharding
-        cols_loc = cols_b[0]
-        vals_loc = vals_b[0]
-        return _local_solve(vecs_sel, r_sel, row_mask, vecs_loc,
-                            cols_loc, vals_loc, lamb=lamb, max_iter=max_iter,
-                            model_axis=model_axis, use_kernel=use_kernel)
-
-    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-    return _with_k_gathers(jax.jit(fn), hoisted=False, max_iter=max_iter)
-
-
-def _per_op_chunk(docs_chunk: int | None,
-                  chunk_placement: str) -> int | None:
-    """The chunk of each contraction op inside the Sinkhorn loop: only
-    "iteration" placement chunks per op ("solve" chunks the whole solve)."""
-    return docs_chunk if chunk_placement == "iteration" else None
-
-
-def _with_k_gathers(fn, *, hoisted: bool, max_iter: int):
-    """Attach ``fn.k_gathers``, the (where, count) a dispatch of ``fn`` adds
-    to the service's ``wmd_k_gathers_total``: ("once", 2) where the program
-    gathers K before its Sinkhorn loop and K.*M once in the final pass,
-    else ("per_iteration", max_iter + 2) -- K in each iteration, then K and
-    K.*M in the final pass (under early exit that is the budget, an upper
-    bound on the iterations run). And ``fn.k_gather_row_bytes(q, v_r)``,
-    the contiguous bytes one ELL slot's K gather fetches at Q queries of
-    v_r rows: one float32 row of all Q queries where K is gathered once
-    (`ss.gather_k_rows`), one query's v_r floats where it is gathered in
-    the loop (`ss.gather_k_batch`, `ss.gather_k`)."""
-    fn.k_gathers = ("once", 2) if hoisted else ("per_iteration",
-                                                 max_iter + 2)
-    fn.k_gather_row_bytes = lambda q, v_r: 4 * v_r * (q if hoisted else 1)
-    return fn
-
 
 def build_wmd_batch_fn(mesh: Mesh, *, lamb: float, max_iter: int,
                        doc_axes: Sequence[str] = ("data",),
-                       model_axis: str = "model", impl: str = "fused",
-                       docs_chunk: int | None = None,
-                       chunk_placement: str = "solve", tol: float = 0.0,
+                       model_axis: str = "model",
+                       docs_chunk: int | None = None, tol: float = 0.0,
                        with_info: bool = False):
-    """Build the jit'd multi-query batched WMD solver for ``mesh``.
+    """Build the jit'd batched WMD solver for ``mesh``: the served solve.
 
-    The (Q, v_r, N) analogue of `build_wmd_fn`: every device gathers K at
-    its ELL slots ONCE for all Q queries, before the Sinkhorn loop, and
-    each iteration's SDDMM and SpMM contractions read that block
-    (`ss.solve_contractions`; per-op "iteration" chunking and the unfused
-    and kernel impls gather in every iteration instead, `fn.k_gathers`
-    says which). The Q solves share the same single psum over
-    ``model`` -- collective count per iteration is independent of Q, so
-    batching amortizes both the gather and the communication latency.
+    Every device gathers K at its ELL slots ONCE for all Q queries, before
+    the Sinkhorn loop, and each iteration's SDDMM and SpMM contractions
+    read that block (`ss.solve_contractions`). The Q solves share the same
+    single psum over ``model`` -- collective count per iteration is
+    independent of Q, so batching amortizes both the gather and the
+    communication latency.
 
-    impl selects the contraction path ("fused" | "unfused" | "kernel", the
-    same table as the single-chip solvers). docs_chunk cache-blocks each
-    device's local doc slice, with ``chunk_placement`` choosing where the
-    chunk loop sits (see sparse_sinkhorn "Batched engine & cache blocking"):
-      * "solve" (default) -- a rolled chunk loop OUTSIDE the Sinkhorn
-        loop: each chunk gathers its own K block and runs all its
-        iterations, so one chunk's blocks are live at a time (how
-        `plan_docs_chunk` bounds memory) and the program holds one
-        Sinkhorn loop whatever the chunk count. The psum count becomes
-        iterations x chunks, and tol freezes each (query, chunk) block at
-        its own convergence (the reported n_iter/delta are per-query
-        maxima over chunks).
-      * "iteration" -- per-op chunking inside the iteration-major loop:
-        keeps ONE psum per iteration (the multi-chip contract) and global
-        per-query freeze semantics exactly matching
-        `core.convergence.sinkhorn_wmd_converged_batch`.
+    docs_chunk bounds each device's blocks by chunking its local doc slice
+    with a rolled chunk loop OUTSIDE the Sinkhorn loop: each chunk gathers
+    its own K block and runs all its iterations, so one chunk's blocks are
+    live at a time (how `plan_docs_chunk` bounds memory) and the program
+    holds one Sinkhorn loop whatever the chunk count. The psum count
+    becomes iterations x chunks, and tol freezes each (query, chunk) block
+    at its own convergence (the reported n_iter/delta are per-query maxima
+    over chunks).
 
     Early exit (tol > 0): the loop is `ss.batched_sinkhorn_loop` with an
     **all-shards convergence vote** -- each device reduces its local doc
     slice to a per-query delta, and a pmax all-reduce over (model, *doc_axes)
     makes the vote unanimous. The pmax of per-shard inf-norms IS the global
-    inf-norm, so per-query freeze/n_iter decisions match the single-host
-    `sinkhorn_wmd_converged_batch` exactly (equivalently one could psum
+    inf-norm, so unchunked, per-query freeze/n_iter decisions match the
+    single-host `sinkhorn_wmd_converged_batch` (equivalently one could psum
     per-shard "still active" votes; the pmax also reproduces the reported
     delta). Converged queries stop contributing writes on every shard; the
     loop (and with it all collectives) exits when every query has converged
@@ -257,9 +145,6 @@ def build_wmd_batch_fn(mesh: Mesh, *, lamb: float, max_iter: int,
     Retracing happens per distinct Q; callers bound it by bucketing Q
     (see serving.wmd_service admission).
     """
-    if chunk_placement not in ("solve", "iteration"):
-        raise ValueError(f"chunk_placement must be 'solve' or 'iteration', "
-                         f"got {chunk_placement!r}")
     in_specs = (P(None, None, None), P(None, None), P(None, None),
                 P(model_axis, None),
                 P(model_axis, *[tuple(doc_axes)], None),
@@ -275,18 +160,15 @@ def build_wmd_batch_fn(mesh: Mesh, *, lamb: float, max_iter: int,
             cols_loc, vals_loc = cols_b[0], vals_b[0]
         wmd, n_iter, delta = _local_batched_solve(
             k_pad, km_pad, r_sel, cols_loc, vals_loc,
-            max_iter=max_iter, model_axis=model_axis, impl=impl,
-            docs_chunk=docs_chunk, chunk_placement=chunk_placement, tol=tol,
-            vote_axes=vote_axes)
+            max_iter=max_iter, model_axis=model_axis,
+            docs_chunk=docs_chunk, tol=tol, vote_axes=vote_axes)
         if with_info:
             return wmd, n_iter, delta
         return wmd
 
     fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    return _with_k_gathers(
-        jax.jit(fn), max_iter=max_iter, hoisted=ss.hoists_k_gather(
-            impl, _per_op_chunk(docs_chunk, chunk_placement)))
+    return jax.jit(fn)
 
 
 LANES = 128                  # a TPU vreg's minor axis
@@ -342,9 +224,8 @@ def plan_docs_chunk(q: int, v_r: int, nnz_loc: int, n_loc: int, v_loc: int,
 
 
 def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
-                         max_iter: int, model_axis: str, impl: str,
-                         docs_chunk: int | None, chunk_placement: str,
-                         tol: float, vote_axes):
+                         max_iter: int, model_axis: str,
+                         docs_chunk: int | None, tol: float, vote_axes):
     """Per-device batched Sinkhorn solve on local (Q, v_r, Vloc+1) stripes.
 
     The shared core of `build_wmd_batch_fn` (stripes computed in-program
@@ -354,18 +235,14 @@ def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
     """
     q, v_r = r_sel.shape
     ones_r = jnp.ones_like(r_sel)
-    iter_chunk = _per_op_chunk(docs_chunk, chunk_placement)
-    # the (V+1, Q*v_r) row tables the hoisted gathers read: built once,
-    # outside the chunk loop
-    rows = ss.k_row_tables(impl, k_pad, km_pad, iter_chunk)
+    # the (V+1, Q*v_r) row tables the gathers read: built once, outside
+    # the chunk loop
+    rows = ss.k_row_tables(k_pad, km_pad)
 
     def solve_chunk(x0_c, cols_c, vals_c):
-        # the chunk's K block is gathered once, before the loop, where
-        # `ss.hoists_k_gather` allows; per-op ("iteration") chunking keeps
-        # its bounded per-op working set and gathers in the loop
-        type1, type2 = ss.solve_contractions(
-            impl, k_pad, km_pad, ones_r, cols_c, vals_c,
-            docs_chunk=iter_chunk, rows=rows)
+        # the chunk's K block is gathered once, before the loop
+        type1, type2 = ss.solve_contractions(k_pad, km_pad, ones_r, cols_c,
+                                             vals_c, rows=rows)
 
         def iteration(x):
             x_part = type1(safe_recip(x))
@@ -387,8 +264,7 @@ def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
             return jax.lax.psum(wmd_part, model_axis), n_iter, delta
 
     n_loc = cols_loc.shape[0]
-    if not (chunk_placement == "solve" and docs_chunk
-            and docs_chunk < n_loc):
+    if not (docs_chunk and docs_chunk < n_loc):
         with jax.named_scope("wmd.iterate"):
             x0 = jnp.full((q, v_r, n_loc), 1.0 / v_r, dtype=k_pad.dtype)
         return solve_chunk(x0, cols_loc, vals_loc)
@@ -406,9 +282,7 @@ def _local_batched_solve(k_pad, km_pad, r_sel, cols_loc, vals_loc, *,
 def build_wmd_batch_fn_stripes(mesh: Mesh, *, max_iter: int,
                                doc_axes: Sequence[str] = ("data",),
                                model_axis: str = "model",
-                               impl: str = "fused",
                                docs_chunk: int | None = None,
-                               chunk_placement: str = "solve",
                                tol: float = 0.0, with_info: bool = False):
     """Batched WMD solver consuming *preassembled* K / K.*M stripes.
 
@@ -426,12 +300,9 @@ def build_wmd_batch_fn_stripes(mesh: Mesh, *, max_iter: int,
     and returns wmd (Q, N) sharded over doc_axes (plus (n_iter, delta) with
     ``with_info=True``). No ``lamb``: it is baked into the cached rows, and
     the cache invalidates itself on a lambda change. Everything else
-    (impl table, docs_chunk/chunk_placement, early-exit vote) is identical
-    to `build_wmd_batch_fn`, with which it shares `_local_batched_solve`.
+    (docs_chunk, early-exit vote) is identical to `build_wmd_batch_fn`,
+    with which it shares `_local_batched_solve`.
     """
-    if chunk_placement not in ("solve", "iteration"):
-        raise ValueError(f"chunk_placement must be 'solve' or 'iteration', "
-                         f"got {chunk_placement!r}")
     in_specs = (P(model_axis, None, None, None),
                 P(model_axis, None, None, None),
                 P(None, None),
@@ -448,18 +319,15 @@ def build_wmd_batch_fn_stripes(mesh: Mesh, *, max_iter: int,
             cols_loc, vals_loc = cols_b[0], vals_b[0]
         wmd, n_iter, delta = _local_batched_solve(
             k_pad, km_pad, r_sel, cols_loc, vals_loc,
-            max_iter=max_iter, model_axis=model_axis, impl=impl,
-            docs_chunk=docs_chunk, chunk_placement=chunk_placement, tol=tol,
-            vote_axes=vote_axes)
+            max_iter=max_iter, model_axis=model_axis,
+            docs_chunk=docs_chunk, tol=tol, vote_axes=vote_axes)
         if with_info:
             return wmd, n_iter, delta
         return wmd
 
     fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    return _with_k_gathers(
-        jax.jit(fn), max_iter=max_iter, hoisted=ss.hoists_k_gather(
-            impl, _per_op_chunk(docs_chunk, chunk_placement)))
+    return jax.jit(fn)
 
 
 def build_wmd_fn_docsharded(mesh: Mesh, *, lamb: float, max_iter: int,
@@ -509,7 +377,8 @@ def build_wmd_fn_docsharded(mesh: Mesh, *, lamb: float, max_iter: int,
 def shard_wmd_inputs(mesh: Mesh, vecs: np.ndarray, cols_b: np.ndarray,
                      vals_b: np.ndarray, *, doc_axes: Sequence[str] = ("data",),
                      model_axis: str = "model"):
-    """Place host arrays on the mesh with the layouts build_wmd_fn expects."""
+    """Place host arrays on the mesh with the layouts build_wmd_batch_fn
+    expects."""
     dev = lambda spec: NamedSharding(mesh, spec)
     vecs_d = jax.device_put(vecs, dev(P(model_axis, None)))
     cols_d = jax.device_put(cols_b, dev(P(model_axis, tuple(doc_axes), None)))

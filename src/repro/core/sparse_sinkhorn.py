@@ -14,11 +14,11 @@ type2 (final distance) swaps the SpMM operand to K.*M and reduces in-kernel:
 
     WMD[j] = sum_i u[i,j] * sum_k (K.*M)[i, cols[j,k]] * v[j,k]
 
-Three execution paths, selected by ``impl`` (one table, shared by the
-single-query and the batched solver -- see `_resolve_impl`):
-  * "fused"    -- one gather feeds both contractions (jnp); the batched
-                  solvers gather it once per solve (see below). Production
-                  jnp path and oracle for the Pallas kernel.
+The single-query solver (`sinkhorn_wmd_sparse`) has three execution paths,
+selected by ``impl`` (see `_resolve_impl`); it is the paper's Fig. 9
+baseline and the oracle of the per-path benchmarks, and no served program
+calls it:
+  * "fused"    -- one gather feeds both contractions (jnp).
   * "unfused"  -- separate SDDMM / SpMM with independent gathers, mirroring
                   the paper's pre-fusion baseline (Fig. 9 numerator).
   * "kernel"   -- `repro.kernels.ops` Pallas kernels (interpret=True on CPU).
@@ -26,52 +26,33 @@ single-query and the batched solver -- see `_resolve_impl`):
 All paths consume K padded with one trailing zero column so ELL pad slots
 (col == V) contribute exactly zero.
 
-Batched engine & cache blocking
--------------------------------
-The batched iteration's nominal working set is the gathered tensor
-``(Q, v_r, N, nnz_max) * 4B`` -- at a bulk shape (Q=16, N=1024, nnz=64,
-v_r=16) that is 64 MB, far past CPU LLC (and any VMEM budget), which is
-where `bench_query_batch.py` showed batched throughput collapsing to
-sequential parity. ``docs_chunk`` cache-blocks the engine at two levels:
-
-  * per-op (``sddmm_spmm_type{1,2}_batch(docs_chunk=...)``): the SAME fused
-    math over static N-chunks, live gather ``(Q, v_r, docs_chunk, nnz)``.
-    Bitwise exact -- every output element's FP op sequence is unchanged
-    because both contractions reduce within a single doc (over v_r resp.
-    nnz), never across docs. Used inside iteration-major loops that must
-    keep ONE collective per iteration (`core.distributed`) or global
-    per-query convergence state (`core.convergence`).
-  * per-solve (`sinkhorn_wmd_sparse_batch(docs_chunk=...)`): docs are
-    *independent* OT problems, so the chunk loop hoists OUTSIDE the whole
-    Sinkhorn loop -- each chunk runs all of its iterations while its
-    ``(Q, v_r, docs_chunk)`` iterate (and the chunk's ELL slice) stays
-    cache-resident across iterations, instead of sweeping the full
-    ``(Q, v_r, N)`` state every iteration. Measured 1.5-3.3x over the
-    iteration-major unchunked loop at bulk shapes (N >= 1024, Q = 16) on a
-    2-core CPU; identical results.
-
-Non-dividing N is handled by padding docs with ELL pad slots (col = V ->
-the zero K column, val = 0), whose outputs are sliced off. The chunk loop
-is unrolled in-trace (preserving XLA's gather-into-contraction fusion; the
-rolled `map_doc_chunks` bounds HLO size past MAX_UNROLLED_CHUNKS, and is
-the chunk loop of the served solve, `core.distributed`). The Pallas
-analogue is the ``docs_blk`` / ``q_blk`` grid tiling in
-`kernels.sddmm_spmm` ("Batched kernel & cache blocking" there).
-
-Gathered once per solve: K[:, :, cols] depends on neither the iterate nor
-the iteration, so the fused batched solvers (`solve_contractions`) gather
-it once, before the Sinkhorn loop, into a (Q, v_r, N, nnz) block (nnz on
-the TPU's 128-wide lane axis) that every iteration and the final pass
-read; the final pass gathers K.*M once more. That holds per solve chunk
-(``docs_chunk`` outside the loop bounds the block); the unfused and kernel
-impls and per-op chunking gather K in every iteration instead
-(`hoists_k_gather`). On one v5e at paper_5k the in-loop gather was 95% of
-the solve. Those two gathers read a row table (`k_row_table`): the
-stripes laid out (V+1, Q*v_r), built once per solve, outside any chunk
-loop, so each ELL slot fetches one contiguous row of Q*v_r floats for all
-Q queries (`gather_k_rows`), where the (Q, V+1, v_r) table of
-`gather_k_batch` has it fetch Q strided rows of v_r. The gathered
+Batched engine
+--------------
+The batched solvers (`sinkhorn_wmd_sparse_batch`, the distributed solve
+of `core.distributed`) run one contraction path: the fused one, with K
+gathered once per solve. K[:, :, cols] depends on neither the iterate nor
+the iteration, so `solve_contractions` gathers it once, before the
+Sinkhorn loop, into a (Q, v_r, N, nnz) block (nnz on the TPU's 128-wide
+lane axis) that every iteration and the final pass read; the final pass
+gathers K.*M once more. On one v5e at paper_5k a gather in every
+iteration was 95% of the solve. Both gathers read a row table
+(`k_row_table`): the stripes laid out (V+1, Q*v_r), built once per solve,
+outside any chunk loop, so each ELL slot fetches one contiguous row of
+Q*v_r floats for all Q queries (`gather_k_rows`), where the (Q, V+1, v_r)
+table of `gather_k_batch` has it fetch Q strided rows of v_r. The gathered
 (N, nnz, Q*v_r) rows are relaid out once into the block.
+
+The block grows with N, so ``docs_chunk`` bounds it by chunking the SOLVE
+over documents: docs are independent OT problems, so the chunk loop sits
+OUTSIDE the whole Sinkhorn loop -- each chunk gathers its own block and
+runs all of its iterations while its ``(Q, v_r, docs_chunk)`` iterate
+stays cache-resident. Results are bitwise per chunk: both contractions
+reduce within a single doc (over v_r resp. nnz), never across docs.
+Non-dividing N is handled by padding docs with ELL pad slots (col = V ->
+the zero K column, val = 0), whose outputs are sliced off. Here the chunk
+loop is unrolled in-trace (`_chunk_over_docs`, which rolls up into
+`map_doc_chunks` past MAX_UNROLLED_CHUNKS); the served solve of
+`core.distributed` sweeps its chunks with the rolled `map_doc_chunks`.
 
 Early exit: `batched_sinkhorn_loop` is the shared while-loop core -- per
 query, iteration stops contributing writes once its relative iterate delta
@@ -176,79 +157,32 @@ def _type1_unfused(k_pad: jax.Array, r_sel: jax.Array, u: jax.Array,
     return spmm(k_pad / r_sel[:, None], v, cols)
 
 
-def _type1_unfused_batch(k_pad: jax.Array, r_sel: jax.Array, u: jax.Array,
-                         cols: jax.Array, vals: jax.Array) -> jax.Array:
-    v = sddmm_batch(k_pad, u, cols, vals)
-    v = jax.lax.optimization_barrier(v)
-    return spmm_batch(k_pad / r_sel[..., None], v, cols)
-
-
-def _resolve_impl(kind: str, impl: str, batched: bool):
-    """The ONE impl dispatch table, shared by the single-query and batched
-    solvers (and `core.distributed`). kind: "type1" (iteration contraction,
-    signature (k_pad, r_sel, u, cols, vals)) or "type2" (final distance,
-    signature (k_pad, km_pad, u, cols, vals)). Batched "type1"/"type2"
-    additionally accept ``docs_chunk=``."""
+def _resolve_impl(kind: str, impl: str):
+    """The single-query solver's impl dispatch table. kind: "type1"
+    (iteration contraction, signature (k_pad, r_sel, u, cols, vals)) or
+    "type2" (final distance, signature (k_pad, km_pad, u, cols, vals))."""
     if impl not in _IMPLS:
         raise ValueError(f"impl must be one of {_IMPLS}, got {impl!r}")
     if impl == "kernel":
         from repro.kernels import ops
-        table = {("type1", False): ops.sddmm_spmm_type1,
-                 ("type2", False): ops.sddmm_spmm_type2,
-                 ("type1", True): _kernel_type1_batch,
-                 ("type2", True): _kernel_type2_batch}
-    else:
-        # the unfused baseline shares the fused final distance (the paper's
-        # Fig. 9 baseline differs only in the iteration body).
-        t1 = _type1_unfused if impl == "unfused" else sddmm_spmm_type1
-        t1b = (_unfused_batch_ignoring_chunk if impl == "unfused"
-               else sddmm_spmm_type1_batch)
-        t2b = (_unfused_final_batch_ignoring_chunk if impl == "unfused"
-               else sddmm_spmm_type2_batch)
-        table = {("type1", False): t1,
-                 ("type2", False): sddmm_spmm_type2,
-                 ("type1", True): t1b,
-                 ("type2", True): t2b}
-    return table[(kind, batched)]
-
-
-def _unfused_batch_ignoring_chunk(k_pad, r_sel, u, cols, vals, *,
-                                  docs_chunk=None):
-    del docs_chunk  # the baseline stays deliberately unblocked
-    return _type1_unfused_batch(k_pad, r_sel, u, cols, vals)
-
-
-def _unfused_final_batch_ignoring_chunk(k_pad, km_pad, u, cols, vals, *,
-                                        docs_chunk=None):
-    # same rule for the final distance: the unfused baseline must stay
-    # unblocked END TO END or fused-vs-unfused perf comparisons mix modes.
-    del docs_chunk
-    return sddmm_spmm_type2_batch(k_pad, km_pad, u, cols, vals)
-
-
-def _kernel_type1_batch(k_pad, r_sel, u, cols, vals, *, docs_chunk=None):
-    # the kernel's native cache blocking IS its doc-tile grid: docs_chunk
-    # maps onto docs_blk instead of an outer scan (None/0 = default tile).
-    from repro.kernels import ops
-    kw = {} if not docs_chunk else {"docs_blk": docs_chunk}
-    return ops.sddmm_spmm_type1_batch(k_pad, r_sel, u, cols, vals, **kw)
-
-
-def _kernel_type2_batch(k_pad, km_pad, u, cols, vals, *, docs_chunk=None):
-    from repro.kernels import ops
-    kw = {} if not docs_chunk else {"docs_blk": docs_chunk}
-    return ops.sddmm_spmm_type2_batch(k_pad, km_pad, u, cols, vals, **kw)
+        return {"type1": ops.sddmm_spmm_type1,
+                "type2": ops.sddmm_spmm_type2}[kind]
+    # the unfused baseline shares the fused final distance (the paper's
+    # Fig. 9 baseline differs only in the iteration body).
+    if kind == "type2":
+        return sddmm_spmm_type2
+    return _type1_unfused if impl == "unfused" else sddmm_spmm_type1
 
 
 def _iteration(impl: str, pre_kpad: jax.Array, r_sel: jax.Array,
                x: jax.Array, cols: jax.Array, vals: jax.Array) -> jax.Array:
-    return _resolve_impl("type1", impl, False)(
-        pre_kpad, r_sel, safe_recip(x), cols, vals)
+    return _resolve_impl("type1", impl)(pre_kpad, r_sel, safe_recip(x), cols,
+                                        vals)
 
 
 def _final(impl: str, k_pad: jax.Array, km_pad: jax.Array, u: jax.Array,
            cols: jax.Array, vals: jax.Array) -> jax.Array:
-    return _resolve_impl("type2", impl, False)(k_pad, km_pad, u, cols, vals)
+    return _resolve_impl("type2", impl)(k_pad, km_pad, u, cols, vals)
 
 
 # ---------------------------------------------------------------------------
@@ -343,10 +277,9 @@ def gather_k_batch(k_pad: jax.Array, cols: jax.Array) -> jax.Array:
     dims (q, n) lead, from the (Q, V+1, v_r) table, so each ELL slot
     fetches Q rows of v_r floats, one per query, V+1 rows apart (the
     (N, nnz, Q, v_r) alternative forces XLA to re-lay it out before every
-    dot inside the loop -- measured ~2.3x slower on CPU). The unfused
-    baseline, per-op chunking and the RWMD bound contract it in this
-    layout, inside their loops; the solve that gathers once reads the row
-    table instead (`gather_k_rows`).
+    dot inside the loop -- measured ~2.3x slower on CPU). The RWMD bound
+    contracts it in this layout; the solve reads the row table instead
+    (`gather_k_rows`).
     """
     with jax.named_scope("wmd.gather"):
         return jnp.transpose(k_pad, (0, 2, 1))[:, cols]
@@ -360,8 +293,8 @@ def gather_k_block(k_pad: jax.Array, cols: jax.Array) -> jax.Array:
     with ELL slots; with v_r (often 32) there it would be padded 4x. This
     is the layout of the block a solve gathers once and carries through
     its Sinkhorn loop (`solve_contractions`), which gathers it from the row
-    table (`gather_k_rows`, bitwise the same block); per-op chunking
-    gathers it here, once per op.
+    table (`gather_k_rows`, bitwise the same block);
+    `sddmm_spmm_type2_batch` gathers it here, per call.
     """
     kg = gather_k_batch(k_pad, cols)
     with jax.named_scope("wmd.gather"):
@@ -409,7 +342,7 @@ def type2_from_block(kg: jax.Array, kmg: jax.Array, u: jax.Array,
     The per-doc reduction is spelled sum_k v * <(K.*M) col, u> -- the u
     contraction happens inside the dot_general and the outer reduce runs
     over the nnz (last) axis, whose extent is chunk-independent. That keeps
-    ``docs_chunk`` bitwise exact.
+    a solve's ``docs_chunk`` bitwise exact.
     """
     w = jnp.einsum("qink,qin->qnk", kg, u, precision=F32)
     v = jnp.where(vals[None] != 0.0, vals[None] * safe_recip(w), 0.0)
@@ -422,14 +355,6 @@ def type2_from_block(kg: jax.Array, kmg: jax.Array, u: jax.Array,
 # inside the loop body (measured up to ~4x slower on CPU) -- callers wanting
 # peak throughput should pick docs_chunk so S stays under this.
 MAX_UNROLLED_CHUNKS = 64
-
-
-def hoists_k_gather(impl: str, per_op_chunk: int | None) -> bool:
-    """Whether a solve gathers K once, before its Sinkhorn loop, instead of
-    once per iteration: the fused impl does, unless its ops are chunked
-    per call (``per_op_chunk``), which exists to bound each op's working
-    set and would be defeated by a whole-slice block."""
-    return impl == "fused" and per_op_chunk is None
 
 
 def _chunk_over_docs(f, u: jax.Array, cols: jax.Array, vals: jax.Array,
@@ -499,101 +424,45 @@ def join_doc_chunks(out: jax.Array, n: int) -> jax.Array:
     return out.reshape(*out.shape[:-2], -1)[..., :n]
 
 
-def sddmm_batch(k_pad: jax.Array, u: jax.Array, cols: jax.Array,
-                vals: jax.Array) -> jax.Array:
-    """Batched sampled dense-dense matmul with its own gather (unfused)."""
-    kg = gather_k_batch(k_pad, cols)                 # gather #1
-    w = jnp.einsum("qnki,qin->qnk", kg, u, precision=F32)
-    return jnp.where(vals[None] != 0.0, vals[None] * safe_recip(w), 0.0)
-
-
-def spmm_batch(kor_pad: jax.Array, v: jax.Array, cols: jax.Array
-               ) -> jax.Array:
-    """Batched SpMM -- re-gathers K (the unfused baseline's second gather)."""
-    kg = gather_k_batch(kor_pad, cols)               # gather #2 (unfused cost)
-    return jnp.einsum("qnki,qnk->qin", kg, v, precision=F32)
-
-
-def sddmm_spmm_type1_batch(k_pad: jax.Array, r_sel: jax.Array, u: jax.Array,
-                           cols: jax.Array, vals: jax.Array, *,
-                           docs_chunk: int | None = None) -> jax.Array:
-    """Batched fused iteration body: (Q, v_r, N) <- one gather, two einsums.
-
-    Same math per query as `sddmm_spmm_type1`: `gather_k_block` then
-    `type1_from_block`. ``docs_chunk`` runs the same math over N-chunks so
-    the live gathered working set is (Q, v_r, docs_chunk, nnz) -- bitwise
-    identical, see "Batched engine & cache blocking" in the module
-    docstring.
-
-    k_pad (Q, v_r, V+1), r_sel (Q, v_r), u (Q, v_r, N), cols/vals (N, nnz).
-    """
-    def chunk(u_c, cols_c, vals_c):
-        return type1_from_block(gather_k_block(k_pad, cols_c), r_sel, u_c,
-                                vals_c)
-
-    return _chunk_over_docs(chunk, u, cols, vals, docs_chunk,
-                            pad_col=k_pad.shape[-1] - 1)
-
-
 def sddmm_spmm_type2_batch(k_pad: jax.Array, km_pad: jax.Array, u: jax.Array,
-                           cols: jax.Array, vals: jax.Array, *,
-                           docs_chunk: int | None = None) -> jax.Array:
-    """Batched fused final distance: (Q, N) WMD for all queries at once:
-    `gather_k_block` of K and of K.*M, then `type2_from_block`."""
-    def chunk(u_c, cols_c, vals_c):
-        return type2_from_block(gather_k_block(k_pad, cols_c),
-                                gather_k_block(km_pad, cols_c), u_c, vals_c)
-
-    return _chunk_over_docs(chunk, u, cols, vals, docs_chunk,
-                            pad_col=k_pad.shape[-1] - 1)
+                           cols: jax.Array, vals: jax.Array) -> jax.Array:
+    """Batched fused final distance on one call's own gathers: (Q, N) WMD
+    for all queries at once, `gather_k_block` of K and of K.*M, then
+    `type2_from_block`. k_pad/km_pad (Q, v_r, V+1), u (Q, v_r, N),
+    cols/vals (N, nnz)."""
+    return type2_from_block(gather_k_block(k_pad, cols),
+                            gather_k_block(km_pad, cols), u, vals)
 
 
-def k_row_tables(impl: str, k_pad: jax.Array, km_pad: jax.Array,
-                 docs_chunk: int | None = None):
+def k_row_tables(k_pad: jax.Array, km_pad: jax.Array):
     """The (K, K.*M) `k_row_table` pair that `solve_contractions` gathers
-    from where `hoists_k_gather` allows, else None. A solve that sweeps
-    chunks builds them here, once, outside its chunk loop, and passes them
-    to each chunk's `solve_contractions`."""
-    if not hoists_k_gather(impl, docs_chunk):
-        return None
+    from. A solve that sweeps chunks builds them here, once, outside its
+    chunk loop, and passes them to each chunk's `solve_contractions`."""
     with jax.named_scope("wmd.precompute"):
         return k_row_table(k_pad), k_row_table(km_pad)
 
 
-def solve_contractions(impl: str, k_pad: jax.Array, km_pad: jax.Array,
+def solve_contractions(k_pad: jax.Array, km_pad: jax.Array,
                        r_sel: jax.Array, cols: jax.Array, vals: jax.Array, *,
-                       docs_chunk: int | None = None, hoist: bool = True,
                        rows=None):
     """The (iteration, final) contractions of one solve over one doc slice:
     ``iteration(u)`` -> (Q, v_r, N) x, ``final(u)`` -> (Q, N) distances.
 
-    Where `hoists_k_gather` allows (and ``hoist``), K is gathered here,
-    once, from its row table (``rows``, the `k_row_tables` pair, built
-    here when None) into a (Q, v_r, N, nnz) block under ``wmd.precompute``
-    that every iteration and the final pass read; the final pass gathers
-    K.*M once more. Otherwise both run the impl table's per-call ops, which
-    gather K again on every call (``docs_chunk`` per-op chunking;
-    ``hoist=False`` forces this path for the fused impl too, and ``rows``
-    goes unread). Call it outside the Sinkhorn loop: the block is then a
-    loop invariant.
+    K is gathered here, once, from its row table (``rows``, the
+    `k_row_tables` pair, built here when None) into a (Q, v_r, N, nnz)
+    block under ``wmd.precompute`` that every iteration and the final pass
+    read; the final pass gathers K.*M once more. Call it outside the
+    Sinkhorn loop: the block is then a loop invariant.
     """
-    if hoist and hoists_k_gather(impl, docs_chunk):
-        k_rows, km_rows = rows or k_row_tables(impl, k_pad, km_pad)
-        q = r_sel.shape[0]
-        with jax.named_scope("wmd.precompute"):
-            kg = gather_k_rows(k_rows, cols, q)
+    k_rows, km_rows = rows or k_row_tables(k_pad, km_pad)
+    q = r_sel.shape[0]
+    with jax.named_scope("wmd.precompute"):
+        kg = gather_k_rows(k_rows, cols, q)
 
-        def final(u):
-            return type2_from_block(kg, gather_k_rows(km_rows, cols, q), u,
-                                    vals)
+    def final(u):
+        return type2_from_block(kg, gather_k_rows(km_rows, cols, q), u, vals)
 
-        return (lambda u: type1_from_block(kg, r_sel, u, vals)), final
-    type1 = _resolve_impl("type1", impl, True)
-    type2 = _resolve_impl("type2", impl, True)
-    return (lambda u: type1(k_pad, r_sel, u, cols, vals,
-                            docs_chunk=docs_chunk),
-            lambda u: type2(k_pad, km_pad, u, cols, vals,
-                            docs_chunk=docs_chunk))
+    return (lambda u: type1_from_block(kg, r_sel, u, vals)), final
 
 
 def batched_sinkhorn_loop(iteration, x0: jax.Array, *, max_iter: int,
@@ -646,23 +515,20 @@ def batched_sinkhorn_loop(iteration, x0: jax.Array, *, max_iter: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("max_iter", "impl", "docs_chunk", "tol"))
+                   static_argnames=("max_iter", "docs_chunk", "tol"))
 def sinkhorn_wmd_sparse_batch(sel_idx: jax.Array, r_sel: jax.Array,
                               cols: jax.Array, vals: jax.Array,
                               vecs: jax.Array, lamb: float, max_iter: int,
                               row_mask: jax.Array | None = None,
-                              impl: str = "fused",
                               docs_chunk: int | None = None,
                               tol: float = 0.0) -> jax.Array:
     """Multi-query sparse PASWD Sinkhorn-WMD. Returns (Q, N) distances.
 
-    The per-query math is identical to `sinkhorn_wmd_sparse` with the same
-    ``impl``; queries never interact -- the batch axis only amortizes the
-    ELL gather, the dispatch, and the K precompute. Matches the sequential
-    per-query solve to fp32 tolerance.
+    The per-query math is that of the fused `sinkhorn_wmd_sparse`, with K
+    gathered once per solve (see "Batched engine"); queries never interact
+    -- the batch axis only amortizes the ELL gather, the dispatch, and the
+    K precompute. Matches the per-query solve to fp32 tolerance.
 
-    impl:       "fused" | "unfused" | "kernel" (same table as the
-                single-query solver).
     docs_chunk: cache-block the SOLVE over N-chunks of this size: the chunk
                 loop sits outside the Sinkhorn loop (docs are independent
                 OT problems), so each chunk's (Q, v_r, docs_chunk) iterate
@@ -675,21 +541,21 @@ def sinkhorn_wmd_sparse_batch(sel_idx: jax.Array, r_sel: jax.Array,
     """
     pre = precompute_batch(sel_idx, r_sel, vecs, lamb, row_mask)
     return _solve_batch_stripes(pad_k(pre.K), pad_k(pre.KM), pre.r,
-                                cols, vals, max_iter=max_iter, impl=impl,
+                                cols, vals, max_iter=max_iter,
                                 docs_chunk=docs_chunk, tol=tol)
 
 
 def _solve_batch_stripes(k_pad: jax.Array, km_pad: jax.Array,
                          r_sel: jax.Array, cols: jax.Array, vals: jax.Array,
-                         *, max_iter: int, impl: str,
-                         docs_chunk: int | None, tol: float) -> jax.Array:
+                         *, max_iter: int, docs_chunk: int | None,
+                         tol: float) -> jax.Array:
     """Shared solver core on preassembled (Q, v_r, V+1) stripes (with the
     zero pad column already appended -- `core.kcache` stores rows that way,
     so the cached hot path never runs `pad_k`)."""
     q, v_r = r_sel.shape
     n = cols.shape[0]
     x0 = jnp.full((q, v_r, n), 1.0 / v_r, dtype=k_pad.dtype)
-    rows = k_row_tables(impl, k_pad, km_pad)
+    rows = k_row_tables(k_pad, km_pad)
 
     def solve_chunk(x0_c, cols_c, vals_c):
         # docs never interact across the Sinkhorn iteration (each doc is an
@@ -697,11 +563,10 @@ def _solve_batch_stripes(k_pad: jax.Array, km_pad: jax.Array,
         # OUTSIDE the whole solve: each chunk runs all its iterations while
         # its (Q, v_r, docs_chunk) iterate stays cache-resident -- measured
         # 1.5-3.3x over the iteration-major unchunked loop at bulk shapes
-        # on CPU (see "Batched engine & cache blocking"). The chunk's K
-        # block is gathered here, once, from the row tables built before
-        # the chunk loop, where `hoists_k_gather` allows.
-        type1, final = solve_contractions(impl, k_pad, km_pad, r_sel,
-                                          cols_c, vals_c, rows=rows)
+        # on CPU. The chunk's K block is gathered here, once, from the row
+        # tables built before the chunk loop.
+        type1, final = solve_contractions(k_pad, km_pad, r_sel, cols_c,
+                                          vals_c, rows=rows)
 
         def iteration(x):
             return type1(safe_recip(x))
@@ -721,11 +586,10 @@ def _solve_batch_stripes(k_pad: jax.Array, km_pad: jax.Array,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("max_iter", "impl", "docs_chunk", "tol"))
+                   static_argnames=("max_iter", "docs_chunk", "tol"))
 def sinkhorn_wmd_sparse_batch_stripes(k_pad: jax.Array, km_pad: jax.Array,
                                       r_sel: jax.Array, cols: jax.Array,
                                       vals: jax.Array, max_iter: int,
-                                      impl: str = "fused",
                                       docs_chunk: int | None = None,
                                       tol: float = 0.0) -> jax.Array:
     """Batched solver on *preassembled* precompute stripes. Returns (Q, N).
@@ -736,10 +600,10 @@ def sinkhorn_wmd_sparse_batch_stripes(k_pad: jax.Array, km_pad: jax.Array,
     with the trailing zero pad column already in place (ELL pad slots gather
     it) and pad query rows already zeroed. ``r_sel`` (Q, v_r) carries 1.0 in
     pad rows; K_over_r remains the in-solver per-row 1/r scale, so no third
-    stripe is materialized. Identical math (same impl table, chunking and
-    early-exit semantics) as `sinkhorn_wmd_sparse_batch`, which now merely
-    computes the stripes from embeddings and delegates here.
+    stripe is materialized. Identical math (chunking and early-exit
+    semantics) as `sinkhorn_wmd_sparse_batch`, which merely computes the
+    stripes from embeddings and delegates here.
     """
     return _solve_batch_stripes(k_pad, km_pad, r_sel, cols, vals,
-                                max_iter=max_iter, impl=impl,
-                                docs_chunk=docs_chunk, tol=tol)
+                                max_iter=max_iter, docs_chunk=docs_chunk,
+                                tol=tol)

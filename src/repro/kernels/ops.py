@@ -74,50 +74,6 @@ def sddmm_spmm_type2(k_pad: jax.Array, km_pad: jax.Array, u: jax.Array,
     return wmd[:n]
 
 
-def sddmm_spmm_type1_batch(k_pad: jax.Array, r_sel: jax.Array, u: jax.Array,
-                           cols: jax.Array, vals: jax.Array, *,
-                           docs_blk: int = 8,
-                           q_blk: int | None = None) -> jax.Array:
-    """Batched (Q-stripe) fused iteration body; see kernels.sddmm_spmm.
-
-    Pads v_r to 8 (r pads with 1.0), docs to docs_blk, and Q to q_blk
-    (default min(Q, 8)); un-pads the result. Q-pad stripes carry an all-zero
-    K (so w = 0 and the masked v multiplies a zero column -> exact zeros,
-    sliced off). K's zero pad column must already be present.
-    """
-    q, v_r, n = u.shape
-    if q_blk is None:
-        q_blk = min(q, 8)
-    k_p = _pad_to(_pad_to(k_pad, 1, 8), 0, q_blk)
-    r_p = _pad_to(_pad_to(r_sel, 1, 8, value=1.0), 0, q_blk, value=1.0)
-    u_p = _pad_to(_pad_to(_pad_to(u, 1, 8), 2, docs_blk), 0, q_blk)
-    cols_p = _pad_to(cols, 0, docs_blk, value=k_pad.shape[-1] - 1)
-    vals_p = _pad_to(vals, 0, docs_blk)
-    x = _sddmm_spmm.sddmm_spmm_type1_batch(
-        k_p, r_p, u_p, cols_p, vals_p,
-        docs_blk=docs_blk, q_blk=q_blk, interpret=_interpret())
-    return x[:q, :v_r, :n]
-
-
-def sddmm_spmm_type2_batch(k_pad: jax.Array, km_pad: jax.Array, u: jax.Array,
-                           cols: jax.Array, vals: jax.Array, *,
-                           docs_blk: int = 8,
-                           q_blk: int | None = None) -> jax.Array:
-    """Batched fused final-distance kernel; returns (Q, N) WMD."""
-    q, v_r, n = u.shape
-    if q_blk is None:
-        q_blk = min(q, 8)
-    k_p = _pad_to(_pad_to(k_pad, 1, 8), 0, q_blk)
-    km_p = _pad_to(_pad_to(km_pad, 1, 8), 0, q_blk)
-    u_p = _pad_to(_pad_to(_pad_to(u, 1, 8), 2, docs_blk), 0, q_blk)
-    cols_p = _pad_to(cols, 0, docs_blk, value=k_pad.shape[-1] - 1)
-    vals_p = _pad_to(vals, 0, docs_blk)
-    wmd = _sddmm_spmm.sddmm_spmm_type2_batch(
-        k_p, km_p, u_p, cols_p, vals_p,
-        docs_blk=docs_blk, q_blk=q_blk, interpret=_interpret())
-    return wmd[:q, :n]
-
-
 def rwmd_bound_batch(m_pad: jax.Array, cols: jax.Array, vals: jax.Array, *,
                      docs_blk: int = 8,
                      q_blk: int | None = None) -> jax.Array:

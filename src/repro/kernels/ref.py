@@ -44,22 +44,6 @@ def _sampled_inverse_product(k_pad, u, cols, vals):
     return jnp.where(c != 0.0, c / jnp.maximum(w, TINY), 0.0)
 
 
-def sddmm_spmm_type1_batch(k_pad: jax.Array, r_sel: jax.Array, u: jax.Array,
-                           cols: jax.Array, vals: jax.Array) -> jax.Array:
-    """Batched oracle: the single-query oracle vmapped over the Q axis --
-    deliberately blind to the shared-gather structure of the real paths."""
-    return jax.vmap(
-        lambda k, r, uu: sddmm_spmm_type1(k, r, uu, cols, vals)
-    )(k_pad, r_sel, u)
-
-
-def sddmm_spmm_type2_batch(k_pad: jax.Array, km_pad: jax.Array, u: jax.Array,
-                           cols: jax.Array, vals: jax.Array) -> jax.Array:
-    return jax.vmap(
-        lambda k, km, uu: sddmm_spmm_type2(k, km, uu, cols, vals)
-    )(k_pad, km_pad, u)
-
-
 def rwmd_bound_batch(m_pad: jax.Array, cols: jax.Array,
                      vals: jax.Array) -> jax.Array:
     """Oracle for the doc-side RWMD min-SDDMM (core.rwmd / kernels.rwmd):

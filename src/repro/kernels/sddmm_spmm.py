@@ -29,33 +29,6 @@ A production Mosaic build would stage the cols tile through scalar prefetch
 (PrefetchScalarGridSpec) and issue the K-column loads as async copies; the
 dynamic-slice form below expresses the same dataflow and validates bit-for-bit
 in interpret mode (this container is CPU-only).
-
-Batched kernel & cache blocking
--------------------------------
-`_type1_batch_kernel` / `_type2_batch_kernel` extend the fusion along the Q
-(concurrent-query) axis. The ELL structure (cols, vals) is a property of the
-corpus, identical for every query, so the irregular work of a slot -- locating
-and loading the K column at ``cols[j, s]`` -- is done ONCE per (doc tile,
-Q-stripe) and the loaded ``(q_blk, v_r)`` column stripe feeds all q_blk
-queries' SDDMM dots *and* SpMM accumulations:
-
-  grid = (Q/q_blk, N/docs_blk)        # Q stripe outer: the multi-MB K
-                                      # stripe block is revisited, not
-                                      # re-fetched, across inner doc tiles
-  for j in docs_blk:                  # docs of this tile
-    for s in nnz_max:                 # slots of doc j
-      kcols = K[:, :, cols[j,s]]      # (q_blk, v_r) -- ONE gather, all queries
-      w[q]  = <kcols[q], u[q,:,j]>    # q_blk SDDMM dots
-      acc  += kcols * (vals[j,s]/w)[:, None]   # q_blk SpMM accumulations
-
-VMEM working set per grid step (f32): the K stripe dominates at
-``q_blk * v_r * (Vloc+1) * 4B`` -- e.g. q_blk=8, v_r=32, Vloc=8192 is 8 MB,
-which is why Q is striped instead of resident wholesale; u/x tiles add
-``2 * q_blk * v_r * docs_blk * 4B`` (KBs) and cols/vals
-``2 * docs_blk * nnz_max * 4B``. Shrink ``q_blk`` (more grid steps, same
-total traffic) when v_r * Vloc grows; shrink ``docs_blk`` only to bound the
-x tile. The jnp mirror of the same idea is `core.sparse_sinkhorn`'s
-``docs_chunk`` scan (its "Batched engine & cache blocking" section).
 """
 from __future__ import annotations
 
@@ -173,124 +146,3 @@ def sddmm_spmm_type2(k_pad: jax.Array, km_pad: jax.Array, u: jax.Array,
         interpret=interpret,
     )(k_pad, km_pad, u, cols, vals)
     return out[0]
-
-
-# ---------------------------------------------------------------------------
-# Batched (multi-query) kernels -- see "Batched kernel & cache blocking" above
-# ---------------------------------------------------------------------------
-
-def _type1_batch_kernel(k_ref, r_ref, u_ref, cols_ref, vals_ref, x_ref):
-    """One (doc tile, Q stripe): the per-slot K-column gather serves all
-    q_blk queries' SDDMM dots and SpMM accumulations."""
-    q_blk, v_r = u_ref.shape[0], u_ref.shape[1]
-    docs_blk, nnz_max = cols_ref.shape
-    dtype = x_ref.dtype
-
-    def doc_body(j, _):
-        u_j = u_ref[:, :, j]                                 # (q_blk, v_r)
-
-        def slot_body(s, acc):
-            col = cols_ref[j, s]
-            kcols = k_ref[:, :, col]                         # ONE gather
-            w = jnp.sum(kcols * u_j, axis=1)                 # q_blk SDDMM dots
-            val = vals_ref[j, s]
-            v = jnp.where(val != 0.0,
-                          val / jnp.maximum(w, TINY), 0.0)   # (q_blk,)
-            return acc + kcols * v[:, None]                  # q_blk SpMM accs
-
-        acc = jax.lax.fori_loop(
-            0, nnz_max, slot_body, jnp.zeros((q_blk, v_r), dtype))
-        x_ref[:, :, j] = acc / r_ref[:, :, 0]
-        return 0
-
-    jax.lax.fori_loop(0, docs_blk, doc_body, 0)
-
-
-def _type2_batch_kernel(k_ref, km_ref, u_ref, cols_ref, vals_ref, wmd_ref):
-    """Batched final distance: shared gather of the K and K.*M column
-    stripes, per-query reduction in-register."""
-    q_blk, v_r = u_ref.shape[0], u_ref.shape[1]
-    docs_blk, nnz_max = cols_ref.shape
-    dtype = wmd_ref.dtype
-
-    def doc_body(j, _):
-        u_j = u_ref[:, :, j]
-
-        def slot_body(s, acc):
-            col = cols_ref[j, s]
-            kcols = k_ref[:, :, col]                         # shared gather
-            kmcols = km_ref[:, :, col]
-            w = jnp.sum(kcols * u_j, axis=1)
-            val = vals_ref[j, s]
-            v = jnp.where(val != 0.0,
-                          val / jnp.maximum(w, TINY), 0.0)
-            return acc + kmcols * v[:, None]
-
-        acc = jax.lax.fori_loop(
-            0, nnz_max, slot_body, jnp.zeros((q_blk, v_r), dtype))
-        wmd_ref[:, 0, j] = jnp.sum(u_j * acc, axis=1)
-        return 0
-
-    jax.lax.fori_loop(0, docs_blk, doc_body, 0)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("docs_blk", "q_blk", "interpret"))
-def sddmm_spmm_type1_batch(k_pad: jax.Array, r_sel: jax.Array, u: jax.Array,
-                           cols: jax.Array, vals: jax.Array, *,
-                           docs_blk: int = 8, q_blk: int = 8,
-                           interpret: bool = False) -> jax.Array:
-    """Batched fused iteration body. Shapes: k_pad (Q, v_r, Vloc+1),
-    r_sel (Q, v_r), u (Q, v_r, N), cols/vals (N, nnz_max) with
-    N % docs_blk == 0 and Q % q_blk == 0. Returns x (Q, v_r, N)."""
-    q, v_r, n = u.shape
-    _, nnz_max = cols.shape
-    # Q stripe OUTER, doc tile inner: the multi-MB K stripe's block index is
-    # constant across all inner doc steps (Pallas skips the re-fetch), so K
-    # is copied into VMEM once per stripe while only the KB-scale cols/vals/u
-    # tiles re-stream -- the dominant-operand-resident grid order.
-    grid = (q // q_blk, n // docs_blk)
-    return pl.pallas_call(
-        _type1_batch_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((q_blk,) + k_pad.shape[1:], lambda qi, i: (qi, 0, 0)),
-            pl.BlockSpec((q_blk, v_r, 1), lambda qi, i: (qi, 0, 0)),
-            pl.BlockSpec((q_blk, v_r, docs_blk), lambda qi, i: (qi, 0, i)),
-            pl.BlockSpec((docs_blk, nnz_max), lambda qi, i: (i, 0)),
-            pl.BlockSpec((docs_blk, nnz_max), lambda qi, i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((q_blk, v_r, docs_blk),
-                               lambda qi, i: (qi, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((q, v_r, n), u.dtype),
-        interpret=interpret,
-    )(k_pad, r_sel[:, :, None], u, cols, vals)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("docs_blk", "q_blk", "interpret"))
-def sddmm_spmm_type2_batch(k_pad: jax.Array, km_pad: jax.Array, u: jax.Array,
-                           cols: jax.Array, vals: jax.Array, *,
-                           docs_blk: int = 8, q_blk: int = 8,
-                           interpret: bool = False) -> jax.Array:
-    """Batched fused final distance. Returns wmd (Q, N)."""
-    q, v_r, n = u.shape
-    _, nnz_max = cols.shape
-    grid = (q // q_blk, n // docs_blk)       # K/K.*M stripes stay resident
-    out = pl.pallas_call(
-        _type2_batch_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((q_blk,) + k_pad.shape[1:], lambda qi, i: (qi, 0, 0)),
-            pl.BlockSpec((q_blk,) + km_pad.shape[1:],
-                         lambda qi, i: (qi, 0, 0)),
-            pl.BlockSpec((q_blk, v_r, docs_blk), lambda qi, i: (qi, 0, i)),
-            pl.BlockSpec((docs_blk, nnz_max), lambda qi, i: (i, 0)),
-            pl.BlockSpec((docs_blk, nnz_max), lambda qi, i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((q_blk, 1, docs_blk),
-                               lambda qi, i: (qi, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((q, 1, n), u.dtype),
-        interpret=interpret,
-    )(k_pad, km_pad, u, cols, vals)
-    return out[:, 0]

@@ -122,7 +122,8 @@ def lower_wmd(shape: str, mesh):
     K-replicated layout (zero in-loop collectives) + length-bucketed ELL
     (nnz_max 48 instead of 128+rebucket padding).
     """
-    from repro.core.distributed import build_wmd_fn, build_wmd_fn_docsharded
+    from repro.core.distributed import (build_wmd_batch_fn,
+                                        build_wmd_fn_docsharded)
     if shape.endswith("_opt"):
         cfg = wmd_cfg.config(shape[:-4])
         doc_par = 1
@@ -157,14 +158,15 @@ def lower_wmd(shape: str, mesh):
     # time does the same for real data)
     num_docs = -(-cfg.num_docs // doc_par) * doc_par
     nnz_loc = max(cfg.nnz_max // model_par * 2, 16)  # rebucket headroom
-    fn = build_wmd_fn(mesh, lamb=cfg.lamb, max_iter=cfg.max_iter,
-                      doc_axes=doc_axes)
+    # the served solve at Q = 1
+    fn = build_wmd_batch_fn(mesh, lamb=cfg.lamb, max_iter=cfg.max_iter,
+                            doc_axes=doc_axes)
     sd = jax.ShapeDtypeStruct
     ns = lambda spec: NamedSharding(mesh, spec)
     args = (
-        sd((cfg.v_r, cfg.embed_dim), jnp.float32, sharding=ns(P())),
-        sd((cfg.v_r,), jnp.float32, sharding=ns(P())),
-        sd((cfg.v_r,), jnp.float32, sharding=ns(P())),
+        sd((1, cfg.v_r, cfg.embed_dim), jnp.float32, sharding=ns(P())),
+        sd((1, cfg.v_r), jnp.float32, sharding=ns(P())),
+        sd((1, cfg.v_r), jnp.float32, sharding=ns(P())),
         sd((cfg.vocab_size, cfg.embed_dim), jnp.float32,
            sharding=ns(P("model", None))),
         sd((model_par, num_docs, nnz_loc), jnp.int32,

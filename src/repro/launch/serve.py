@@ -46,10 +46,6 @@ def main():
     ap.add_argument("--batch-queries", action="store_true",
                     help="sinkhorn-wmd: serve all queries in one batched "
                          "(Q, v_r, N) solve instead of a per-query loop")
-    ap.add_argument("--impl", default="fused",
-                    choices=("fused", "unfused", "kernel"),
-                    help="sinkhorn-wmd: contraction path for the batched "
-                         "engine (kernel = Pallas, interpret on CPU)")
     ap.add_argument("--wmd-preset", default="paper_5k",
                     choices=("paper_5k", "news20"),
                     help="sinkhorn-wmd: the deployment to serve "
@@ -91,7 +87,7 @@ def main():
                     help="serving loop: total queries to serve")
     ap.add_argument("--resilience", action="store_true",
                     help="serving loop: route dispatches through the "
-                         "resilience layer (circuit-breaker impl ladder, "
+                         "resilience layer (circuit-breaker rung ladder, "
                          "bounded retry, degraded bound-only fallback) and "
                          "run the serving watchdog (dispatcher liveness + "
                          "straggler strikes -> breaker trips)")
@@ -208,11 +204,10 @@ def main():
                 print(f"[serve-wmd] live corpus recovered: "
                       f"{live.num_live} docs, gen {live.gen} at {live_dir}")
             svc = WMDService.from_live(mesh, cfg, vecs=data.vecs, live=live,
-                                       impl=args.impl, tol=args.tol)
+                                       tol=args.tol)
         else:
             svc = WMDService(mesh=mesh, cfg=cfg, vecs=data.vecs,
-                             ell=data.ell, impl=args.impl,
-                             docs_chunk=args.docs_chunk,
+                             ell=data.ell, docs_chunk=args.docs_chunk,
                              tol=args.tol)
         if args.offline:
             _serve_wmd_offline(svc, args)
@@ -340,8 +335,7 @@ def _serve_wmd_offline(svc, args):
         _warmup_wmd(svc, args)
     try:
         res = run_offline(svc, qs, k=args.top_k or None,
-                          max_batch=args.max_batch, rerank=args.rerank,
-                          impl=args.impl)
+                          max_batch=args.max_batch, rerank=args.rerank)
         msg = (f"[serve-wmd] offline {res.mode}: {res.n} queries in "
                f"{res.batches} batches of <= {res.max_batch}, "
                f"{res.wall_s:.2f}s ({res.throughput_qps:.1f} q/s)")

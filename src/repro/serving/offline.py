@@ -127,7 +127,7 @@ class OfflineResult:
 
 def run_offline(svc, queries: Sequence[np.ndarray], *,
                 k: int | None = None, max_batch: int = 16,
-                rerank: str = "union", impl: str | None = None,
+                rerank: str = "union",
                 use_cache: bool | None = None) -> OfflineResult:
     """Score every query at maximum batch occupancy.
 
@@ -144,8 +144,6 @@ def run_offline(svc, queries: Sequence[np.ndarray], *,
     qs = list(queries)
     bucket = _next_pow2(max(int(max_batch), 1))
     kw = {}
-    if impl is not None:
-        kw["impl"] = impl
     if use_cache is not None:
         kw["use_cache"] = use_cache
     rows, idxs, dists = [], [], []

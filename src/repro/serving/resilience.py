@@ -1,15 +1,15 @@
-"""Serving resilience: circuit-breaker impl demotion, bounded retry, and
-brownout degradation in front of the WMD engine.
+"""Serving resilience: circuit breakers, bounded retry, and brownout
+degradation in front of the WMD engine.
 
 The coalescer (serving.coalescer) turns client streams into engine
 dispatches; this module decides *which* engine those dispatches hit when
 things go wrong, without ever blocking the serving loop:
 
   EngineGuard     -- the dispatch wrapper. Every batch walks an ordered
-                     ladder of rungs (impl fallbacks: the service default,
-                     then cheaper contraction paths; pruned top-k falls
-                     back to the exhaustive scan route), each rung behind
-                     its own `CircuitBreaker`. Failures retry with seeded
+                     ladder of rungs, each behind its own
+                     `CircuitBreaker`: a plain query has one rung, the
+                     engine; pruned top-k has two, the pruned route, then
+                     the exhaustive scan route. Failures retry with seeded
                      exponential backoff + jitter (`ResiliencePolicy`),
                      trip the rung's breaker after a failure streak, and
                      demote to the next rung; when every exact rung is
@@ -32,9 +32,9 @@ things go wrong, without ever blocking the serving loop:
 Design rules, each load-bearing for the chaos suite's contracts
 (tests/test_resilience.py):
 
-* Rung 0 dispatches with ``impl=None`` -- byte-for-byte the call the
-  coalescer makes without a guard -- so fault-free dispatches stay
-  *bitwise identical* to the unguarded baseline.
+* Rung 0 is byte-for-byte the call the coalescer makes without a guard,
+  so fault-free dispatches stay *bitwise identical* to the unguarded
+  baseline.
 * `DegradedResult` is a wrapper, never a mutation: normal responses remain
   raw arrays, so the success path's bitwise contract is untouched and
   ``isinstance(x, DegradedResult)`` is the complete client-side detection
@@ -67,22 +67,13 @@ import numpy as np
 from repro.core import guards as _guards
 from repro.obs.trace import NULL_TRACER
 
-# the full contraction-path ladder, fastest-and-twitchiest first; a
-# service's ladder starts at its own impl and demotes rightward
-_IMPL_ORDER = ("kernel", "fused", "unfused")
-
 
 @dataclasses.dataclass(frozen=True)
 class ResiliencePolicy:
     """Knobs of the resilience layer (all times in seconds).
 
-    ``impl_ladder``: explicit demotion ladder; () derives it from the
-    service impl (e.g. "kernel" -> (None, "fused", "unfused") -- None is
-    "the service default", kept first so fault-free dispatches are the
-    exact unguarded call). ``brownout_queue_hi`` / ``brownout_miss_hi``
-    of None disable that brownout signal; both None disables brownout
-    entirely."""
-    impl_ladder: tuple = ()
+    ``brownout_queue_hi`` / ``brownout_miss_hi`` of None disable that
+    brownout signal; both None disables brownout entirely."""
     breaker_failures: int = 3          # failure streak that opens a rung
     breaker_cooldown_s: float = 5.0    # open -> half_open delay
     breaker_probes: int = 1            # half_open successes to close
@@ -228,16 +219,6 @@ class ResilienceStats:
     breaker_states: dict[str, str]   # "kind/rung" -> state
 
 
-def _default_ladder(svc_impl: str) -> tuple:
-    """(None, <impls strictly below svc_impl in the order>): None = the
-    service default (the exact unguarded dispatch), demotions follow."""
-    try:
-        start = _IMPL_ORDER.index(svc_impl)
-    except ValueError:
-        return (None,)
-    return (None,) + _IMPL_ORDER[start + 1:]
-
-
 class EngineGuard:
     """Resilient dispatch wrapper around a `WMDService`-shaped engine.
 
@@ -288,17 +269,13 @@ class EngineGuard:
             }
         self._rng = np.random.default_rng(self.policy.seed)
         self._lock = threading.Lock()
-        ladder = tuple(self.policy.impl_ladder) or _default_ladder(
-            getattr(svc, "impl", "fused"))
-        # rung tables: ("impl", x) dispatches query_batch(impl=x);
-        # ("pruned", x) dispatches the two-tier top-k with impl x;
-        # ("scan", None) the exhaustive one-program top-k route -- a
-        # genuinely different code path for when the prune machinery
+        # rung tables: "engine" dispatches query_batch; "pruned" the
+        # two-tier top-k; "scan" the exhaustive one-program top-k route --
+        # a genuinely different code path for when the prune machinery
         # itself is what's failing
-        self._rungs: dict[str, list[tuple[str, object]]] = {
-            "plain": [("impl", impl) for impl in ladder],
-            "top_k": [("pruned", impl) for impl in ladder]
-                     + [("scan", None)],
+        self._rungs: dict[str, list[str]] = {
+            "plain": ["engine"],
+            "top_k": ["pruned", "scan"],
         }
         def mk(kind: str, i: int) -> CircuitBreaker:
             return CircuitBreaker(
@@ -363,17 +340,11 @@ class EngineGuard:
 
     # -- dispatch ---------------------------------------------------------
 
-    def _call(self, kind: str, rung: tuple[str, object],
-              payloads: Sequence[np.ndarray], k: int | None):
-        mode, impl = rung
-        if mode == "impl":
-            if impl is None:
-                return self.svc.query_batch(payloads)
-            return self.svc.query_batch(payloads, impl=impl)
-        if mode == "pruned":
-            kw = {} if impl is None else {"impl": impl}
-            return self.svc.top_k_batch(payloads, k, prune=True, **kw)
-        return self.svc.top_k_batch(payloads, k, prune=False)
+    def _call(self, rung: str, payloads: Sequence[np.ndarray],
+              k: int | None):
+        if rung == "engine":
+            return self.svc.query_batch(payloads)
+        return self.svc.top_k_batch(payloads, k, prune=rung == "pruned")
 
     def _post_check(self, kind: str, res) -> None:
         """Re-verify the result at the guard boundary: the service's own
@@ -441,7 +412,7 @@ class EngineGuard:
                     if not br.allow():
                         break
                 try:
-                    res = self._call(kind, rung, payloads, k)
+                    res = self._call(rung, payloads, k)
                     self._post_check(kind, res)
                 except _guards.InvalidQueryError:
                     raise     # caller bug: deterministic, never retried

@@ -6,8 +6,7 @@ Why a registry
 --------------
 The engine compiles one XLA program per *dispatch shape*: the pow2 Q
 admission bucket x the request kind (plain distances / pruned top-k) x k x
-the engine knobs baked into the jitted fns (impl, docs_chunk, tol,
-prune_chunk). A first-hit compile costs 100-1000x a warm solve (PR 5
+the engine knobs baked into the jitted fns (docs_chunk, tol, prune_chunk). A first-hit compile costs 100-1000x a warm solve (PR 5
 measured serve-loop p50 dropping 335 -> 58 ms from warming one program), so
 a latency-mode service must never meet a shape cold. The ad-hoc warmers
 this module replaces (`QueryCoalescer.warm` / `warm_top_k`, now shims over
@@ -224,12 +223,10 @@ class ProgramShape:
     ``kind`` is the request kind the coalescer cuts batches by ("plain"
     distance rows, "top_k" = pruned per-query rerank, "top_k_union" = the
     offline bulk mode's (Q, chunk) union rerank); ``q_bucket`` the pow2
-    admission bucket; ``k`` the retrieval size (None for plain);
-    ``impl`` the contraction path baked into the solver fns."""
+    admission bucket; ``k`` the retrieval size (None for plain)."""
     kind: str
     q_bucket: int
     k: int | None = None
-    impl: str = "fused"
 
     def __post_init__(self):
         if self.kind not in _KINDS:
@@ -265,16 +262,15 @@ class ShapeRegistry:
     @classmethod
     def from_service(cls, svc, *, max_batch: int = 16,
                      ks: Sequence[int] = (),
-                     kinds: Sequence[str] | None = None,
-                     impl: str | None = None) -> "ShapeRegistry":
+                     kinds: Sequence[str] | None = None
+                     ) -> "ShapeRegistry":
         """Enumerate the envelope: every pow2 Q bucket up to ``max_batch``
         x every request kind x every k the deployment serves.
 
         ``kinds`` defaults to "plain" plus "top_k" when ``ks`` is
         non-empty ("top_k_union" -- the offline mode's rerank shape -- must
         be requested explicitly: it is never dispatched by the online
-        coalescer). ``impl`` defaults to the service's configured impl, so
-        the registry follows the config instead of restating it."""
+        coalescer)."""
         if kinds is None:
             kinds = ("plain",) + (("top_k",) if ks else ())
         for kind in kinds:
@@ -282,7 +278,6 @@ class ShapeRegistry:
                 raise ValueError(f"unknown kind {kind!r}")
         if any(kind != "plain" for kind in kinds) and not ks:
             raise ValueError("top_k kinds need at least one k in ks")
-        impl = svc.impl if impl is None else impl
         buckets = []
         b = 1
         while b <= _next_pow2(max_batch):
@@ -292,9 +287,9 @@ class ShapeRegistry:
         for kind in kinds:
             for b in buckets:
                 if kind == "plain":
-                    shapes.append(ProgramShape(kind, b, impl=impl))
+                    shapes.append(ProgramShape(kind, b))
                 else:
-                    shapes.extend(ProgramShape(kind, b, k=int(k), impl=impl)
+                    shapes.extend(ProgramShape(kind, b, k=int(k))
                                   for k in ks)
         return cls(shapes)
 
@@ -437,7 +432,7 @@ def warm(svc, registry: ShapeRegistry, *,
 
     Dispatches go through the *public* entry points (`query_batch` /
     `top_k_batch`), so whatever the admission policy routes a bucket to --
-    the sequential singleton path, the stripes engine, the pruned rerank --
+    the fused single-program engine, the stripes engine, the pruned rerank --
     is exactly what gets compiled, including the K-cache's fixed-shape
     row-compute/scatter/gather programs on the very first dispatch. Shapes
     run smallest-bucket first so per-shape compile attribution is sharp
@@ -477,16 +472,16 @@ def warm(svc, registry: ShapeRegistry, *,
             t0 = time.perf_counter()
             with measure_compiles() as counter:
                 if shape.kind == "plain":
-                    svc.query_batch(batch, impl=shape.impl)
+                    svc.query_batch(batch)
                 else:
                     rerank = "union" if shape.kind == "top_k_union" \
                         else "per_query"
                     svc.top_k_batch(batch, shape.k, prune=True,
-                                    impl=shape.impl, rerank=rerank)
+                                    rerank=rerank)
                     for sweep in _bound_chunk_payloads(
                             svc.cfg, shape.q_bucket, rows_bucket, seed=seed):
                         svc.top_k_batch(sweep, shape.k, prune=True,
-                                        impl=shape.impl, rerank=rerank)
+                                        rerank=rerank)
             shapes[shape.label] = ShapeWarmup(
                 shape=shape, wall_s=time.perf_counter() - t0,
                 compiles=counter.compiles, compile_s=counter.compile_s,

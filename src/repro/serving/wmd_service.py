@@ -6,8 +6,9 @@ engine with one psum per iteration.
 
 Service API
 -----------
-  query(r)                  -- one (V,) histogram -> (N,) distances.
-  query_batch(rs, impl=...) -- Q histograms -> (Q, N) batched:
+  query(r)                  -- one (V,) histogram -> (N,) distances:
+      ``query_batch([r])[0]``, the same engine at Q = 1.
+  query_batch(rs)           -- Q histograms -> (Q, N) batched:
       queries are padded to the service's v_r bucket (exact mask-based
       padding, `core.distributed.pad_query_batch`) and admitted in
       power-of-two Q buckets (bounding retrace count). With the cross-query
@@ -23,21 +24,15 @@ Service API
       way the (Q, v_r, N) solve shares a single ELL gather and a single
       psum per Sinkhorn iteration across all Q queries. Slots added by
       Q-bucketing carry an all-zero row mask, so they cost flops but
-      contribute nothing and are sliced off before returning.
-      ``impl`` ("fused" | "unfused" | "kernel") overrides the service
-      default per call (built fns are cached per impl).
+      contribute nothing and are sliced off before returning. Every query
+      count, Q = 1 included, runs this one solve.
       ``use_cache`` routes explicitly: False = the transient
       (dedup + recompute-everything) stripes path, the cache-off baseline
       that is *bitwise identical* to the cached path; True = the stripes
       engine even on a cache-less service (how the bench phase-splits).
-      Admission policy: with the cache disabled, Q = 1 routes to the
-      sequential path -- the batched engine's (Q, v_r, N) padding/precompute
-      overhead makes a singleton *slower* than the per-query program
-      (speedup 0.96x at Q=1 in the BENCH_query_batch.json artifact). With
-      the cache enabled even singletons go through the batched stripes path
-      so they hit (and warm) the row store.
-  query_batch_sequential(rs) -- the per-query dispatch loop, kept as the
-      correctness oracle and the baseline for bench_query_batch.py.
+  query_batch_sequential(rs) -- a loop of Q = 1 dispatches, kept as the
+      oracle of batch composition and the baseline for
+      bench_query_batch.py.
   top_k(r, k) / top_k_batch(rs, k) -- nearest-k doc ids + distances
       (argpartition + a tie-deterministic local sort: O(N + k log k), not a
       full argsort; ties are broken by doc id so every route selects the
@@ -81,7 +76,6 @@ Service API
       `invalidate_embedding_rows` is the scoped hook for vector updates.
 
 Perf knobs (constructor fields):
-  impl           -- default contraction path for query_batch.
   docs_chunk     -- sweep the batched solve over doc chunks of this size
                     (0 = unchunked). None (the default) plans it per Q
                     bucket from the device's memory
@@ -150,10 +144,9 @@ from repro.core import guards as _guards
 from repro.core import rwmd as rwmd_core
 from repro.core.kcache import KCache, MCache
 from repro.core.distributed import (build_wmd_batch_fn,
-                                    build_wmd_batch_fn_stripes, build_wmd_fn,
-                                    pad_query, pad_query_batch,
-                                    plan_docs_chunk, shard_wmd_inputs,
-                                    solve_bytes_per_doc)
+                                    build_wmd_batch_fn_stripes,
+                                    pad_query_batch, plan_docs_chunk,
+                                    shard_wmd_inputs, solve_bytes_per_doc)
 from repro.obs.trace import span
 # one copy of the pow2 bucket-rounding rule for the whole serving layer:
 # the coalescer's admission buckets must match the service's Q padding
@@ -167,7 +160,8 @@ def _serialized(fn):
     slot map and donates its device ring buffers), so concurrent callers --
     several `async_service` dispatcher threads, or `warm()` on a client
     thread while a dispatcher is live -- must take turns. Reentrant because
-    query_batch routes singletons through query."""
+    some entry points call others (the live top-k fallback calls
+    query_batch)."""
     @functools.wraps(fn)
     def wrapper(self, *args, **kwargs):
         with self._engine_lock:
@@ -198,7 +192,6 @@ class WMDService:
     cfg: wmd_cfg.WMDConfig
     vecs: np.ndarray
     ell: formats.EllDocs | None = None
-    impl: str = "fused"
     docs_chunk: int | None = None
     tol: float = 0.0
     cache_capacity: int = 0
@@ -238,7 +231,6 @@ class WMDService:
         self._rb = formats.rebucket_for_vocab_shards(self.ell, model_size)
         self._doc_axes = tuple(a for a in ("pod", "data")
                                if a in self.mesh.axis_names)
-        self._fns: dict[tuple, object] = {}
         self._batch_fns: dict[tuple, object] = {}
         self._stripe_fns: dict[tuple, object] = {}
         self._vecs_d, self._cols_d, self._vals_d = shard_wmd_inputs(
@@ -276,16 +268,6 @@ class WMDService:
                 f"{what} slots swept by full-distance solve dispatches",
                 labels={"kind": kind})
             for what in ("query", "ell") for kind in ("real", "pad")}
-        # the gathers of K at every ELL slot those dispatches make, as each
-        # solve program states them (its ``k_gathers``): "once" where it
-        # gathers K before its Sinkhorn loop, "per_iteration" where it
-        # gathers K again in every iteration
-        self._k_gathers = {
-            where: self.metrics.counter(
-                "wmd_k_gathers_total",
-                "gathers of K at every ELL slot by full-distance solve "
-                "dispatches", labels={"where": where})
-            for where in ("once", "per_iteration")}
         # the document chunks those dispatches sweep, and the plan of the
         # last one (`_count_dispatch`)
         self._solve_chunks = self.metrics.counter(
@@ -475,7 +457,6 @@ class WMDService:
             self._live_version = lc.version
 
     def _query_batch_live(self, rs: Sequence[np.ndarray], spans: list,
-                          impl: str | None = None,
                           use_cache: bool | None = None):
         """(Q, num_live) exact distances over the live corpus, columns in
         ascending doc-id order, and the route's stats (None for an empty
@@ -496,7 +477,7 @@ class WMDService:
             self.last_batch_stats = {}
             return np.zeros((q, n_live), np.float32), None
         k_s, km_s, info = self._cache_rows(sel_b, mask_b, use_cache, spans)
-        fn = self._stripe_fn(impl or self.impl, None)
+        fn = self._stripe_fn(None)
         r_d = jnp.asarray(r_b)
         out = np.empty((q, n_live), np.float32)
         segments = 0
@@ -507,7 +488,7 @@ class WMDService:
             if not pick.any():
                 continue
             with span("wmd.dispatch", spans):
-                self._count_dispatch(mask_b, nnz, fn)
+                self._count_dispatch(mask_b, nnz)
                 d_seg = fn(k_s, km_s, r_d, cols_d, vals_d)
             with span("wmd.fetch", spans):
                 d_seg = np.asarray(d_seg)[:q]
@@ -644,80 +625,43 @@ class WMDService:
         """M rows currently resident in the bound tiers' row cache."""
         return self._mcache.resident
 
-    def _single_fn(self):
-        """Per-query solver, keyed by lamb so a mutated cfg.lamb can't serve
-        a stale program (lamb is baked into the jitted fn -- the same reason
-        `_batch_fn` keys on it and the cache re-keys via `ensure_lamb`)."""
-        key = (self.cfg.lamb,)
-        fn = self._fns.get(key)
-        if fn is None:
-            fn = build_wmd_fn(self.mesh, lamb=self.cfg.lamb,
-                              max_iter=self.cfg.max_iter,
-                              doc_axes=self._doc_axes)
-            self._fns[key] = fn
-        return fn
-
-    def _batch_fn(self, impl: str, docs_chunk: int | None):
+    def _batch_fn(self, docs_chunk: int | None):
         """Single-program batched solver (precompute fused into the device
         program) -- the engine `query_batch` runs when the cross-query cache
         is disabled; the cache routes through `_stripe_fn` instead. tol and
         lamb are part of the key so mutating svc.tol / svc.cfg.lamb can't
         serve a stale solver."""
-        key = (impl, docs_chunk, self.tol, self.cfg.lamb)
+        key = (docs_chunk, self.tol, self.cfg.lamb)
         fn = self._batch_fns.get(key)
         if fn is None:
             fn = build_wmd_batch_fn(self.mesh, lamb=self.cfg.lamb,
                                     max_iter=self.cfg.max_iter,
-                                    doc_axes=self._doc_axes, impl=impl,
-                                    docs_chunk=docs_chunk,
-                                    tol=self.tol)
+                                    doc_axes=self._doc_axes,
+                                    docs_chunk=docs_chunk, tol=self.tol)
             self._batch_fns[key] = fn
         return fn
 
-    def _stripe_fn(self, impl: str, docs_chunk: int | None):
+    def _stripe_fn(self, docs_chunk: int | None):
         """Batched solver on cache-assembled stripes, built once per
-        (impl, docs_chunk, tol) -- same caching contract as `_batch_fn`."""
-        key = (impl, docs_chunk, self.tol)
+        (docs_chunk, tol) -- same caching contract as `_batch_fn`."""
+        key = (docs_chunk, self.tol)
         fn = self._stripe_fns.get(key)
         if fn is None:
             fn = build_wmd_batch_fn_stripes(
                 self.mesh, max_iter=self.cfg.max_iter,
-                doc_axes=self._doc_axes, impl=impl, docs_chunk=docs_chunk,
+                doc_axes=self._doc_axes, docs_chunk=docs_chunk,
                 tol=self.tol)
             self._stripe_fns[key] = fn
         return fn
 
-    @_serialized
     def query(self, r: np.ndarray) -> np.ndarray:
         """r: (V,) sparse query histogram -> (N,) distances (num_live
-        columns in ascending doc-id order on a live service)."""
-        if self.live is not None:
-            return self.query_batch([r])[0]
-        return self._solve_one(r, [])
-
-    def _solve_one(self, r: np.ndarray, spans: list) -> np.ndarray:
-        """One query through the per-query program, its stages recorded
-        into ``spans``."""
-        with span("wmd.prepare", spans):
-            self._validate_queries([r])
-            sel_idx, r_sel = select_query(r)
-            sel_p, r_p, mask = pad_query(sel_idx, r_sel, self.cfg.v_r)
-            vecs_sel = self.vecs[sel_p]
-        with span("wmd.dispatch", spans):
-            fn = self._single_fn()
-            self._count_dispatch(mask, self._ell_nnz, fn)
-            wmd = fn(jnp.asarray(vecs_sel), jnp.asarray(r_p),
-                     jnp.asarray(mask), self._vecs_d, self._cols_d,
-                     self._vals_d)
-        with span("wmd.fetch", spans):
-            wmd = np.asarray(wmd)
-        with span("wmd.check", spans):
-            self._check_result(wmd, what="query distances")
-        return wmd
+        columns in ascending doc-id order on a live service): the batched
+        engine at Q = 1."""
+        return self.query_batch([r])[0]
 
     @_serialized
     def query_batch(self, rs: Sequence[np.ndarray],
-                    impl: str | None = None,
                     docs_chunk=_UNSET,
                     use_cache: bool | None = None) -> np.ndarray:
         """Multiple queries -> (Q, N) via the batched (Q, v_r, N) engine.
@@ -726,15 +670,14 @@ class WMDService:
         the whole batch and computes only rows missing from the cross-query
         cache; cache-less services run the legacy fused-precompute program.
         The fused solve gathers K at the ELL slots once for the whole batch
-        (`core.sparse_sinkhorn.hoists_k_gather`) and runs one psum per
-        Sinkhorn iteration either way. Q is rounded up to a power of two
-        (retrace bound), with the filler slots masked to contribute exactly
-        zero. ``impl`` / ``docs_chunk`` override the service defaults for
+        and runs one psum per Sinkhorn iteration either way. Q is rounded up
+        to a power of two (retrace bound), with the filler slots masked to
+        contribute exactly zero. ``docs_chunk`` overrides the service's for
         this call (pass docs_chunk=0 for explicitly unchunked);
         ``use_cache`` overrides the engine routing (False = transient
         stripes baseline, bitwise identical to the cached path; True =
         stripes engine even with the cache disabled). Built fns are cached
-        per (impl, docs_chunk).
+        per docs_chunk.
 
         Live services route every call through the per-segment dispatch
         (`_query_batch_live`; docs_chunk is forced unchunked there) --
@@ -751,45 +694,24 @@ class WMDService:
         """
         spans: list = []
         with span("wmd.query_batch", spans, q=len(rs)):
-            out, stats = self._query_batch_routed(rs, spans, impl,
-                                                  docs_chunk, use_cache)
+            out, stats = self._query_batch_routed(rs, spans, docs_chunk,
+                                                  use_cache)
         if stats is not None:
             self._finish(stats, spans)
         return out
 
-    def _query_batch_routed(self, rs, spans: list, impl, docs_chunk,
-                            use_cache):
+    def _query_batch_routed(self, rs, spans: list, docs_chunk, use_cache):
         """`query_batch`'s routes: (distances, stats for `_finish`), the
         stats None when the call dispatched nothing."""
         if self.live is not None:
-            return self._query_batch_live(rs, spans, impl=impl,
-                                          use_cache=use_cache)
+            return self._query_batch_live(rs, spans, use_cache=use_cache)
         if len(rs) == 0:
             return np.zeros((0, self.ell.num_docs), np.float32), None
         # under an armed underflow gate every dispatch routes through the
         # stripes engine so the K*M pre-check (`core.guards.check_km_rows`)
         # sees the assembled rows; off at every shipped lambda, so the
-        # fast-path routing below is untouched in production
+        # routing below is untouched in production
         risk = self._underflow_risk()
-        if (len(rs) == 1 and impl is None and docs_chunk is _UNSET
-                and self.impl == "fused" and self.tol == 0.0
-                and self.cache_capacity == 0 and not risk):
-            # admission policy: a singleton is *slower* batched than
-            # sequential (0.96x in BENCH_query_batch.json -- the (Q, v_r, N)
-            # precompute/padding overhead has nothing to amortize), so route
-            # Q = 1 to the per-query program. Taken only when the sequential
-            # path implements the configured engine: an explicit per-call
-            # override, a non-fused service impl, or early-exit tol all
-            # bypass it (the sequential program is fused fixed-iteration),
-            # and so does an enabled cache (singletons should hit and warm
-            # the row store). A service-level docs_chunk does NOT bypass --
-            # chunking is result-identical and the sequential route is the
-            # faster singleton plan either way.
-            # no stripes phase split for this route, but the call must not
-            # vanish from attribution: report the solve time with an
-            # explicit phases_separable=False marker
-            out = np.stack([self._solve_one(r, spans) for r in rs])
-            return out, {"phases_separable": False, "route": "sequential"}
         q = len(rs)
         # cache disabled and no explicit routing request: the legacy
         # single-program engine (precompute fused into the solve) is the
@@ -805,9 +727,9 @@ class WMDService:
             if legacy:
                 vecs_b = self.vecs[sel_b]
         if legacy:
-            fn = self._batch_fn(impl or self.impl, dc)
+            fn = self._batch_fn(dc)
             with span("wmd.dispatch", spans):
-                self._count_dispatch(mask_b, self._ell_nnz, fn,
+                self._count_dispatch(mask_b, self._ell_nnz,
                                      chunk_docs=dc or self._n_loc)
                 wmd = fn(jnp.asarray(vecs_b), jnp.asarray(r_b),
                          jnp.asarray(mask_b), self._vecs_d, self._cols_d,
@@ -817,11 +739,11 @@ class WMDService:
             # instead of silently dropping the call from attribution
             stats = {"phases_separable": False, "route": "legacy_fused"}
         else:
-            fn = self._stripe_fn(impl or self.impl, dc)
+            fn = self._stripe_fn(dc)
             k_s, km_s, stats = self._cache_rows(sel_b, mask_b, use_cache,
                                                 spans)
             with span("wmd.dispatch", spans):
-                self._count_dispatch(mask_b, self._ell_nnz, fn,
+                self._count_dispatch(mask_b, self._ell_nnz,
                                      chunk_docs=dc or self._n_loc)
                 wmd = fn(k_s, km_s, jnp.asarray(r_b), self._cols_d,
                          self._vals_d)
@@ -863,20 +785,15 @@ class WMDService:
                 "wmd_span_seconds", "seconds in each stage of a service call",
                 labels={"span": n}).observe(t1 - t0)
 
-    def _count_dispatch(self, mask: np.ndarray, ell_nnz: tuple, fn, *,
+    def _count_dispatch(self, mask: np.ndarray, ell_nnz: tuple, *,
                         chunk_docs: int | None = None) -> None:
-        """Count one solve dispatch's swept slots, gathers of K and doc
-        chunks (skipped in warm-up): ``mask`` is its padded query mask,
-        ``ell_nnz`` the (real, all) slots of the ELL segment it gathers
-        over, ``fn`` the solve program, whose ``k_gathers`` and
-        ``k_gather_row_bytes`` (`core.distributed`) state where it gathers
-        K, how often, and how long a row each ELL slot fetches. (The
-        unfused baseline's second gather, of K/r, is the same gather in
-        these programs -- r is folded out of the iteration under shard_map
-        -- and XLA merges the two.) ``chunk_docs`` is the documents a chunk
-        of a batched solve over the service ELL (its local slice where
-        unchunked): such a dispatch also sets the plan gauges. Every other
-        dispatch sweeps its documents as one chunk."""
+        """Count one solve dispatch's swept slots and doc chunks (skipped
+        in warm-up): ``mask`` is its padded query mask, ``ell_nnz`` the
+        (real, all) slots of the ELL segment it gathers over.
+        ``chunk_docs`` is the documents a chunk of a batched solve over the
+        service ELL (its local slice where unchunked): such a dispatch also
+        sets the plan gauges. Every other dispatch sweeps its documents as
+        one chunk."""
         if self._warming:
             return
         real = int(np.count_nonzero(mask))
@@ -884,14 +801,6 @@ class WMDService:
                                       ("ell", ell_nnz)):
             self._slots[what, "real"].inc(n_real)
             self._slots[what, "pad"].inc(n_all - n_real)
-        where, n = fn.k_gathers
-        self._k_gathers[where].inc(n)
-        self.metrics.gauge(
-            "wmd_k_gather_row_bytes",
-            "contiguous bytes one ELL slot's K gather fetches in the last "
-            "full-distance solve dispatch").set(
-                fn.k_gather_row_bytes(mask.size // mask.shape[-1],
-                                      mask.shape[-1]))
         if chunk_docs is None:
             self._solve_chunks.inc(1)
             return
@@ -924,7 +833,8 @@ class WMDService:
             self._warm_local.on = prev
 
     def query_batch_sequential(self, rs: Sequence[np.ndarray]) -> np.ndarray:
-        """Per-query dispatch loop -- the oracle/baseline for query_batch."""
+        """A loop of Q = 1 dispatches -- the oracle/baseline for
+        query_batch."""
         return np.stack([self.query(r) for r in rs])
 
     def _padded_query_batch(self, rs: Sequence[np.ndarray]):
@@ -992,12 +902,12 @@ class WMDService:
 
         Default: `query_batch` (one device program for all Q x N solves)
         followed by the tie-deterministic selection; ``**kw`` forwards
-        impl / docs_chunk / use_cache. With ``prune=True`` the two-tier
+        docs_chunk / use_cache. With ``prune=True`` the two-tier
         engine runs instead -- RWMD prefilter over all N docs, exact
         Sinkhorn rerank only on the candidate prefix -- and returns the
         bitwise-identical set as `top_k_scan_batch` while skipping the
         pruned docs' solves (stats in ``last_prune_stats``). ``**kw`` then
-        forwards impl / use_cache / prune_chunk / prune_margin.
+        forwards use_cache / prune_chunk / prune_margin.
 
         ``rerank`` picks the pruned rerank strategy: ``"per_query"`` (the
         online default -- each query visits its own candidate blocks with
@@ -1052,7 +962,6 @@ class WMDService:
 
     @_serialized
     def _top_k_live_fallback(self, rs: Sequence[np.ndarray], k: int, *,
-                             impl: str | None = None,
                              use_cache: bool | None = None,
                              prune_chunk: int | None = None,
                              prune_margin: float | None = None
@@ -1066,7 +975,7 @@ class WMDService:
         shared block schedule does not yet span segments) routes here."""
         self._prune_fallbacks.inc()
         t0 = time.perf_counter()
-        d = self.query_batch(rs, impl=impl, use_cache=use_cache)
+        d = self.query_batch(rs, use_cache=use_cache)
         q, n = d.shape
         k_eff = min(k, n)
         idx = self._top_k(d, k_eff)
@@ -1083,7 +992,7 @@ class WMDService:
 
     @_serialized
     def _top_k_live_pruned(self, rs: Sequence[np.ndarray], k: int, *,
-                           exhaustive: bool, impl: str | None = None,
+                           exhaustive: bool,
                            use_cache: bool | None = None,
                            prune_chunk: int | None = None,
                            prune_margin: float | None = None
@@ -1123,7 +1032,7 @@ class WMDService:
         bounds = combined[:q]               # columns: base-segment rows
         t_bound = time.perf_counter() - t0
         self._kcache.ensure_lamb(self.cfg.lamb)   # lambda-invalidation
-        fn = self._stripe_fn(impl or self.impl, None)
+        fn = self._stripe_fn(None)
         bpos = np.nonzero(self._live_seg == 0)[0]   # live base positions
         dpos = np.nonzero(self._live_seg == 1)[0]   # live delta positions
         brow = self._live_row[bpos]                 # base-segment rows
@@ -1345,7 +1254,7 @@ class WMDService:
 
     @_serialized
     def _top_k_pruned(self, rs: Sequence[np.ndarray], k: int, *,
-                      exhaustive: bool, impl: str | None = None,
+                      exhaustive: bool,
                       use_cache: bool | None = None,
                       prune_chunk: int | None = None,
                       prune_margin: float | None = None
@@ -1380,7 +1289,7 @@ class WMDService:
         bounds = combined[:q]
         t_bound = time.perf_counter() - t0
         self._kcache.ensure_lamb(self.cfg.lamb)   # lambda-invalidation
-        fn = self._stripe_fn(impl or self.impl, None)  # chunk IS the block
+        fn = self._stripe_fn(None)  # chunk IS the block
         idx_out = np.empty((q, k_eff), np.int64)
         d_out = np.empty((q, k_eff), np.float32)
         solves = 0
@@ -1448,7 +1357,6 @@ class WMDService:
 
     @_serialized
     def _top_k_union(self, rs: Sequence[np.ndarray], k: int, *,
-                     impl: str | None = None,
                      use_cache: bool | None = None,
                      prune_chunk: int | None = None,
                      prune_margin: float | None = None
@@ -1496,7 +1404,7 @@ class WMDService:
         lb = combined[:q]                                     # (q, N)
         t_bound = time.perf_counter() - t0
         self._kcache.ensure_lamb(self.cfg.lamb)   # lambda-invalidation
-        fn = self._stripe_fn(impl or self.impl, None)
+        fn = self._stripe_fn(None)
         # ONE stripes assembly for the whole batch (vs per-query on the
         # online path) -- rows are bit-reproducible either way
         k_s, km_s, info = self._kcache.stripes_for_batch(sel_b, mask_b,

@@ -1,7 +1,9 @@
-"""Cache-blocked batched engine: batched Pallas kernels == batched jnp
-fused (mixed-size padded queries, pad rows/slots inert), doc-chunked
-iteration bitwise at the op level, early-exit convergence == fixed budget,
-and the distributed convergence vote == single-host masking."""
+"""The batched engine: every batched solve (stripes, mesh, converged;
+chunked or not; fixed budget or early exit) against the dense reference,
+pad rows/slots and filler queries inert, early-exit convergence == fixed
+budget, the distributed convergence vote == single-host masking, and
+every service route -- Q = 1 included -- dispatching the batched
+program."""
 import functools
 
 import jax
@@ -10,13 +12,15 @@ import numpy as np
 import pytest
 
 from repro.core import (ell_from_dense, pad_k, precompute_batch, select_query,
-                        sddmm_spmm_type1_batch, sddmm_spmm_type2_batch,
-                        sinkhorn_wmd_converged_batch, sinkhorn_wmd_sparse_batch)
+                        sinkhorn_wmd_converged_batch, sinkhorn_wmd_dense,
+                        sinkhorn_wmd_sparse_batch)
 from repro.core.distributed import pad_query_batch
-from repro.core.sparse_sinkhorn import safe_recip
-from repro.kernels import ops, ref
 
 LAMB, ITERS = 1.0, 12
+# a batched solve against the dense reference (`core.sinkhorn`), relative
+# to the largest distance: the two sum in different orders over the
+# Sinkhorn iterations. Early exit at tol 1e-3 stays inside it too.
+REL_ERR = 1e-4
 
 
 @pytest.fixture(scope="module")
@@ -40,14 +44,9 @@ def batch_problem():
         queries.append(r)
     sels, rsels = zip(*[select_query(r) for r in queries])
     sel_b, r_b, mask_b = pad_query_batch(sels, rsels, 16)
-    pre = precompute_batch(jnp.asarray(sel_b), jnp.asarray(r_b),
-                           jnp.asarray(vecs), LAMB,
-                           row_mask=jnp.asarray(mask_b))
     return {"vecs": vecs, "ell": ell, "sels": sels, "rsels": rsels,
-            "sel_b": sel_b, "r_b": r_b, "mask_b": mask_b, "pre": pre,
-            "k_pad": pad_k(pre.K), "km_pad": pad_k(pre.KM),
-            "cols": jnp.asarray(ell.cols), "vals": jnp.asarray(ell.vals),
-            "u": safe_recip(jnp.full((4, 16, n), 1.0 / 16, jnp.float32))}
+            "sel_b": sel_b, "r_b": r_b, "mask_b": mask_b,
+            "cols": jnp.asarray(ell.cols), "vals": jnp.asarray(ell.vals)}
 
 
 def _solver_args(p):
@@ -56,101 +55,31 @@ def _solver_args(p):
 
 
 # ---------------------------------------------------------------------------
-# Batched kernel vs batched jnp fused (the acceptance gate)
+# Pad rows, pad slots and filler queries
 # ---------------------------------------------------------------------------
 
-@pytest.mark.kernel
-def test_batched_kernel_type1_matches_jnp_fused(batch_problem):
-    """ops.sddmm_spmm_type1_batch (interpret) == jnp fused == naive oracle
-    on a mixed-size padded query bucket (pad rows present in K/r/u)."""
-    p = batch_problem
-    r_b = jnp.asarray(p["r_b"])
-    x_jnp = sddmm_spmm_type1_batch(p["k_pad"], r_b, p["u"],
-                                   p["cols"], p["vals"])
-    x_ref = ref.sddmm_spmm_type1_batch(p["k_pad"], r_b, p["u"],
-                                       p["cols"], p["vals"])
-    for q_blk in (None, 2):  # single stripe covering Q, and 2-query stripes
-        x_pal = ops.sddmm_spmm_type1_batch(p["k_pad"], r_b, p["u"],
-                                           p["cols"], p["vals"], q_blk=q_blk)
-        np.testing.assert_allclose(np.asarray(x_pal), np.asarray(x_jnp),
-                                   rtol=2e-4, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(x_pal), np.asarray(x_ref),
-                                   rtol=2e-4, atol=1e-6)
-
-
-@pytest.mark.kernel
-def test_batched_kernel_type2_matches_jnp_fused(batch_problem):
-    p = batch_problem
-    w_jnp = sddmm_spmm_type2_batch(p["k_pad"], p["km_pad"], p["u"],
-                                   p["cols"], p["vals"])
-    w_ref = ref.sddmm_spmm_type2_batch(p["k_pad"], p["km_pad"], p["u"],
-                                       p["cols"], p["vals"])
-    w_pal = ops.sddmm_spmm_type2_batch(p["k_pad"], p["km_pad"], p["u"],
-                                       p["cols"], p["vals"])
-    np.testing.assert_allclose(np.asarray(w_pal), np.asarray(w_jnp),
-                               rtol=2e-4, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(w_pal), np.asarray(w_ref),
-                               rtol=2e-4, atol=1e-6)
-
-
-@pytest.mark.kernel
 def test_batched_kernel_pad_rows_and_slots_inert(batch_problem):
-    """Pad-slot retargeting is bit-identical through the kernel (val == 0
-    gates the accumulation), and an all-pad filler stripe solves to exactly
-    zero through the full impl="kernel" batched solver."""
-    p = batch_problem
-    w_a = ops.sddmm_spmm_type2_batch(p["k_pad"], p["km_pad"], p["u"],
-                                     p["cols"], p["vals"])
-    cols_mut = jnp.where(p["vals"] == 0.0, 0, p["cols"])
-    w_b = ops.sddmm_spmm_type2_batch(p["k_pad"], p["km_pad"], p["u"],
-                                     cols_mut, p["vals"])
-    np.testing.assert_array_equal(np.asarray(w_a), np.asarray(w_b))
-    # all-pad filler query (the service's Q-bucket filler), kernel path
-    wmd = sinkhorn_wmd_sparse_batch(
-        jnp.zeros((1, 16), jnp.int32), jnp.ones((1, 16), jnp.float32),
-        p["cols"], p["vals"], jnp.asarray(p["vecs"]), LAMB, ITERS,
-        row_mask=jnp.zeros((1, 16), jnp.float32), impl="kernel")
-    np.testing.assert_array_equal(np.asarray(wmd), 0.0)
-
-
-@pytest.mark.kernel
-def test_batched_solver_kernel_impl_matches_fused(batch_problem):
-    """The full batched solver agrees across the impl table (the unified
-    fused|unfused|kernel API of the tentpole)."""
+    """Pad-slot retargeting is bit-identical through the fused batched
+    solver (val == 0 gates the contractions), and an all-pad filler stripe
+    solves to exactly zero."""
     p = batch_problem
     kw = dict(row_mask=jnp.asarray(p["mask_b"]))
     base = np.asarray(sinkhorn_wmd_sparse_batch(*_solver_args(p), **kw))
-    for impl in ("kernel", "unfused"):
-        got = np.asarray(sinkhorn_wmd_sparse_batch(*_solver_args(p), **kw,
-                                                   impl=impl))
-        err = np.abs(got - base).max() / np.abs(base).max()
-        assert err < 1e-4, (impl, err)
+    args = list(_solver_args(p))
+    args[2] = jnp.where(p["vals"] == 0.0, 0, p["cols"])
+    got = np.asarray(sinkhorn_wmd_sparse_batch(*args, **kw))
+    np.testing.assert_array_equal(got, base)
+    # all-pad filler query (the service's Q-bucket filler)
+    wmd = sinkhorn_wmd_sparse_batch(
+        jnp.zeros((1, 16), jnp.int32), jnp.ones((1, 16), jnp.float32),
+        p["cols"], p["vals"], jnp.asarray(p["vecs"]), LAMB, ITERS,
+        row_mask=jnp.zeros((1, 16), jnp.float32))
+    np.testing.assert_array_equal(np.asarray(wmd), 0.0)
 
 
 # ---------------------------------------------------------------------------
-# Doc-chunked (cache-blocked) iteration
+# Doc-chunked (cache-blocked) solve
 # ---------------------------------------------------------------------------
-
-def test_chunked_op_bitwise_including_nondividing(batch_problem):
-    """Chunked contraction == unchunked BITWISE at the op level (jitted),
-    for dividing and non-dividing docs_chunk values (N = 45)."""
-    p = batch_problem
-    r_b = jnp.asarray(p["r_b"])
-    t1 = jax.jit(functools.partial(sddmm_spmm_type1_batch),
-                 static_argnames="docs_chunk")
-    t2 = jax.jit(functools.partial(sddmm_spmm_type2_batch),
-                 static_argnames="docs_chunk")
-    x_base = np.asarray(t1(p["k_pad"], r_b, p["u"], p["cols"], p["vals"]))
-    w_base = np.asarray(t2(p["k_pad"], p["km_pad"], p["u"],
-                           p["cols"], p["vals"]))
-    for dc in (0, 8, 15, 16, 32, 45):      # 0 = unchunked alias, no crash
-        x_c = np.asarray(t1(p["k_pad"], r_b, p["u"], p["cols"], p["vals"],
-                            docs_chunk=dc))
-        np.testing.assert_array_equal(x_c, x_base, err_msg=f"type1 dc={dc}")
-        w_c = np.asarray(t2(p["k_pad"], p["km_pad"], p["u"], p["cols"],
-                            p["vals"], docs_chunk=dc))
-        np.testing.assert_array_equal(w_c, w_base, err_msg=f"type2 dc={dc}")
-
 
 def test_chunked_solver_matches_unchunked(batch_problem):
     """Full batched solver: chunked == unchunked to fp32 tolerance (whole-
@@ -166,7 +95,7 @@ def test_chunked_solver_matches_unchunked(batch_problem):
 
 
 # ---------------------------------------------------------------------------
-# K gathered once per solve (hoisted) vs once per iteration (in-loop)
+# Every batched solve against the dense reference
 # ---------------------------------------------------------------------------
 
 def _with_filler(p):
@@ -177,89 +106,77 @@ def _with_filler(p):
     return q_pad(p["sel_b"], 0), q_pad(p["r_b"], 1.0), q_pad(p["mask_b"], 0.0)
 
 
-def _stripes_solve(p, sel_b, r_b, mask_b, *, tol, docs_chunk, placement):
-    """`sparse_sinkhorn._solve_batch_stripes`, traced afresh (a patched
-    `solve_contractions` must not hit an earlier trace)."""
+def _stripes_solve(p, sel_b, r_b, mask_b, *, tol, docs_chunk):
+    """`sparse_sinkhorn._solve_batch_stripes` (its chunk loop unrolled)."""
     from repro.core import sparse_sinkhorn as ss
-    del placement                    # always "solve" here
     pre = precompute_batch(jnp.asarray(sel_b), jnp.asarray(r_b),
                            jnp.asarray(p["vecs"]), LAMB,
                            row_mask=jnp.asarray(mask_b))
     fn = jax.jit(functools.partial(ss._solve_batch_stripes, max_iter=ITERS,
-                                   impl="fused", docs_chunk=docs_chunk,
-                                   tol=tol))
-    args = (pad_k(pre.K), pad_k(pre.KM), pre.r, p["cols"], p["vals"])
-    return np.asarray(fn(*args)), fn.lower(*args).as_text()
+                                   docs_chunk=docs_chunk, tol=tol))
+    return np.asarray(fn(pad_k(pre.K), pad_k(pre.KM), pre.r, p["cols"],
+                         p["vals"]))
 
 
-def _mesh_solve(p, sel_b, r_b, mask_b, *, tol, docs_chunk, placement):
-    """`distributed.build_wmd_batch_fn` on a (1, 1) mesh."""
+def _mesh_solve(p, sel_b, r_b, mask_b, *, tol, docs_chunk):
+    """`distributed.build_wmd_batch_fn` on a (1, 1) mesh (its chunk loop
+    rolled)."""
     from repro.core import rebucket_for_vocab_shards
     from repro.core.distributed import build_wmd_batch_fn, shard_wmd_inputs
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
     rb = rebucket_for_vocab_shards(p["ell"], 1)
     fn = build_wmd_batch_fn(mesh, lamb=LAMB, max_iter=ITERS, tol=tol,
-                            docs_chunk=docs_chunk,
-                            chunk_placement=placement)
-    args = (jnp.asarray(p["vecs"][sel_b]), jnp.asarray(r_b),
-            jnp.asarray(mask_b),
-            *shard_wmd_inputs(mesh, p["vecs"], rb.cols, rb.vals))
-    return np.asarray(fn(*args)), fn.lower(*args).as_text()
+                            docs_chunk=docs_chunk)
+    return np.asarray(fn(jnp.asarray(p["vecs"][sel_b]), jnp.asarray(r_b),
+                         jnp.asarray(mask_b),
+                         *shard_wmd_inputs(mesh, p["vecs"], rb.cols,
+                                           rb.vals)))
 
 
-def _converged_solve(p, sel_b, r_b, mask_b, *, tol, docs_chunk, placement):
-    """`convergence.sinkhorn_wmd_converged_batch`, traced afresh (past
-    its own jit cache); its docs_chunk is per-op only."""
-    del placement                    # always "iteration" here
-    fn = jax.jit(lambda s, r, m: sinkhorn_wmd_converged_batch.__wrapped__(
-        s, r, p["cols"], p["vals"], jnp.asarray(p["vecs"]), LAMB, ITERS,
-        tol=tol, docs_chunk=docs_chunk, row_mask=m).wmd)
-    args = (jnp.asarray(sel_b), jnp.asarray(r_b), jnp.asarray(mask_b))
-    return np.asarray(fn(*args)), fn.lower(*args).as_text()
+def _converged_solve(p, sel_b, r_b, mask_b, *, tol, docs_chunk):
+    """`convergence.sinkhorn_wmd_converged_batch` (unchunked only)."""
+    assert docs_chunk is None
+    return np.asarray(sinkhorn_wmd_converged_batch(
+        jnp.asarray(sel_b), jnp.asarray(r_b), p["cols"], p["vals"],
+        jnp.asarray(p["vecs"]), LAMB, ITERS, tol=tol,
+        row_mask=jnp.asarray(mask_b)).wmd)
 
 
-# (core, docs_chunk, chunk placement): the stripes core chunks the whole
-# solve only, the converged core each op only
-_HOIST_CASES = [
-    pytest.param(solve, dc, placement, id=f"{core}-{cid}")
-    for core, solve in (("stripes", _stripes_solve), ("mesh", _mesh_solve),
-                        ("converged", _converged_solve))
-    for cid, dc, placement in (("unchunked", None, "solve"),
-                               ("solve_chunks", 16, "solve"),
-                               ("iteration_unchunked", None, "iteration"),
-                               ("iteration_chunks", 16, "iteration"))
-    if (core, placement) != ("stripes", "iteration")
-    and (core, placement) != ("converged", "solve")]
+def _dense_reference(p) -> np.ndarray:
+    """(Q, N) `core.sinkhorn.sinkhorn_wmd_dense` of the bucket's real
+    queries, fixed budget, float32 at highest matmul precision."""
+    c = jnp.asarray(p["ell"].to_dense())
+    with jax.default_matmul_precision("highest"):
+        return np.stack([np.asarray(sinkhorn_wmd_dense(
+            jnp.asarray(s), jnp.asarray(r), c, jnp.asarray(p["vecs"]), LAMB,
+            ITERS)) for s, r in zip(p["sels"], p["rsels"])])
+
+
+# (core, docs_chunk): N = 45, so 16-doc chunks do not divide it and
+# 15-doc chunks do; the converged core is the unchunked oracle
+_SOLVE_CASES = [
+    pytest.param(solve, dc, id=f"{core}-{cid}")
+    for core, solve in (("stripes", _stripes_solve), ("mesh", _mesh_solve))
+    for cid, dc in (("unchunked", None), ("chunks16", 16), ("chunks15", 15))
+] + [pytest.param(_converged_solve, None, id="converged-unchunked")]
+
 
 
 @pytest.mark.parametrize("tol", [0.0, 1e-3], ids=["fixed", "early_exit"])
-@pytest.mark.parametrize("solve,docs_chunk,placement", _HOIST_CASES)
-def test_hoisted_gather_matches_in_loop(batch_problem, monkeypatch, solve,
-                                        docs_chunk, placement, tol):
-    """The solve that gathers K once (`hoists_k_gather`) matches the one
-    that gathers it every iteration (`solve_contractions(hoist=False)`) to
-    1e-6 relative, with pad query rows, an all-pad query and ELL pad slots;
-    N = 45 so the 16-doc chunks do not divide it. Every solve without a
-    per-op chunk hoists; per-op chunking never does, so its two programs
-    are the same."""
-    from repro.core import convergence
-    from repro.core import sparse_sinkhorn as ss
+@pytest.mark.parametrize("solve,docs_chunk", _SOLVE_CASES)
+def test_batched_solves_match_reference(batch_problem, solve, docs_chunk,
+                                        tol):
+    """Each batched solve matches the dense reference, with pad query
+    rows, an all-pad filler query (which solves to exactly 0) and ELL pad
+    slots present."""
     p = batch_problem
-    args = _with_filler(p)
-    kw = dict(tol=tol, docs_chunk=docs_chunk, placement=placement)
-    hoisted, text_once = solve(p, *args, **kw)
-    in_loop_only = functools.partial(ss.solve_contractions, hoist=False)
-    monkeypatch.setattr(ss, "solve_contractions", in_loop_only)
-    monkeypatch.setattr(convergence, "solve_contractions", in_loop_only)
-    in_loop, text_each = solve(p, *args, **kw)
-    per_op_chunked = placement == "iteration" and docs_chunk is not None
-    assert (text_once != text_each) == (not per_op_chunked)
-    err = np.abs(hoisted - in_loop).max() / np.abs(in_loop).max()
-    assert err < 1e-6, err
-    assert hoisted.shape == (5, p["ell"].num_docs)
-    np.testing.assert_array_equal(hoisted[-1], 0.0)
-    np.testing.assert_array_equal(in_loop[-1], 0.0)
+    got = solve(p, *_with_filler(p), tol=tol, docs_chunk=docs_chunk)
+    assert got.shape == (5, p["ell"].num_docs)
+    np.testing.assert_array_equal(got[-1], 0.0)
+    ref = _dense_reference(p)
+    err = np.abs(got[:-1] - ref).max() / np.abs(ref).max()
+    assert err < REL_ERR, err
 
 
 @pytest.mark.parametrize("q,v_r,v,n,nnz", [
@@ -331,10 +248,11 @@ def test_early_exit_fewer_iterations_same_result(batch_problem):
 # ---------------------------------------------------------------------------
 
 def test_distributed_vote_matches_single_host_masking():
-    """build_wmd_batch_fn(tol>0) on a (2, 2) mesh: per-query n_iter from the
-    all-shards vote is within one iteration of single-host
-    sinkhorn_wmd_converged_batch, and the distances agree (subprocess: needs
-    a forced device count).
+    """build_wmd_batch_fn(tol>0) on a (2, 2) mesh: unchunked, per-query
+    n_iter from the all-shards vote is within one iteration of single-host
+    sinkhorn_wmd_converged_batch, and the distances agree; chunked over
+    documents, the distances agree too (subprocess: needs a forced device
+    count).
 
     Why not equal: the vote compares a relative iterate delta |dx|/|x| with
     tol, and dx is a difference of two nearly equal f32 iterates, so the
@@ -381,7 +299,6 @@ ref = sinkhorn_wmd_converged_batch(
 assert int(np.asarray(ref.n_iter).max()) < 400   # masking engaged
 rb = rebucket_for_vocab_shards(ell, 2)
 fn = build_wmd_batch_fn(mesh, lamb=1.0, max_iter=400, tol=1e-5,
-                        docs_chunk=16, chunk_placement="iteration",
                         with_info=True)
 vd, cd, vld = shard_wmd_inputs(mesh, vecs, rb.cols, rb.vals)
 wmd, n_iter, delta = fn(jnp.asarray(vecs[sel_b]), jnp.asarray(r_b),
@@ -391,8 +308,8 @@ assert gap.max() <= 1, (np.asarray(n_iter), np.asarray(ref.n_iter))
 err = (np.abs(np.asarray(wmd) - np.asarray(ref.wmd)).max()
        / np.abs(np.asarray(ref.wmd)).max())
 assert err < 1e-4, err
-# chunk_placement="solve" (per-chunk freeze): same distances, and no block
-# runs longer than the slowest global query
+# solve chunking (per-chunk freeze): same distances, and no block runs
+# longer than the slowest global query
 fn2 = build_wmd_batch_fn(mesh, lamb=1.0, max_iter=400, tol=1e-5,
                          docs_chunk=16, with_info=True)
 wmd2, n_iter2, _ = fn2(jnp.asarray(vecs[sel_b]), jnp.asarray(r_b),
@@ -430,43 +347,83 @@ def _smoke_service(**kw):
                       **kw), data
 
 
-def test_service_q1_routes_to_sequential():
-    """The Q=1 admission policy returns exactly the sequential result (it IS
-    the sequential path -- no batched overhead for singletons), and is NOT
-    taken when the service is configured with an engine the sequential path
-    doesn't implement (tol > 0)."""
-    svc, data = _smoke_service()
-    lone = [data.queries[0]]
-    np.testing.assert_array_equal(svc.query_batch(lone),
-                                  svc.query_batch_sequential(lone))
-    # shortcut: no batched fn (legacy or stripes) was built
-    assert not svc._batch_fns and not svc._stripe_fns
-    svc_tol, _ = _smoke_service(tol=1e-6)
-    got = svc_tol.query_batch(lone)
-    assert svc_tol._batch_fns           # early-exit engine actually ran
-    seq = svc_tol.query_batch_sequential(lone)
-    err = np.abs(got - seq).max() / np.abs(seq).max()
-    assert err < 1e-4, err
+def _route_service(route, tmp_path):
+    """The smoke service on ``route``: "legacy_fused" (no K cache),
+    "stripes" (K cache on) or "live" (a live corpus of the same docs)."""
+    kw = {} if route == "legacy_fused" else {"cache_capacity": 64}
+    svc, data = _smoke_service(**kw)
+    if route == "live":
+        from repro.core import formats as fmt
+        from repro.data.live_corpus import LiveCorpus
+        from repro.serving import WMDService
+        lc = LiveCorpus(str(tmp_path), svc.vecs.shape[0], normalize=False)
+        lc.add_docs(range(svc.ell.num_docs), fmt.doc_lists_from_ell(svc.ell))
+        svc = WMDService.from_live(svc.mesh, svc.cfg, svc.vecs, lc, **kw)
+    return svc, data
 
 
-@pytest.mark.kernel
+def _service_reference(svc, data, r) -> np.ndarray:
+    """`core.sinkhorn.sinkhorn_wmd_dense` of one query against the smoke
+    corpus (a live service's docs in ascending id order), at highest
+    matmul precision."""
+    s, rr = select_query(r)
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(sinkhorn_wmd_dense(
+            jnp.asarray(s), jnp.asarray(rr), jnp.asarray(data.ell.to_dense()),
+            jnp.asarray(svc.vecs), svc.cfg.lamb, svc.cfg.max_iter))
+
+
+@pytest.mark.parametrize("route", ["legacy_fused", "stripes", "live"])
+def test_query_runs_the_batched_engine(route, tmp_path):
+    """``query(r)`` is the batched engine at Q = 1 on every route: it
+    builds the route's batched program and no other, sweeps one v_r-row
+    query bucket, and equals the dense reference."""
+    svc, data = _route_service(route, tmp_path)
+    r = data.queries[0]
+    got = svc.query(r)
+    built = (svc._batch_fns, svc._stripe_fns)
+    assert [len(f) for f in built] == ([1, 0] if route == "legacy_fused"
+                                       else [0, 1])
+    snap = svc.metrics.snapshot()
+    words = int(np.count_nonzero(r))
+    assert snap["wmd_query_slots_total{kind=real}"] == words
+    assert snap["wmd_query_slots_total{kind=pad}"] == svc.cfg.v_r - words
+    ref = _service_reference(svc, data, r)
+    err = np.abs(got - ref).max() / np.abs(ref).max()
+    assert err < REL_ERR, err
+    np.testing.assert_array_equal(svc.query_batch([r])[0], got)
+
+
+@pytest.mark.parametrize("tol", [0.0, 1e-3], ids=["fixed", "early_exit"])
+def test_q1_matches_dense_reference(tol):
+    """A one-query batch equals the dense reference, on the fixed budget
+    and under early exit (the while-loop program at Q = 1)."""
+    svc, data = _smoke_service(tol=tol)
+    for r in data.queries:
+        got = svc.query_batch([r])
+        assert got.shape == (1, svc.ell.num_docs)
+        ref = _service_reference(svc, data, r)
+        err = np.abs(got[0] - ref).max() / np.abs(ref).max()
+        assert err < REL_ERR, err
+    assert list(svc._batch_fns) == [(None, tol, svc.cfg.lamb)]
+
+
 def test_service_forwards_impl_and_chunk():
-    """query_batch(impl=...) and the docs_chunk/tol fields reach the engine:
-    every combination matches the sequential oracle."""
+    """The docs_chunk and tol fields and a per-call docs_chunk override
+    reach the engine: every combination, Q = 1 included, matches the
+    per-query oracle."""
     svc, data = _smoke_service(docs_chunk=16, tol=1e-6)
     seq = svc.query_batch_sequential(data.queries)
-    for impl in ("fused", "kernel"):
-        got = svc.query_batch(data.queries, impl=impl)
-        err = np.abs(got - seq).max() / np.abs(seq).max()
-        assert err < 1e-4, (impl, err)
+    got = svc.query_batch(data.queries)
+    err = np.abs(got - seq).max() / np.abs(seq).max()
+    assert err < 1e-4, err
     # per-call docs_chunk override (0 = explicitly unchunked)
     got = svc.query_batch(data.queries, docs_chunk=0)
     err = np.abs(got - seq).max() / np.abs(seq).max()
     assert err < 1e-4, err
-    # an explicit impl override bypasses the Q=1 sequential shortcut and
-    # still matches the per-query result
+    assert {k[0] for k in svc._batch_fns} == {16, None}
     lone = [data.queries[0]]
-    got1 = svc.query_batch(lone, impl="kernel")
+    got1 = svc.query_batch(lone, docs_chunk=0)
     assert got1.shape == (1, seq.shape[1])
     err1 = np.abs(got1 - seq[:1]).max() / np.abs(seq[:1]).max()
     assert err1 < 1e-4, err1

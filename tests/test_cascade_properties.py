@@ -3,7 +3,7 @@ M-row cache + the cascaded `WMDService.top_k_batch(prune=True)`).
 
 The invariants, in decreasing order of load-bearing-ness:
   1. the bound chain -- tier-0 centroid <= LC-RWMD <= doc-side RWMD <=
-     engine distance, for every impl and every iteration budget. Each
+     engine distance, chunked or not, at every iteration budget. Each
      link is what makes the tier in front of it safe to prune with; the
      LC link is *bitwise* (the same min over the same floats, hoisted
      out of the doc loop -- core.cascade docstring).
@@ -113,9 +113,11 @@ def _queries(vocab, q, seed):
 # 1. the bound chain: tier0 <= LC <= doc-side <= engine, every budget
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("impl", ["fused", "unfused", "kernel"])
+# N = 20 documents: unchunked, 5-doc chunks (dividing), 8-doc chunks (not)
+@pytest.mark.parametrize("docs_chunk", [None, 5, 8],
+                         ids=["none", "dividing", "non-dividing"])
 @pytest.mark.parametrize("max_iter", [1, 3, 15])
-def test_bound_chain_all_impls_all_budgets(impl, max_iter):
+def test_bound_chain_all_impls_all_budgets(docs_chunk, max_iter):
     """tier0(q,d) <= lc(q,d) <= rwmd(q,d) <= sinkhorn(q,d) at ANY fixed
     iteration budget -- the fact each cascade tier's pruning rests on.
     The LC <= doc-side link is equality down to the bit (hoisted min)."""
@@ -127,7 +129,7 @@ def test_bound_chain_all_impls_all_budgets(impl, max_iter):
     d = np.asarray(sinkhorn_wmd_sparse_batch(
         jnp.asarray(sel_b), jnp.asarray(r_b), jnp.asarray(ell.cols),
         jnp.asarray(ell.vals), jnp.asarray(vecs), 1.0, max_iter,
-        row_mask=jnp.asarray(mask_b), impl=impl))
+        row_mask=jnp.asarray(mask_b), docs_chunk=docs_chunk))
     assert np.all(lb_doc <= d * (1 + RTOL) + ATOL), \
         f"doc-side bound exceeds engine output by {np.max(lb_doc - d)}"
 

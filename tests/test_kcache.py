@@ -4,8 +4,9 @@ The cache's contract (core.kcache) is *bitwise* exactness: stripes assembled
 from resident rows equal the recompute-from-scratch transient path bit for
 bit, for any interleaving of hits, misses, evictions, capacity overflows and
 lambda invalidations -- and therefore solver output is identical with the
-cache on or off, for every impl. A seeded random-stream test always runs;
-a hypothesis property test (optional dev dep) drives broader sequences.
+cache on or off, chunked or not, fixed budget or early exit. A seeded
+random-stream test always runs; a hypothesis property test (optional dev
+dep) drives broader sequences.
 """
 import dataclasses
 
@@ -191,7 +192,7 @@ def test_random_sequences_property():
 
 
 # ---------------------------------------------------------------------------
-# Service-level: cache on/off bitwise through the full solver, all impls
+# Service-level: cache on/off bitwise through the full solver
 # ---------------------------------------------------------------------------
 
 def _service(**kw):
@@ -207,17 +208,19 @@ def _service(**kw):
                       **kw), data
 
 
-@pytest.mark.parametrize("impl", ["fused", "unfused",
-                                  pytest.param("kernel",
-                                               marks=pytest.mark.kernel)])
-def test_service_cache_on_off_bitwise(impl):
+@pytest.mark.parametrize("engine", [{"docs_chunk": 0},
+                                    {"docs_chunk": 24},
+                                    {"docs_chunk": 0, "tol": 1e-3}],
+                         ids=["unchunked", "solve_chunks", "early_exit"])
+def test_service_cache_on_off_bitwise(engine):
     """query_batch with the cache enabled is bitwise identical to the
-    cache-off path for every impl, including after evictions (capacity is
-    tiny) and repeat batches (hits)."""
-    svc, data = _service(cache_capacity=24, cache_rows_bucket=8)
+    cache-off path, unchunked, chunked over documents and under early
+    exit, including after evictions (capacity is tiny) and repeat batches
+    (hits)."""
+    svc, data = _service(cache_capacity=24, cache_rows_bucket=8, **engine)
     for queries in (data.queries[:3], data.queries[1:5], data.queries[:3]):
-        on = svc.query_batch(queries, impl=impl)
-        off = svc.query_batch(queries, impl=impl, use_cache=False)
+        on = svc.query_batch(queries)
+        off = svc.query_batch(queries, use_cache=False)
         np.testing.assert_array_equal(on, off)
     assert svc.cache_stats.hit_rows > 0
 

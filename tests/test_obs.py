@@ -14,9 +14,9 @@ Contracts pinned here (ISSUE 9 acceptance criteria):
     log;
   * observability on is bitwise identical to observability off on the
     golden routes (tracing never touches result arrays);
-  * ``last_batch_stats`` is never cleared to ``{}``: the sequential and
-    legacy-fused routes report total solve wall time with an explicit
-    ``phases_separable: False`` marker.
+  * ``last_batch_stats`` is never cleared to ``{}``: the legacy-fused
+    route, at Q = 1 and above, reports total solve wall time with an
+    explicit ``phases_separable: False`` marker.
 """
 import json
 import re
@@ -463,9 +463,9 @@ def test_last_batch_stats_sequential_and_legacy_routes_report_time():
     mesh = make_mesh((1, 1), ("data", "model"))
     svc = WMDService(mesh=mesh, cfg=cfg, vecs=vecs, ell=ell,
                      cache_capacity=0)          # no cache: fast-path routes
-    svc.query_batch([rs[0]])                    # singleton -> sequential
+    svc.query_batch([rs[0]])                    # Q = 1 -> legacy fused
     st = svc.last_batch_stats
-    assert st["route"] == "sequential"
+    assert st["route"] == "legacy_fused"
     assert st["phases_separable"] is False and st["solve_s"] > 0.0
     svc.query_batch(rs)                         # Q>1 -> legacy fused
     st = svc.last_batch_stats

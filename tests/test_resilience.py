@@ -70,9 +70,8 @@ class FakeClock:
 
 
 class FlakyService:
-    """jax-free engine stub: fails the first ``fail`` calls per method
-    route, records every (method, impl) it was dispatched."""
-    impl = "fused"
+    """jax-free engine stub: fails the first ``fail`` calls, records every
+    route it was dispatched."""
 
     def __init__(self, fail=0, n_docs=6):
         self.fail = fail
@@ -84,24 +83,23 @@ class FlakyService:
             self.fail -= 1
             raise RuntimeError("flaky")
 
-    def query_batch(self, rs, impl=None):
-        self.calls.append(("query_batch", impl))
+    def query_batch(self, rs):
+        self.calls.append(("query_batch",))
         self._maybe_fail()
         return np.ones((len(rs), self.n_docs), np.float32)
 
-    def top_k_batch(self, rs, k=10, prune=False, impl=None):
-        self.calls.append(("top_k_batch", "pruned" if prune else "scan",
-                           impl))
+    def top_k_batch(self, rs, k=10, prune=False):
+        self.calls.append(("top_k_batch", "pruned" if prune else "scan"))
         self._maybe_fail()
         return (np.zeros((len(rs), k), np.int64),
                 np.ones((len(rs), k), np.float32))
 
     def query_batch_bounds(self, rs):
-        self.calls.append(("bounds", None))
+        self.calls.append(("bounds",))
         return np.full((len(rs), self.n_docs), 0.5, np.float32)
 
     def top_k_batch_bounds(self, rs, k=10):
-        self.calls.append(("bounds_topk", None))
+        self.calls.append(("bounds_topk",))
         return (np.zeros((len(rs), k), np.int64),
                 np.full((len(rs), k), 0.5, np.float32))
 
@@ -262,8 +260,8 @@ def test_retry_recovers_transient_failures():
     assert isinstance(res, np.ndarray) and res.shape == (2, 6)
     st = g.stats()
     assert st.retries == 2 and st.failures == 2 and st.demoted == 0
-    # rung 0 dispatches with impl=None: the exact unguarded call
-    assert svc.calls[-1] == ("query_batch", None)
+    # rung 0 is the exact unguarded call
+    assert svc.calls == [("query_batch",)] * 3
 
 
 def test_demotion_and_breaker_recovery():
@@ -273,36 +271,53 @@ def test_demotion_and_breaker_recovery():
         max_retries=0, breaker_failures=2, breaker_cooldown_s=10.0),
         clock=clk, sleep=lambda s: None)
     res = g.dispatch("plain", [np.ones(4)])
-    # retries=0: rung 0 fails once (breaker streak 1), demote to rung 1
-    # ("unfused"), which succeeds
-    assert isinstance(res, np.ndarray)
-    assert ("query_batch", "unfused") in svc.calls
+    # retries=0: the engine rung fails once (breaker streak 1); no rung is
+    # left, so the bound tier answers
+    assert isinstance(res, DegradedResult)
+    assert svc.calls == [("query_batch",), ("bounds",)]
     st = g.stats()
-    assert st.demoted == 1
-    # fail rung 0 once more -> streak 2 -> breaker opens
+    assert st.demoted == 0 and st.degraded == 1
+    # fail the engine once more -> streak 2 -> breaker opens
     svc.fail = 1
     g.dispatch("plain", [np.ones(4)])
     assert g.stats().breaker_states["plain/0"] == "open"
-    # while open, dispatches skip rung 0 entirely
+    # while open, dispatches skip the engine entirely
     n_calls = len(svc.calls)
-    g.dispatch("plain", [np.ones(4)])
-    assert svc.calls[n_calls:] == [("query_batch", "unfused")]
-    # cooldown passes: next dispatch probes rung 0 (half_open) and closes
+    res = g.dispatch("plain", [np.ones(4)])
+    assert svc.calls[n_calls:] == [("bounds",)]
+    assert res.reason == "all rungs open"
+    # cooldown passes: next dispatch probes the engine (half_open), closes
     clk.advance(10.1)
-    g.dispatch("plain", [np.ones(4)])
-    assert svc.calls[-1] == ("query_batch", None)
+    res = g.dispatch("plain", [np.ones(4)])
+    assert isinstance(res, np.ndarray)
+    assert svc.calls[-1] == ("query_batch",)
     assert g.stats().breaker_states["plain/0"] == "closed"
 
 
 def test_top_k_ladder_falls_back_to_scan():
-    svc = FlakyService(fail=2)                  # pruned rungs: None, unfused
+    svc = FlakyService(fail=1)                  # the pruned rung fails
     g = EngineGuard(svc, ResiliencePolicy(max_retries=0, breaker_failures=1,
                                           degrade_on_failure=False),
                     sleep=lambda s: None)
     res = g.dispatch("top_k", [np.ones(4)], k=3)
     assert res[0].shape == (1, 3)
     kinds = [c[1] for c in svc.calls if c[0] == "top_k_batch"]
-    assert kinds == ["pruned", "pruned", "scan"]
+    assert kinds == ["pruned", "scan"]
+    assert g.stats().demoted == 1
+
+
+def test_plain_ladder_is_one_rung_then_degraded():
+    """A plain dispatch has one exact rung, the engine: its retries
+    exhausted, the bound tier answers; there is no second engine call."""
+    svc = FlakyService(fail=3)
+    g = EngineGuard(svc, ResiliencePolicy(max_retries=2, breaker_failures=5),
+                    sleep=lambda s: None)
+    res = g.dispatch("plain", [np.ones(4)] * 2)
+    assert isinstance(res, DegradedResult) and "flaky" in res.reason
+    assert svc.calls == [("query_batch",)] * 3 + [("bounds",)]
+    assert set(g.stats().breaker_states) == {"plain/0", "top_k/0",
+                                             "top_k/1"}
+    assert g.dispatch_log[-1] == ("plain", -1, True)
 
 
 def test_degraded_when_every_rung_fails():
@@ -330,8 +345,8 @@ def test_degradation_disabled_raises_last_error():
 
 def test_invalid_query_never_retried():
     class Rejecting(FlakyService):
-        def query_batch(self, rs, impl=None):
-            self.calls.append(("query_batch", impl))
+        def query_batch(self, rs):
+            self.calls.append(("query_batch",))
             raise guards.InvalidQueryError("bad row")
 
     svc = Rejecting()
@@ -345,8 +360,8 @@ def test_invalid_query_never_retried():
 
 def test_guard_post_check_catches_corruption():
     class Corrupting(FlakyService):
-        def query_batch(self, rs, impl=None):
-            self.calls.append(("query_batch", impl))
+        def query_batch(self, rs):
+            self.calls.append(("query_batch",))
             out = np.ones((len(rs), self.n_docs), np.float32)
             if len(self.calls) == 1:            # only the first dispatch
                 out[0, 0] = np.nan
@@ -377,12 +392,17 @@ def test_brownout_dispatch_serves_bounds_and_recovers():
 def test_trip_force_opens_active_rung():
     svc = FlakyService()
     g = EngineGuard(svc, ResiliencePolicy(), sleep=lambda s: None)
-    g.trip("plain")
+    g.trip("top_k")
+    assert g.stats().breaker_states["top_k/0"] == "open"
+    g.dispatch("top_k", [np.ones(4)], k=3)      # served by rung 1
+    assert svc.calls[-1] == ("top_k_batch", "scan")
+    g.trip("top_k")                             # next non-open rung
+    assert g.stats().breaker_states["top_k/1"] == "open"
+    g.trip("plain")                             # the engine, plain's one
     assert g.stats().breaker_states["plain/0"] == "open"
-    g.dispatch("plain", [np.ones(4)])           # served by rung 1
-    assert svc.calls[-1] == ("query_batch", "unfused")
-    g.trip("plain")                             # next non-open rung
-    assert g.stats().breaker_states["plain/1"] == "open"
+    res = g.dispatch("plain", [np.ones(4)])
+    assert isinstance(res, DegradedResult)
+    assert svc.calls[-1] == ("bounds",)
 
 
 # ---------------------------------------------------------------------------
@@ -475,11 +495,12 @@ def test_fake_services_keep_light_validation():
 # high-lambda underflow: typed error vs the old silent-zero behavior
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("impl", ["fused", "unfused"])
+@pytest.mark.parametrize("docs_chunk", [0, DOCS // 3],
+                         ids=["unchunked", "solve_chunks"])
 @pytest.mark.parametrize("capacity", [0, 64])
-def test_high_lambda_raises_numerical_error(impl, capacity):
+def test_high_lambda_raises_numerical_error(docs_chunk, capacity):
     svc = _service(lamb=30.0, capacity=capacity)
-    svc.impl = impl
+    svc.docs_chunk = docs_chunk
     qs = _queries(4, seed=1)
     with pytest.raises(guards.NumericalError) as ei:
         svc.query_batch(qs)
@@ -571,7 +592,7 @@ def test_chaos_no_deadlock_every_future_resolves_bitwise():
     replayed = 0
     for rec in eng.dispatch_log:
         if (rec.method == "query_batch" and rec.result is not None
-                and not rec.fault.corrupt and "impl" not in rec.kwargs):
+                and not rec.fault.corrupt):
             np.testing.assert_array_equal(
                 rec.result, clean.query_batch(rec.payloads))
             replayed += 1

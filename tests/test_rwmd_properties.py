@@ -3,7 +3,7 @@
 
 The invariants, in decreasing order of load-bearing-ness:
   1. soundness -- the doc-side RWMD bound never exceeds the engine's
-     returned distance, for every impl and (crucially) every iteration
+     returned distance, chunked or not, and (crucially) at every iteration
      budget. This is THE fact the pruning contract rests on, and the
      reason the doc side was chosen: the engine enforces the doc-side
      marginal exactly at every iterate, while the classic query-side
@@ -64,14 +64,15 @@ def _problem(seed, *, v=96, w=8, n=20, vr_bucket=8, q=3):
     return sel_b, r_b, mask_b, ell, vecs
 
 
-def _bound_and_dist(sel_b, r_b, mask_b, ell, vecs, *, max_iter, impl):
+def _bound_and_dist(sel_b, r_b, mask_b, ell, vecs, *, max_iter,
+                    docs_chunk):
     cols, vals = jnp.asarray(ell.cols), jnp.asarray(ell.vals)
     m_pad = assemble_m_stripes(sel_b, mask_b, vecs, rows_bucket=8)
     lb = np.asarray(rwmd_bound_batch(m_pad, cols, vals))
     d = np.asarray(sinkhorn_wmd_sparse_batch(
         jnp.asarray(sel_b), jnp.asarray(r_b), cols, vals,
         jnp.asarray(vecs), 1.0, max_iter,
-        row_mask=jnp.asarray(mask_b), impl=impl))
+        row_mask=jnp.asarray(mask_b), docs_chunk=docs_chunk))
     return lb, d
 
 
@@ -95,7 +96,7 @@ def _queries(vocab, q, seed):
 
 
 # ---------------------------------------------------------------------------
-# 1. soundness: bound <= engine output, every impl, every iteration budget
+# 1. soundness: bound <= engine output, every chunking, every budget
 # ---------------------------------------------------------------------------
 
 # fp slack of the comparison: the bound and the distance accumulate their
@@ -105,14 +106,16 @@ def _queries(vocab, q, seed):
 RTOL, ATOL = 1e-5, 1e-6
 
 
-@pytest.mark.parametrize("impl", ["fused", "unfused", "kernel"])
+# N = 20 documents: unchunked, 5-doc chunks (dividing), 8-doc chunks (not)
+@pytest.mark.parametrize("docs_chunk", [None, 5, 8],
+                         ids=["none", "dividing", "non-dividing"])
 @pytest.mark.parametrize("max_iter", [1, 3, 15])
-def test_bound_below_engine_all_impls_all_budgets(impl, max_iter):
+def test_bound_below_engine_all_impls_all_budgets(docs_chunk, max_iter):
     """rwmd(q, d) <= sinkhorn_wmd(q, d) at ANY fixed iteration budget --
     including budget 1, where the query-side marginal is maximally stale."""
     sel_b, r_b, mask_b, ell, vecs = _problem(seed=max_iter * 7 + 1)
     lb, d = _bound_and_dist(sel_b, r_b, mask_b, ell, vecs,
-                            max_iter=max_iter, impl=impl)
+                            max_iter=max_iter, docs_chunk=docs_chunk)
     assert np.all(lb <= d * (1 + RTOL) + ATOL), \
         f"bound exceeds engine output by {np.max(lb - d)}"
 
@@ -308,11 +311,11 @@ if given is not None:
 
     @_settings
     @given(st.integers(0, 10_000), st.integers(1, 12),
-           st.sampled_from(["fused", "unfused"]))
-    def test_hyp_bound_below_engine(seed, max_iter, impl):
+           st.sampled_from([None, 7]))
+    def test_hyp_bound_below_engine(seed, max_iter, docs_chunk):
         sel_b, r_b, mask_b, ell, vecs = _problem(seed=seed)
         lb, d = _bound_and_dist(sel_b, r_b, mask_b, ell, vecs,
-                                max_iter=max_iter, impl=impl)
+                                max_iter=max_iter, docs_chunk=docs_chunk)
         assert np.all(lb <= d * (1 + RTOL) + ATOL)
 
     @_settings

@@ -13,8 +13,7 @@ Contracts pinned here:
   * ``precompute_s`` / ``solve_s`` are read off the same spans, on every
     route;
   * ``wmd_query_slots_total`` / ``wmd_ell_slots_total`` count real and pad
-    slots per solve dispatch, ``wmd_k_gathers_total`` the gathers of K it
-    makes (once, or in every iteration), and warm-up is not counted.
+    slots per solve dispatch, and warm-up is not counted.
 """
 import glob
 import os
@@ -81,7 +80,7 @@ def test_solve_programs_carry_wmd_scopes(route):
         np.testing.assert_array_equal(svc.query_batch(rs),
                                       golden["service_legacy"])
         sel_b, r_b, mask_b = svc._padded_query_batch(rs)
-        fn = svc._batch_fn(svc.impl, svc.docs_chunk)
+        fn = svc._batch_fn(svc.docs_chunk)
         args = (jnp.asarray(svc.vecs[sel_b]), jnp.asarray(r_b),
                 jnp.asarray(mask_b), svc._vecs_d, svc._cols_d, svc._vals_d)
     else:
@@ -91,7 +90,7 @@ def test_solve_programs_carry_wmd_scopes(route):
                                       golden["service_stripes"])
         sel_b, r_b, mask_b = svc._padded_query_batch(rs)
         k_s, km_s, _ = svc._kcache.stripes_for_batch(sel_b, mask_b)
-        fn = svc._stripe_fn(svc.impl, svc.docs_chunk)
+        fn = svc._stripe_fn(svc.docs_chunk)
         args = (k_s, km_s, jnp.asarray(r_b), svc._cols_d, svc._vals_d)
     ops = _op_scopes(fn.lower(*args).compile().as_text())
     assert ops
@@ -141,8 +140,8 @@ def test_query_batch_stages_in_a_profiler_trace(tmp_path):
         assert inside == set(stages)
 
 
-@pytest.mark.parametrize("route", ["sequential", "legacy_fused", "stripes",
-                                   "live"])
+@pytest.mark.parametrize("route", ["q1_legacy_fused", "legacy_fused",
+                                   "stripes", "live"])
 def test_last_batch_stats_read_off_the_spans(route, tmp_path):
     kw = {"cache_capacity": 64} if route in ("stripes", "live") else {}
     svc, rs = _service(**kw)
@@ -152,7 +151,7 @@ def test_last_batch_stats_read_off_the_spans(route, tmp_path):
         lc = LiveCorpus(str(tmp_path), svc.vecs.shape[0], normalize=False)
         lc.add_docs(range(svc.ell.num_docs), fmt.doc_lists_from_ell(svc.ell))
         svc = WMDService.from_live(svc.mesh, svc.cfg, svc.vecs, lc, **kw)
-    batch = rs[:1] if route == "sequential" else rs
+    batch = rs[:1] if route == "q1_legacy_fused" else rs
     svc.query_batch(batch)
     st = svc.last_batch_stats
     names = [n for n, _, _ in st["spans"]]
@@ -164,7 +163,7 @@ def test_last_batch_stats_read_off_the_spans(route, tmp_path):
     if route in ("stripes", "live"):
         assert st["precompute_s"] == seconds("wmd.cache_rows") > 0
     else:
-        assert st["route"] == route and "precompute_s" not in st
+        assert st["route"] == "legacy_fused" and "precompute_s" not in st
     root = [(t0, t1) for n, t0, t1 in st["spans"]
             if n == "wmd.query_batch"][0]
     assert all(root[0] <= t0 <= t1 <= root[1] for _, t0, t1 in st["spans"])
@@ -180,7 +179,7 @@ def test_slot_counters_match_hand_counts():
     assert snap.get("wmd_query_slots_total{kind=real}", 0) == 0
     assert "wmd_span_seconds{span=wmd.query_batch}" not in snap
     svc.query_batch(rs)                     # Q = 3 -> one Q_pow2 = 4 bucket
-    svc.query_batch(rs[:1])                 # sequential: one dispatch
+    svc.query_batch(rs[:1])                 # Q = 1: one dispatch
     words = [int(np.count_nonzero(r)) for r in rs]
     v_r = tg.V_R_BUCKET
     nnz = int(np.count_nonzero(svc.ell.vals))
@@ -193,52 +192,6 @@ def test_slot_counters_match_hand_counts():
     assert snap["wmd_ell_slots_total{kind=pad}"] == 2 * (slots - nnz)
     assert snap["wmd_span_seconds{span=wmd.query_batch}"]["count"] == 2
     assert snap["wmd_span_seconds{span=wmd.dispatch}"]["count"] == 2
-
-
-def test_k_gather_counter_counts_each_solve_dispatch():
-    """A fused full-distance dispatch gathers K once, before its loop, and
-    K.*M once; the unfused baseline and the sequential route gather K in
-    each of max_iter iterations plus the final pass's two. Warm-up adds
-    nothing."""
-    from repro.serving.warmup import ProgramShape, ShapeRegistry, warm
-    svc, rs = _service()
-    warm(svc, ShapeRegistry([ProgramShape("plain", 4)]))
-
-    def gathers():
-        snap = svc.metrics.snapshot()
-        return tuple(snap.get(f"wmd_k_gathers_total{{where={w}}}", 0)
-                     for w in ("once", "per_iteration"))
-    assert gathers() == (0, 0)
-    svc.query_batch(rs)
-    assert gathers() == (2, 0)
-    svc.query_batch(rs, impl="unfused")
-    assert gathers() == (2, tg.MAX_ITER + 2)
-    svc.query_batch(rs[:1])                 # sequential: per-query program
-    assert gathers() == (2, 2 * (tg.MAX_ITER + 2))
-    svc.query_batch(rs, use_cache=False)    # stripes program
-    assert gathers() == (4, 2 * (tg.MAX_ITER + 2))
-
-
-def test_k_gather_row_bytes_reads_the_last_dispatch():
-    """``wmd_k_gather_row_bytes`` holds the contiguous bytes one ELL slot's
-    K gather fetches in the last solve dispatch: a row of all Q_pow2 x v_r
-    floats where K is gathered once (the legacy fused route), one query's
-    v_r where the unfused baseline gathers in its loop. Warm-up sets
-    nothing."""
-    from repro.serving.warmup import ProgramShape, ShapeRegistry, warm
-    svc, rs = _service()
-    warm(svc, ShapeRegistry([ProgramShape("plain", 4)]))
-
-    def row_bytes():
-        return svc.metrics.snapshot().get("wmd_k_gather_row_bytes")
-    assert row_bytes() is None
-    svc.query_batch(rs)                     # Q = 3 -> one Q_pow2 = 4 bucket
-    assert svc.last_batch_stats["route"] == "legacy_fused"
-    assert row_bytes() == 4 * tg.V_R_BUCKET * 4
-    svc.query_batch(rs, impl="unfused")
-    assert row_bytes() == tg.V_R_BUCKET * 4
-    svc.query_batch(rs)
-    assert row_bytes() == 4 * tg.V_R_BUCKET * 4
 
 
 def test_coalescer_phase_children_are_the_service_spans():

@@ -64,7 +64,8 @@ def test_distributed_wmd_matches_single_chip():
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import (select_query, sinkhorn_wmd_sparse, ell_from_dense,
                         rebucket_for_vocab_shards)
-from repro.core.distributed import build_wmd_fn, shard_wmd_inputs, pad_query
+from repro.core.distributed import (build_wmd_batch_fn, shard_wmd_inputs,
+                                    pad_query_batch)
 from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((4, 2), ("data", "model"))
@@ -82,12 +83,13 @@ sel_idx, r_sel = select_query(r)
 ell = ell_from_dense(c)
 ref = np.asarray(sinkhorn_wmd_sparse(sel_idx, r_sel, jnp.asarray(ell.cols),
                                      jnp.asarray(ell.vals), vecs, 1.0, 12))
-sel_p, r_p, mask = pad_query(sel_idx, r_sel, 16)
+sel_b, r_b, mask_b = pad_query_batch([sel_idx], [r_sel], 16)
 rb = rebucket_for_vocab_shards(ell, 2)
-fn = build_wmd_fn(mesh, lamb=1.0, max_iter=12)
+# one query; each data shard's 16 docs solved in two chunks of 8
+fn = build_wmd_batch_fn(mesh, lamb=1.0, max_iter=12, docs_chunk=8)
 vd, cd, vld = shard_wmd_inputs(mesh, vecs, rb.cols, rb.vals)
-got = np.asarray(fn(jnp.asarray(vecs[sel_p]), jnp.asarray(r_p),
-                    jnp.asarray(mask), vd, cd, vld))
+got = np.asarray(fn(jnp.asarray(vecs[sel_b]), jnp.asarray(r_b),
+                    jnp.asarray(mask_b), vd, cd, vld))[0]
 err = np.abs(got - ref).max() / np.abs(ref).max()
 assert err < 1e-4, err
 print("DIST_WMD_OK", err)

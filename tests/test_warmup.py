@@ -171,6 +171,24 @@ def test_warm_then_zero_compiles_on_every_shape(stack):
     assert cc.compiles == 0
 
 
+def test_registry_warms_q1_as_batched_program(stack):
+    """The plain Q = 1 shape warms the batched program: warm-up builds
+    exactly one batched solver, and `query` -- the batched engine at
+    Q = 1 -- then dispatches it without a compile."""
+    cfg, data, mesh, _ = stack
+    svc = WMDService(mesh=mesh, cfg=cfg, vecs=data.vecs, ell=data.ell)
+    reg = ShapeRegistry.from_service(svc, max_batch=1)
+    assert reg.labels == ["plain/q1"]
+    warm(svc, reg)
+    assert list(svc._batch_fns) == [(None, 0.0, cfg.lamb)]
+    assert not svc._stripe_fns
+    with measure_compiles() as cc:
+        d = svc.query(data.queries[0])
+    assert cc.events == 0, f"{cc.events} compile-or-retrieve events"
+    assert d.shape == (cfg.num_docs,)
+    assert svc.last_batch_stats["route"] == "legacy_fused"
+
+
 def test_measure_compiles_nests_with_equal_tallies():
     """An inner span whose tallies equal the outer's (both still zero)
     detaches its own counter on exit, so the outer one keeps counting."""
